@@ -14,12 +14,18 @@ Raft/RocksDB stores) re-designed for TPU:
 - the SQL frontend, planner, catalog and storage tiers live on the host
   (sql/, plan/, meta/, storage/).
 
-int64/float64 columns require jax x64 mode; enabled at import.
+int64/float64 columns require jax x64 mode; enabled at import, together
+with the persistent compile cache (utils/compilecache.enable).  Neither
+initialises a backend.
 """
 
 import jax as _jax
 
 _jax.config.update("jax_enable_x64", True)
+
+from .utils import compilecache as _compilecache  # noqa: E402
+
+_compilecache.enable()
 
 from .types import Field, LType, Schema  # noqa: E402,F401
 from .column.batch import Column, ColumnBatch  # noqa: E402,F401

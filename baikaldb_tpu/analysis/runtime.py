@@ -29,7 +29,7 @@ Trips surface in ``metrics`` (``guard_transfer_trips`` /
 
 CPU caveat: on the CPU backend device->host reads are zero-copy views, so
 jax's transfer guard never fires there — the transfer half of debug_guards
-is a no-op under JAX_PLATFORMS=cpu and bites on real accelerators, which is
+is a no-op on the CPU backend and bites on real accelerators, which is
 exactly where the sync costs a round-trip.  The lock half is
 backend-independent.
 """

@@ -53,6 +53,10 @@ class Connection:
         self.sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
         self.p = Packets(self.sock)
         self._handshake(user, database, password)
+        # the 30 s bound the connect and the handshake; a query has no read
+        # timeout (MySQL clients default to none): a first compile on the
+        # chip runs for minutes before the server sends a byte
+        self.sock.settimeout(None)
 
     def _handshake(self, user: str, database: str, password: str):
         greet = self.p.read()

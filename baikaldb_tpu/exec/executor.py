@@ -39,7 +39,7 @@ from ..plan.nodes import (AggNode, DistinctNode, ExchangeNode, FilterNode,
                           ShrinkNode, SortNode, StreamResultNode, UnionNode,
                           ValuesNode, WindowNode)
 from ..column.batch import concat_batches
-from ..parallel.mesh import AXIS, shard_map
+from ..parallel.mesh import AXIS
 from ..types import LType
 
 
@@ -57,6 +57,7 @@ from ..utils.flags import FLAGS, define  # noqa: E402
 # make pushed results silently diverge from the image path (the
 # off-switch's bit-identity guarantee), so fail loudly instead.
 from ..parallel.agg import WIRE_MERGE as _WIRE_MERGE  # noqa: E402
+from ..parallel.agg import merge_collective  # noqa: E402
 
 _drift = {k for k, op in _WIRE_MERGE.items() if MERGE_OP.get(k) != op}
 if _drift:
@@ -66,6 +67,8 @@ if _drift:
 del _drift
 
 import threading  # noqa: E402
+
+_I32_MAX = (1 << 31) - 1
 
 # set (thread-locally) by utils/compilecache._analyze while it AOT
 # re-lowers a cached executable for cost accounting: jax traces on the
@@ -181,8 +184,11 @@ def compile_plan(plan: PlanNode, trace: bool = False, mesh=None) -> Callable:
         flags = tuple(f for _, f in overflows)
         if n_shards:
             # flags carry NEEDED capacities: the retry must satisfy the
-            # hungriest shard, so reduce with pmax
-            flags = tuple(jax.lax.pmax(jnp.asarray(f), AXIS) for f in flags)
+            # hungriest shard, so reduce with pmax — as int32 (a capacity is
+            # a row count): the TPU lowers no 64-bit max all-reduce
+            flags = tuple(
+                jax.lax.pmax(jnp.minimum(jnp.asarray(f), _I32_MAX)
+                             .astype(jnp.int32), AXIS) for f in flags)
         if trace:
             return out, flags, tuple(counts)
         return out, flags
@@ -203,8 +209,8 @@ def compile_plan(plan: PlanNode, trace: bool = False, mesh=None) -> Callable:
             # never reconstructs a trace.
             specs = {k: (P() if k == PARAMS_KEY else P(AXIS))
                      for k in batches}
-            smapped = shard_map(run_local, mesh=mesh, in_specs=(specs,),
-                                out_specs=P(), check_vma=False)
+            smapped = jax.shard_map(run_local, mesh=mesh, in_specs=(specs,),
+                                    out_specs=P(), check_vma=False)
             return smapped(batches)
 
     run.join_order = join_order
@@ -720,16 +726,6 @@ def _repartition_exec(b: ColumnBatch, keys: list[str], n: int, cap: int):
     return repartition_collective(b, keys, n, cap)
 
 
-def _merge_collective(op: str, x):
-    if op == "sum":
-        return jax.lax.psum(x, AXIS)
-    if op == "min":
-        return jax.lax.pmin(x, AXIS)
-    if op == "max":
-        return jax.lax.pmax(x, AXIS)
-    raise ExecError(f"no collective merge for {op}")
-
-
 def _merge_partial_cols(part: ColumnBatch, parts: list[AggSpec],
                         key_names: list[str]):
     """psum/pmin/pmax-merge the aggregate columns of a local partial table."""
@@ -739,7 +735,7 @@ def _merge_partial_cols(part: ColumnBatch, parts: list[AggSpec],
             cols.append(c)
             continue
         spec = next(s for s in parts if s.out_name == name)
-        merged = _merge_collective(MERGE_OP[spec.op], c.data)
+        merged = merge_collective(MERGE_OP[spec.op], c.data)
         validity = c.validity
         if validity is not None:
             validity = jax.lax.psum(validity.astype(jnp.int32), AXIS) > 0

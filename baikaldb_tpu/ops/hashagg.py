@@ -294,15 +294,10 @@ def _pallas_dense_cols(batch, specs, gid, ng: int, sel):
 
     from ..utils.flags import FLAGS
     from . import segments
-    from .pallas_kernels import (PALLAS_AVAILABLE, PALLAS_MAX_GROUPS,
-                                 filtered_group_sum, fused_group_aggregate,
-                                 partition_histogram)
+    from .pallas_kernels import (PALLAS_MAX_GROUPS, filtered_group_sum,
+                                 fused_group_aggregate, partition_histogram)
 
-    try:
-        enabled = bool(FLAGS.pallas_group_kernels)
-    except Exception:
-        enabled = False
-    if not (enabled and PALLAS_AVAILABLE
+    if not (bool(FLAGS.pallas_group_kernels)
             and _jax.default_backend() not in ("cpu",)
             and segments._max_segments() < ng + 1 <= PALLAS_MAX_GROUPS):
         return None

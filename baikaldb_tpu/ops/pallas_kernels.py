@@ -25,9 +25,9 @@ the remote compile until restructured):
   in VMEM scratch compensates the cross-step f32 adds.
 
 The public entry points pad rows to full blocks with out-of-range codes
-(their one-hot rows are all zero) and fall back to the XLA lowering off-TPU
-or when Pallas is unavailable; ``interpret=True`` runs the same kernels on
-CPU for tests.
+(their one-hot rows are all zero).  There is no other lowering behind them:
+off the TPU the caller (ops/hashagg.py) stays on the segment reductions, and
+``interpret=True`` runs the same kernels on CPU for tests only.
 """
 
 from __future__ import annotations
@@ -37,20 +37,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:  # Pallas is part of jax; guard for stripped builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    PALLAS_AVAILABLE = True
-except Exception:  # pragma: no cover
-    PALLAS_AVAILABLE = False
-
-try:
-    from jax._src.config import enable_x64 as _x64_scope  # context manager
-except Exception:  # pragma: no cover
-    import contextlib
-
-    def _x64_scope(_):
-        return contextlib.nullcontext()
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
 R_BLOCK = 8                  # sublane rows per grid step = out_ref sublanes
@@ -188,9 +176,7 @@ def filtered_group_sum(codes, values, mask, num_groups: int,
     codes: int [N]; values: [N] (contracted as f32); mask: bool [N].
     -> (counts [num_groups] f32, sums [num_groups] f32).  Rows failing the
     mask or with out-of-range codes drop."""
-    if not PALLAS_AVAILABLE:
-        return _xla_fallback(codes, values, mask, num_groups)
-    with _x64_scope(False):
+    with jax.enable_x64(False):
         (g2, v2), steps, ng_pad = _prep(codes, mask, num_groups, values)
         out = pl.pallas_call(
             functools.partial(_sum_kernel, ng=ng_pad),
@@ -213,9 +199,7 @@ def fused_group_aggregate(codes, values, mask, num_groups: int,
 
     -> (counts, sums, mins, maxs) [num_groups] f32; min/max lanes of empty
     groups hold +/-3.4e38 (count==0 marks them)."""
-    if not PALLAS_AVAILABLE:
-        return _xla_agg_fallback(codes, values, mask, num_groups)
-    with _x64_scope(False):
+    with jax.enable_x64(False):
         (g2, v2), steps, ng_pad = _prep(codes, mask, num_groups, values)
         out = pl.pallas_call(
             functools.partial(_agg_kernel, ng=ng_pad),
@@ -240,13 +224,7 @@ def partition_histogram(dest, mask, num_partitions: int,
     """Per-destination row counts for a hash shuffle, as one MXU pass (sizes
     exchange capacities exactly so the repartition compiles with the right
     cap on the FIRST attempt)."""
-    if not PALLAS_AVAILABLE:
-        gid = jnp.where(mask & (dest >= 0) & (dest < num_partitions),
-                        dest, num_partitions)
-        return jax.ops.segment_sum(
-            jnp.ones(dest.shape[0], jnp.float32), gid,
-            num_segments=num_partitions + 1)[:num_partitions]
-    with _x64_scope(False):
+    with jax.enable_x64(False):
         (g2,), steps, ng_pad = _prep(dest, mask, num_partitions)
         out = pl.pallas_call(
             functools.partial(_hist_kernel, ng=ng_pad),
@@ -257,30 +235,3 @@ def partition_histogram(dest, mask, num_partitions: int,
             interpret=interpret,
         )(g2)
     return out.astype(jnp.float64).sum(axis=0).astype(jnp.float32)[:num_partitions]
-
-
-def _xla_fallback(codes, values, mask, num_groups: int):
-    gid = jnp.where(mask & (codes >= 0) & (codes < num_groups),
-                    codes, num_groups)
-    counts = jax.ops.segment_sum(jnp.ones_like(values, jnp.float32), gid,
-                                 num_segments=num_groups + 1)[:num_groups]
-    sums = jax.ops.segment_sum(values.astype(jnp.float32), gid,
-                               num_segments=num_groups + 1)[:num_groups]
-    return counts, sums
-
-
-def _xla_agg_fallback(codes, values, mask, num_groups: int):
-    live = mask & (codes >= 0) & (codes < num_groups)
-    gid = jnp.where(live, codes, num_groups)
-    v = values.astype(jnp.float32)
-    counts = jax.ops.segment_sum(jnp.ones_like(v), gid,
-                                 num_segments=num_groups + 1)[:num_groups]
-    sums = jax.ops.segment_sum(v, gid,
-                               num_segments=num_groups + 1)[:num_groups]
-    mins = jnp.minimum(jax.ops.segment_min(
-        jnp.where(live, v, _BIG), gid,
-        num_segments=num_groups + 1)[:num_groups], _BIG)
-    maxs = jnp.maximum(jax.ops.segment_max(
-        jnp.where(live, v, -_BIG), gid,
-        num_segments=num_groups + 1)[:num_groups], -_BIG)
-    return counts, sums, mins, maxs

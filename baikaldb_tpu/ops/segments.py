@@ -36,11 +36,8 @@ def _onehot_backend() -> bool:
 
 def _max_segments() -> int:
     """Flag-tunable crossover (utils/flags.py: onehot_max_segments)."""
-    try:
-        from ..utils.flags import FLAGS
-        return int(FLAGS.onehot_max_segments)
-    except Exception:
-        return ONEHOT_MAX_SEGMENTS
+    from ..utils.flags import FLAGS
+    return int(FLAGS.onehot_max_segments)
 
 
 def _use_onehot(num_segments: int) -> bool:

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 
 from ..column.batch import Column, ColumnBatch
 from ..types import LType
-from ..utils.jax_compat import cummax
 from .segments import seg_max, seg_min, seg_sum
 from .sort import SortKey
 
@@ -87,7 +86,7 @@ def window_compute(batch: ColumnBatch, partition_names: list[str],
             v = c.validity[perm]
             tie = tie | (v != jnp.roll(v, 1))
 
-    start_idx = cummax(jnp.where(flags, idx, 0))
+    start_idx = jnp.maximum.accumulate(jnp.where(flags, idx, 0))
     row_number = idx - start_idx + 1
     sid = jnp.cumsum(flags.astype(jnp.int32)) - 1
     nseg = n + 1
@@ -104,7 +103,7 @@ def window_compute(batch: ColumnBatch, partition_names: list[str],
     fctx = None
     if any(s.frame for s in specs):
         # tie (peer) group bounds, shared by RANGE CURRENT ROW bounds
-        tstart = cummax(jnp.where(tie, idx, 0))
+        tstart = jnp.maximum.accumulate(jnp.where(tie, idx, 0))
         tid = jnp.cumsum(tie.astype(jnp.int32)) - 1
         tsize = seg_sum(sel_s.astype(jnp.int64),
                         jnp.where(sel_s, tid, n), num_segments=nseg)[:n]
@@ -142,7 +141,7 @@ def _one(s: WinSpec, batch, perm, idx, sel_s, flags, tie, sid, start_idx,
     if s.op == "row_number":
         return row_number.astype(jnp.int64), None, LType.INT64
     if s.op == "rank":
-        tstart = cummax(jnp.where(tie, idx, 0))
+        tstart = jnp.maximum.accumulate(jnp.where(tie, idx, 0))
         return (tstart - start_idx + 1).astype(jnp.int64), None, LType.INT64
     if s.op == "dense_rank":
         c = jnp.cumsum(tie.astype(jnp.int64))

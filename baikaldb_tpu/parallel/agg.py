@@ -30,7 +30,7 @@ from ..ops.hashagg import (AggSpec, MERGE_OP, finalize_partials,
                            group_aggregate_dense, group_aggregate_sorted,
                            partial_specs)
 from ..utils.flags import FLAGS, define
-from .mesh import AXIS, shard_map
+from .mesh import AXIS
 
 define("adaptive_agg", True,
        "choose per query between local pre-aggregation and raw-row shuffle "
@@ -116,13 +116,24 @@ def rewrap_partial(part: ColumnBatch) -> ColumnBatch:
     return ColumnBatch(part.names, part.columns, sel, None)
 
 
-def _merge_collective(op: str, x, axis_name: str):
+def _pextremum(x, axis_name: str, is_min: bool):
+    """pmin/pmax the TPU can lower for BIGINT/DOUBLE lanes too.  Its x64
+    rewriter implements only the Sum all-reduce over 64-bit element types
+    ("Supported lowering only of Sum all reduce"), so 64-bit partials are
+    all_gathered — data movement, which it does rewrite: the broadcast join
+    gathers BIGINT/DOUBLE columns the same way — and reduced locally."""
+    if x.dtype.itemsize < 8:
+        return (jax.lax.pmin if is_min else jax.lax.pmax)(x, axis_name)
+    gathered = jax.lax.all_gather(x, axis_name)
+    return (jnp.min if is_min else jnp.max)(gathered, axis=0)
+
+
+def merge_collective(op: str, x, axis_name: str = AXIS):
+    """In-network merge of one partial-aggregate lane under its MERGE_OP."""
     if op == "sum":
         return jax.lax.psum(x, axis_name)
-    if op == "min":
-        return jax.lax.pmin(x, axis_name)
-    if op == "max":
-        return jax.lax.pmax(x, axis_name)
+    if op in ("min", "max"):
+        return _pextremum(x, axis_name, op == "min")
     raise ValueError(f"no collective merge for {op}")
 
 
@@ -150,7 +161,7 @@ def dist_group_aggregate_dense(batch: ColumnBatch, key_names: list[str],
                 cols.append(c)
                 continue
             spec = next(s for s in parts if s.out_name == name)
-            merged = _merge_collective(MERGE_OP[spec.op], c.data, AXIS)
+            merged = merge_collective(MERGE_OP[spec.op], c.data)
             validity = c.validity
             if validity is not None:
                 validity = jax.lax.psum(validity.astype(jnp.int32), AXIS) > 0
@@ -160,8 +171,8 @@ def dist_group_aggregate_dense(batch: ColumnBatch, key_names: list[str],
 
     out_specs = jax.tree.map(lambda _: P(), _shape_probe(batch, key_names,
                                                          domains, parts))
-    fn = shard_map(local, mesh=mesh, in_specs=(in_specs,),
-                   out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(in_specs,),
+                       out_specs=out_specs, check_vma=False)
     merged = fn(batch)
     return finalize_partials(merged, fin, key_names)
 
@@ -231,8 +242,8 @@ def dist_group_aggregate_partial_shuffled(batch: ColumnBatch,
 
     probe = jax.eval_shape(probe_fn, _shard_view(batch, n))
     out_specs = (jax.tree.map(lambda _: P(AXIS), probe), P(), P())
-    fn = shard_map(local, mesh=mesh, in_specs=(in_specs,),
-                   out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(in_specs,),
+                       out_specs=out_specs, check_vma=False)
     out, s_ovf, g_ovf = fn(batch)
     return out, (s_ovf, g_ovf)
 
@@ -262,7 +273,7 @@ def dist_scalar_aggregate(batch: ColumnBatch, specs: list[AggSpec],
         cols = []
         for name, c in zip(part.names, part.columns):
             spec = next(s for s in parts if s.out_name == name)
-            merged = _merge_collective(MERGE_OP[spec.op], c.data, AXIS)
+            merged = merge_collective(MERGE_OP[spec.op], c.data)
             validity = c.validity
             if validity is not None:
                 validity = jax.lax.psum(validity.astype(jnp.int32), AXIS) > 0
@@ -271,7 +282,7 @@ def dist_scalar_aggregate(batch: ColumnBatch, specs: list[AggSpec],
 
     out_probe = jax.eval_shape(lambda b: scalar_aggregate(b, parts), batch)
     out_specs = jax.tree.map(lambda _: P(), out_probe)
-    fn = shard_map(local, mesh=mesh, in_specs=(in_specs,),
-                   out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(in_specs,),
+                       out_specs=out_specs, check_vma=False)
     merged = fn(batch)
     return finalize_partials(merged, fin, [])

@@ -22,11 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# version-adapted shard_map (experimental-vs-promoted import, check_rep vs
-# check_vma kwarg); re-exported here because every mesh consumer pulls it
-# from this module alongside AXIS
-from ..utils.jax_compat import shard_map  # noqa: F401
-
 from ..column.batch import Column, ColumnBatch, bucket_capacity, pad_batch
 
 AXIS = "shard"

@@ -32,7 +32,7 @@ from ..column.batch import Column, ColumnBatch
 from ..ops import join as join_ops
 from ..ops.hashagg import AggSpec, group_aggregate_sorted
 from ..utils.hashing import partition_ids
-from .mesh import AXIS, shard_map
+from .mesh import AXIS
 
 
 def partition_key_arrays(b: ColumnBatch, key_names: list[str]) -> list:
@@ -139,8 +139,8 @@ def dist_hash_repartition(batch: ColumnBatch, key_names: list[str], mesh,
     # output pytree structure == input batch structure (cols+sel), so reuse it
     # as the out_specs template (eval_shape can't trace the collectives)
     out_specs = (jax.tree.map(lambda _: P(AXIS), batch), P())
-    fn = shard_map(local, mesh=mesh, in_specs=(in_specs,), out_specs=out_specs,
-                   check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(in_specs,),
+                       out_specs=out_specs, check_vma=False)
     return fn(batch)
 
 
@@ -183,8 +183,8 @@ def dist_join(probe: ColumnBatch, probe_keys: list[str],
                                    cap=local_cap)[0],
         probe_local, build_local)
     out_specs = (jax.tree.map(lambda _: P(AXIS), out_probe), P())
-    fn = shard_map(local, mesh=mesh, in_specs=(in_p, in_b),
-                   out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(in_p, in_b),
+                       out_specs=out_specs, check_vma=False)
     out, ovf_j = fn(pshard, bshard)
     return out, (ovf_p, ovf_b, ovf_j)
 
@@ -236,8 +236,8 @@ def dist_multiway_join(probe: ColumnBatch, probe_keys: list[str],
             cap=local_cap, level_keys=level_keys, packs=packs)[0],
         *locals_)
     out_specs = (jax.tree.map(lambda _: P(AXIS), out_probe), P())
-    fn = shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     out, ovf_j = fn(pshard, *bshards)
     return out, (ovf_p, ovf_b, ovf_j)
 
@@ -269,7 +269,7 @@ def dist_group_aggregate_shuffled(batch: ColumnBatch, key_names: list[str],
         _local_view(shard, n))
     probe = ColumnBatch(probe.names, probe.columns, probe.sel, None)
     out_specs = (jax.tree.map(lambda _: P(AXIS), probe), P())
-    fn = shard_map(local, mesh=mesh, in_specs=(in_specs,), out_specs=out_specs,
-                   check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(in_specs,),
+                       out_specs=out_specs, check_vma=False)
     out, group_ovf = fn(shard)
     return out, (ovf, group_ovf)

@@ -5,7 +5,7 @@ with >5x speedup on CPU.  Prints ONE JSON line.
 
 Run: python -m baikaldb_tpu.tools.bench_ann [--rows 1000000] [--dim 128]
      [--queries 32] [--k 10]
-CPU: PYTHONPATH= JAX_PLATFORMS=cpu python -m baikaldb_tpu.tools.bench_ann
+Runs on the backend the process has; the JSON line names it.
 """
 
 from __future__ import annotations

@@ -22,7 +22,13 @@ import time
 
 from ..utils.net import RpcClient
 
-_ENV = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="")
+# Meta and store daemons hold no chip by design today: a chip belongs to
+# one process, and on a one-chip host that process is the frontend, where
+# the analytical operators run.  The daemons' own jax work (compiled
+# pushed-down fragments) is pinned to the CPU explicitly.  A frontend
+# inherits the caller's platform; a second frontend on a one-chip host then
+# fails to initialise its backend, loudly.
+_DAEMON_ENV = {"JAX_PLATFORMS": "cpu"}
 
 
 def _repo_root() -> str:
@@ -30,7 +36,7 @@ def _repo_root() -> str:
         os.path.abspath(__file__))))
 
 
-def _spawn(args: list[str]) -> subprocess.Popen:
+def _spawn(args: list[str], env_extra: dict | None = None) -> subprocess.Popen:
     log_dir = os.environ.get("BK_CLUSTER_LOGS")
     if log_dir:
         os.makedirs(log_dir, exist_ok=True)
@@ -40,7 +46,8 @@ def _spawn(args: list[str]) -> subprocess.Popen:
         out = open(os.path.join(log_dir, name + ".log"), "ab")
     else:
         out = subprocess.DEVNULL
-    return subprocess.Popen([sys.executable, "-m"] + args, env=_ENV,
+    return subprocess.Popen([sys.executable, "-m"] + args,
+                            env=dict(os.environ, **(env_extra or {})),
                             cwd=_repo_root(), stdout=out, stderr=out)
 
 
@@ -69,7 +76,7 @@ def spawn_cluster(n_stores: int = 3, base_port: int = 9100,
     meta_addr = f"127.0.0.1:{base_port}"
     procs = {"meta": _spawn(["baikaldb_tpu.server.meta_server",
                              "--address", meta_addr,
-                             "--peer-count", str(n_stores)]),
+                             "--peer-count", str(n_stores)], _DAEMON_ENV),
              "stores": [], "mysql": None, "mysqls": []}
     _wait_ping(meta_addr)
     for i in range(1, n_stores + 1):
@@ -80,7 +87,7 @@ def spawn_cluster(n_stores: int = 3, base_port: int = 9100,
             cmd += ["--aot-dir", os.path.join(aot_dir, f"store{i}")]
         if cold_dir:
             cmd += ["--cold-dir", os.path.join(cold_dir, f"store{i}")]
-        procs["stores"].append(_spawn(cmd))
+        procs["stores"].append(_spawn(cmd, _DAEMON_ENV))
         _wait_ping(addr)
     if mysql_port and n_mysql > 0:
         for j in range(n_mysql):

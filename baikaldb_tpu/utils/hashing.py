@@ -19,22 +19,28 @@ def split64(x):
     64-bit ``bitcast_convert``.
 
     TPU's X64-elimination pass cannot rewrite ``bitcast_convert`` involving
-    64-bit element types AT ALL (it aborts compilation), so integers split
+    64-bit element types AT ALL (it aborts compilation) — and ``jnp.frexp``
+    / ``jnp.signbit`` of a float64 lower to exactly that.  So integers split
     arithmetically (mask + shift — ops the eliminator does rewrite) and
-    float64 decomposes via frexp into an exact (sign, exponent, 53-bit
-    mantissa) -> two u32 words.  For integers the result is bit-identical to
-    the old bitcast; for floats it is a different (still deterministic,
-    collision-free) 64-bit image, which is all hashing needs.
+    float64 splits into its nearest float32 plus the float32 of what that
+    left over: two 32-bit bitcasts.  For integers the result is bit-
+    identical to a 64-bit bitcast; for floats it is a deterministic image of
+    the top ~48 significand bits (all a v5e keeps of a DOUBLE anyway), which
+    is all hashing needs — equal values map alike.  The limit: a value past
+    the float32 range shares its sign's infinity image, and one below it
+    (~1e-38) loses its remainder or shares the zero image.  The v5e holds no
+    such DOUBLE; on the CPU a key column made of them repartitions onto one
+    shard and counts as one value in the HLL sketch (APPROX_COUNT_DISTINCT
+    under-counts them).  Exact joins and group-bys compare the keys
+    themselves, so for them it is skew, never a wrong answer.
     """
     x = jnp.asarray(x)
     if x.dtype.kind == "f":
-        neg = jnp.signbit(x)
-        m, e = jnp.frexp(jnp.abs(x))
-        m53 = m * (2.0 ** 53)               # integer-valued f64 < 2**53
-        lo = (m53 % 4294967296.0).astype(jnp.uint32)
-        hi = (m53 // 4294967296.0).astype(jnp.uint32)      # < 2**21
-        hi = hi ^ (e.astype(jnp.uint32) << 21) ^ (neg.astype(jnp.uint32) << 31)
-        return lo, hi
+        hi = x.astype(jnp.float32)
+        hi = jnp.where(jnp.isnan(hi), jnp.float32(jnp.nan), hi)  # one NaN image
+        lo = jnp.where(jnp.isfinite(hi), x - hi.astype(x.dtype),
+                       jnp.zeros((), x.dtype)).astype(jnp.float32)
+        return lo.view(jnp.uint32), hi.view(jnp.uint32)
     lo = (x & jnp.asarray(0xFFFFFFFF, x.dtype)).astype(jnp.uint32)
     hi = ((x >> 32) & jnp.asarray(0xFFFFFFFF, x.dtype)).astype(jnp.uint32)
     return lo, hi

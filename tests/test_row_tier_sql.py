@@ -162,7 +162,7 @@ def test_kill9_recovery(tmp_path):
         print("READY", flush=True)
         os.kill(os.getpid(), 9)
     """)
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run([sys.executable, "-c", child], env=env,
                        capture_output=True, text=True, timeout=120)
     assert p.returncode == -signal.SIGKILL and "READY" in p.stdout, p.stderr
