@@ -949,22 +949,22 @@ class Session:
 
     def _execute(self, sql: str) -> Result:
         progress.current().beat(phase="parse")
-        with trace.span("parse"):
+        with trace.span("parse.sql"):
             stmts = parse_sql(sql)
-        if self.db.qos is not None:
-            # COMMIT/ROLLBACK are exempt: shedding load must never pin open
-            # transactions; batches are charged per statement
-            billable = sum(1 for s in stmts if not isinstance(s, TxnStmt))
-            if billable:
-                self.db.qos.admit(sql, cost=float(billable),
-                                  user=self.user,
-                                  tables=self._qos_tables(stmts))
         if len(stmts) == 1 and isinstance(stmts[0], SelectStmt):
-            self._access_check(stmts[0])
-            stmt, env = self._resolve_session_exprs(stmts[0])
+            # select.route is everything between the parser and the plan
+            # cache, in four blocks that sum under the one key: admission
+            # and the access check here, the snapshot scope's probes
+            # (_snapshot_pinned), the fast paths that may answer instead
+            # and the auto-parameterization (_select_impl)
+            with trace.span("select.route"):
+                self._qos_admit(sql, stmts)
+                self._access_check(stmts[0])
+                stmt, env = self._resolve_session_exprs(stmts[0])
             # env-substituted literals are session state: never cache those
             return self._select(stmt, cache_key=None if env
                                 else (sql, self.current_db))
+        self._qos_admit(sql, stmts)
         res = Result()
         for s in stmts:
             # check immediately before EACH statement: an earlier USE in the
@@ -972,6 +972,16 @@ class Session:
             self._access_check(s)
             res = self._execute_stmt(s)
         return res
+
+    def _qos_admit(self, sql: str, stmts) -> None:
+        if self.db.qos is None:
+            return
+        # COMMIT/ROLLBACK are exempt: shedding load must never pin open
+        # transactions; batches are charged per statement
+        billable = sum(1 for s in stmts if not isinstance(s, TxnStmt))
+        if billable:
+            self.db.qos.admit(sql, cost=float(billable), user=self.user,
+                              tables=self._qos_tables(stmts))
 
     def query(self, sql: str) -> list[dict]:
         return self.execute(sql).to_pylist()
@@ -2249,18 +2259,23 @@ class Session:
         region pre-images; COMMIT is one atomic WAL batch per table."""
         if s.kind == "begin":
             # a new BEGIN implicitly commits any previous txn (MySQL behavior)
+            open_txn = self._sql_txn is not None
             self._commit_txn()
+            if open_txn:
+                metrics.txn_commits.add(1)
             self._sql_txn = {}
             return Result()
         if self._sql_txn is None:
             return Result()      # COMMIT/ROLLBACK outside txn: no-op
         if s.kind == "commit":
             self._commit_txn()
+            metrics.txn_commits.add(1)
             return Result()
         for tctx in self._sql_txn.values():
             tctx.rollback()
         self._sql_txn = None
         self._txn_binlog.clear()    # rolled back: subscribers never see these
+        metrics.txn_rollbacks.add(1)
         return Result()
 
     def _commit_txn(self):
@@ -2683,20 +2698,24 @@ class Session:
         if len(set(names)) != len(names):
             return None     # duplicate output names: the device path's
             #                 rename-dedup behavior must not change shape
-        try:
-            row = store.point_lookup(pk)
-        except Exception:
-            return None         # any host-index hiccup: run the full path
-        metrics.point_lookups.add(1)
-        sch = schema_to_arrow(store.info.schema)
-        cols: dict = {}
-        for it, out_name in zip(self._expand_items(stmt.items, store), names):
-            cname = it
-            cols[out_name] = pa.array(
-                [None if row is None else row.get(cname)],
-                sch.field(cname).type)
-        t = pa.table(cols) if row is not None else \
-            pa.table({n: c.slice(0, 0) for n, c in cols.items()})
+        # the row tier answers: no query_log row is written on this path,
+        # so the time also feeds a counter
+        with trace.timed("point.lookup", table=key) as sp:
+            try:
+                row = store.point_lookup(pk)
+            except Exception:
+                return None     # any host-index hiccup: run the full path
+            metrics.point_lookups.add(1)
+            sch = schema_to_arrow(store.info.schema)
+            cols: dict = {}
+            for cname, out_name in zip(self._expand_items(stmt.items, store),
+                                       names):
+                cols[out_name] = pa.array(
+                    [None if row is None else row.get(cname)],
+                    sch.field(cname).type)
+            t = pa.table(cols) if row is not None else \
+                pa.table({n: c.slice(0, 0) for n, c in cols.items()})
+        metrics.point_lookup_ms.add(sp.ms)
         return Result(columns=names, arrow=t)
 
     def _expand_items(self, items, store):
@@ -3911,7 +3930,8 @@ class Session:
     @contextmanager
     def _snapshot_pinned(self, stmt: SelectStmt):
         """Enter this SELECT's snapshot scope (see _snapshot_scope)."""
-        pin = self._snapshot_scope(stmt)
+        with trace.span("select.route"):
+            pin = self._snapshot_scope(stmt)
         if pin is None:
             yield
             return
@@ -3988,7 +4008,9 @@ class Session:
         store = self.db.stores.get(f"{dbname}.{t.name}")
         if store is None:
             return False    # view / info-schema / unstaged: nothing to pin
-        return store.mvcc_needs_versioned(self._snap_ts)
+        with trace.span("mvcc.dirty_check", table=f"{dbname}.{t.name}",
+                        live_rows=store.num_rows):
+            return store.mvcc_needs_versioned(self._snap_ts)
 
     def _select_impl(self, stmt: SelectStmt, cache_key=None) -> Result:
         """Plan cache (reference: state_machine.cpp:1984): one logical plan
@@ -3997,9 +4019,59 @@ class Session:
 
         if stmt.into_outfile is not None:
             return self._select_into_outfile(stmt, cache_key)
+        with trace.span("select.route"):
+            answer, stmt, cache_key = self._route_fast_paths(stmt, cache_key)
+        if answer is not None:
+            return answer
+
+        def _has_gc(e):
+            if e is None:
+                return False
+            if isinstance(e, AggCall) and e.op == "group_concat":
+                return True
+            return any(_has_gc(a) for a in getattr(e, "args", ()))
+
+        if any(_has_gc(it.expr) for it in stmt.items) or _has_gc(stmt.having) \
+                or any(_has_gc(o.expr) for o in stmt.order_by):
+            return self._select_group_concat(stmt)
+        with trace.span("select.route"):
+            stmt_run, lookup_key, norm = self._route_paramize(stmt, cache_key)
+        if norm is None:
+            return self._select_cached(stmt, cache_key, cache_key, None)
+        from ..expr.compile import ExprError
+        from ..expr.params import ParamError
+        self._param_counted = False
+        try:
+            return self._select_cached(stmt_run, cache_key, lookup_key, norm)
+        except (paramize.BindError, ExprError, ParamError, PlanError):
+            # conservative valve: anything the parameterized path cannot
+            # express replans with baked literals (a genuine user error
+            # re-raises identically from the baked run)
+            self._plan_cache.pop(lookup_key, None)
+            # hold the one-count-per-SELECT invariant: the baked re-run
+            # only counts if the param attempt died before its counter
+            self._qlog_outcome = "fallback"   # query_log: WHY it was slow
+            try:
+                res = self._select_cached(stmt, cache_key, cache_key, None,
+                                          count=not self._param_counted)
+            finally:
+                self._qlog_outcome = None
+            # counted only when the baked run SUCCEEDED: a genuine user
+            # error (unknown column, bad subquery) re-raised above and is
+            # not a param-machinery fallback — the metric stays an alarm
+            # for the parameterized path itself
+            metrics.plan_cache_param_fallbacks.add(1)
+            return res
+        finally:
+            self._where_sel_hint = None
+
+    def _route_fast_paths(self, stmt: SelectStmt, cache_key) -> tuple:
+        """The paths that answer a SELECT without the plan cache (pushdown,
+        egress, point lookup), then the matview / rollup rewrite.
+        -> (Result | None, the statement to plan, its cache key)."""
         pushed = self._try_pushdown(stmt)
         if pushed is not None:
-            return pushed
+            return pushed, stmt, cache_key
         from . import egress as egress_mod
         # pinned snapshot over a table with version churn: egress streaming
         # and rowstore point lookups read the physically-latest image
@@ -4009,10 +4081,10 @@ class Session:
         snap_dirty = self._snap_dirty(stmt)
         eg = None if snap_dirty else egress_mod.extract(stmt, self)
         if eg is not None:
-            return self._select_egress(eg, cache_key)
+            return self._select_egress(eg, cache_key), stmt, cache_key
         point = None if snap_dirty else self._try_point_lookup(stmt)
         if point is not None:
-            return point
+            return point, stmt, cache_key
         rewritten = self._try_matview(stmt)
         if rewritten is not None:
             # answered from incrementally maintained view state: re-enter
@@ -4028,25 +4100,18 @@ class Session:
                 stmt = rewritten
                 cache_key = None if cache_key is None else \
                     (cache_key[0] + " /*rollup*/", cache_key[1])
+        return None, stmt, cache_key
 
-        def _has_gc(e):
-            if e is None:
-                return False
-            if isinstance(e, AggCall) and e.op == "group_concat":
-                return True
-            return any(_has_gc(a) for a in getattr(e, "args", ()))
-
-        if any(_has_gc(it.expr) for it in stmt.items) or _has_gc(stmt.having) \
-                or any(_has_gc(o.expr) for o in stmt.order_by):
-            return self._select_group_concat(stmt)
-        # auto-parameterization (plan/paramize.py): hoist WHERE literals
-        # into a runtime params vector and key the plan cache on the
-        # canonical statement structure — WHERE id = 42 and WHERE id = 43
-        # share one entry AND one compiled executable.  Mesh programs
-        # participate too: the executor's per-leaf in_specs replicate the
-        # params feed (P()) while batches shard P(AXIS), so one shard_map
-        # executable serves every literal variant — without this, the big
-        # MPP programs (fused multiway exchange) would fork per WHERE value.
+    def _route_paramize(self, stmt: SelectStmt, cache_key) -> tuple:
+        """Auto-parameterization (plan/paramize.py): hoist WHERE literals
+        into a runtime params vector and key the plan cache on the
+        canonical statement structure — WHERE id = 42 and WHERE id = 43
+        share one entry AND one compiled executable.  Mesh programs
+        participate too: the executor's per-leaf in_specs replicate the
+        params feed (P()) while batches shard P(AXIS), so one shard_map
+        executable serves every literal variant — without this, the big
+        MPP programs (fused multiway exchange) would fork per WHERE value.
+        -> (the statement to run, the plan-cache key, norm | None)."""
         norm = None
         lookup_key = cache_key
         stmt_run = stmt
@@ -4085,39 +4150,24 @@ class Session:
                     cls = selectivity_class(wsel)
                     if cls > 0:
                         lookup_key = lookup_key + (f"selcls{cls}",)
-        if norm is None:
-            return self._select_cached(stmt, cache_key, cache_key, None)
-        from ..expr.compile import ExprError
-        from ..expr.params import ParamError
-        self._param_counted = False
-        try:
-            return self._select_cached(stmt_run, cache_key, lookup_key, norm)
-        except (paramize.BindError, ExprError, ParamError, PlanError):
-            # conservative valve: anything the parameterized path cannot
-            # express replans with baked literals (a genuine user error
-            # re-raises identically from the baked run)
-            self._plan_cache.pop(lookup_key, None)
-            # hold the one-count-per-SELECT invariant: the baked re-run
-            # only counts if the param attempt died before its counter
-            self._qlog_outcome = "fallback"   # query_log: WHY it was slow
-            try:
-                res = self._select_cached(stmt, cache_key, cache_key, None,
-                                          count=not self._param_counted)
-            finally:
-                self._qlog_outcome = None
-            # counted only when the baked run SUCCEEDED: a genuine user
-            # error (unknown column, bad subquery) re-raised above and is
-            # not a param-machinery fallback — the metric stays an alarm
-            # for the parameterized path itself
-            metrics.plan_cache_param_fallbacks.add(1)
-            return res
-        finally:
-            self._where_sel_hint = None
+        return stmt_run, lookup_key, norm
 
     def _select_cached(self, stmt: SelectStmt, text_key, lookup_key,
                        norm, count: bool = True) -> Result:
         qp = progress.current()
         qp.beat(phase="plan")
+        with trace.span("plan.cache") as sp:
+            entry, qlog_outcome = self._plan_entry(stmt, text_key,
+                                                   lookup_key, norm, count)
+            sp.set(outcome=qlog_outcome)
+        return self._run_entry(entry, qlog_outcome, text_key, lookup_key,
+                               norm)
+
+    def _plan_entry(self, stmt: SelectStmt, text_key, lookup_key, norm,
+                    count: bool) -> tuple:
+        """The plan-cache lookup, its staleness check and, on a miss or a
+        stale entry, the (re)plan.  -> (entry, the outcome query_log
+        shows)."""
         entry = self._plan_cache.get(lookup_key) if lookup_key else None
         replanned = False
         if entry is not None:
@@ -4186,8 +4236,12 @@ class Session:
             self._param_counted = True
         # the query_log row reports the param-machinery fallback, not the
         # baked re-run's own hit/miss — that's the "why was it slow" signal
-        qlog_outcome = getattr(self, "_qlog_outcome", None) or outcome
-        trace.event("plan.cache", outcome=qlog_outcome)
+        return entry, getattr(self, "_qlog_outcome", None) or outcome
+
+    def _run_entry(self, entry: dict, qlog_outcome: str, text_key,
+                   lookup_key, norm) -> Result:
+        """Stage, run and egress a plan-cache entry; log the statement."""
+        qp = progress.current()
         plan = entry["plan"]
         # forensic-dump reference + progress denominators (host plan walk,
         # cached on the entry): SHOW PROCESSLIST renders "batch m/n" /
@@ -4232,8 +4286,10 @@ class Session:
             # capacity buckets the scan batches compiled against
             buckets = ";".join(f"{p[0]}={p[2]}"
                                for p in sorted(shape_key))
+            # dur_ms (the duration_ms column) runs from after staging;
+            # the dict's ``query`` is the statement's whole wall time
             self.db.query_log.append((text_key[0], dur_ms, table.num_rows,
-                                      qlog_outcome, buckets, qp.phase_ms(),
+                                      qlog_outcome, buckets, qp.logged_ms(),
                                       self._snap_ts))
         return Result(columns=list(table.column_names), arrow=table)
 
@@ -4311,7 +4367,8 @@ class Session:
         # describe the plan that actually runs, not a truncated first attempt
         entry = {"plan": plan, "compiled": {}, "versions": {}}
         self._run_plan(entry, batches, shape_key)
-        if streaming.stream_source(batches) is not None:
+        streamed = streaming.stream_source(batches) is not None
+        if streamed:
             # chunk-folded execution: there is no single jitted program to
             # re-run under the counting tracer (the scan input is a host
             # chunk iterator) — ops render uncounted; the measured fold
@@ -4373,8 +4430,11 @@ class Session:
                     deser_avg_ms=dstats["avg_ms"])
         # device-resource accounting for THIS plan's executable (same rows
         # as information_schema.executables): what the program costs the
-        # accelerator, not just how long the host waited
-        if compilecache.EXECUTABLES.enabled():
+        # accelerator, not just how long the host waited.  A streamed
+        # statement has no such executable (its fold step and finalize are
+        # jitted outside the plan's entry; the `stream` event has what it
+        # ran): asking would find the newest OTHER one
+        if compilecache.EXECUTABLES.enabled() and not streamed:
             dev = compilecache.EXECUTABLES.find(
                 plan_sig=entry.get("plan_sig"))
             if dev is not None:
@@ -4557,15 +4617,17 @@ class Session:
         from ..storage.mvcc import visibility_mask
 
         snap = self._snap_ts
-        with trace.span("mvcc.visibility", table=table_key, ts=snap):
+        with trace.span("mvcc.visibility", table=table_key, ts=snap) as sp:
             sv = store.snapshot_versions(snap)
             wm = self.db.mvcc.snapshots.watermark(
                 self.db.mvcc.tso.last_ts())
             if sv is None:
+                sp.set(versions_scanned=0)
                 trace.event("snapshot", ts=snap, table=table_key,
                             versions_scanned=0, gc_watermark=wm)
                 return None
             tbl, cts, dts, nver = sv
+            sp.set(versions_scanned=nver)
             b = ColumnBatch.from_arrow(tbl)
             mask = visibility_mask(jnp.asarray(cts), jnp.asarray(dts),
                                    jnp.int64(snap))
@@ -4643,7 +4705,11 @@ class Session:
                     if n.ann is not None:
                         b = self._ann_batch(n, store)
                     if b is None:
-                        b = self._access_path_batch(n, db, name, store)
+                        with trace.span("access.path",
+                                        table=n.table_key) as sp:
+                            b = self._access_path_batch(n, db, name, store)
+                            if b is not None:
+                                sp.set(rows=len(b))
                 if b is None:
                     if self.mesh is not None:
                         b = self._sharded_batch(n.table_key, store)
@@ -4654,9 +4720,17 @@ class Session:
                         # whole table; _run_plan folds it chunk by chunk.
                         # NOT a full_scan member: presort permutations and
                         # the batched dispatcher need resident positions
-                        b = self._maybe_stream_source(plan, n, store)
+                        with trace.span("stream.source",
+                                        table=n.table_key) as sp:
+                            b = self._maybe_stream_source(plan, n, store)
+                            if b is not None:
+                                sp.set(chunks_kept=len(b.keep),
+                                       chunks=b.chunks.n_chunks)
                         if b is None:
-                            b = store.device_table_batch()
+                            with trace.span("stage.resident",
+                                            table=n.table_key) as sp:
+                                b = store.device_table_batch()
+                                sp.set(rows=len(b))
                             full_scan.add(n.table_key)
                 batches[n.table_key] = b
                 # snapped batches append a constant marker, NOT the ts:
@@ -5598,7 +5672,12 @@ class Session:
             # ONE explicit transfer for every overflow flag: int(flag) per
             # join would block on a device round-trip once per node
             # (tpulint HOSTSYNC)
-            host_flags = jax.device_get(flags)
+            with trace.span("exec.flags"):
+                # where the plan has flags (joins, shuffles, scalar
+                # subqueries) the host blocks here until its program has
+                # run; a flag-less plan returns at once and first waits in
+                # egress.count
+                host_flags = jax.device_get(flags)
             if mesh is not None:
                 # the one device program carried every planned collective:
                 # all rounds are behind us once the flags landed on host
@@ -5675,31 +5754,27 @@ class Session:
             # pay because an input was already partitioned on the key class
             metrics.shuffle_rounds_saved.add(summary["reused"])
         if not trace.active():
-            # tracing off: the counter above is the whole cost — no plan
-            # walk, no per-node span churn on the hot path
+            # no trace tree: the counter above is the whole cost — no plan
+            # walk, no per-node event churn on the hot path
             return
         for node, flag in zip(join_order, host_flags):
             needed = int(flag)
             if isinstance(node, ExchangeNode) and node.kind == "repartition":
-                with trace.span("mpp.repartition",
-                                keys=",".join(node.keys or ()),
-                                cap=int(node.cap or 0), occupancy=needed):
-                    pass
+                trace.event("mpp.repartition",
+                            keys=",".join(node.keys or ()),
+                            cap=int(node.cap or 0), occupancy=needed)
             elif isinstance(node, _CapBox) and node.kind == "shuffle":
-                with trace.span("mpp.repartition", site=node.site,
-                                cap=int(node.cap or 0), occupancy=needed):
-                    pass
+                trace.event("mpp.repartition", site=node.site,
+                            cap=int(node.cap or 0), occupancy=needed)
             elif isinstance(node, MultiJoinNode):
-                with trace.span("mpp.join", strategy="multiway",
-                                builds=len(node.children) - 1, rows=needed,
-                                cap=int(node.cap or 0)):
-                    pass
+                trace.event("mpp.join", strategy="multiway",
+                            builds=len(node.children) - 1, rows=needed,
+                            cap=int(node.cap or 0))
             elif isinstance(node, JoinNode) and any(
                     isinstance(c, ExchangeNode) and c.kind == "repartition"
                     for c in node.children):
-                with trace.span("mpp.join", strategy="chained", rows=needed,
-                                cap=int(node.cap or 0)):
-                    pass
+                trace.event("mpp.join", strategy="chained", rows=needed,
+                            cap=int(node.cap or 0))
 
         seen: set = set()
 
@@ -5708,9 +5783,8 @@ class Session:
                 return
             seen.add(id(n))
             if isinstance(n, AggNode) and getattr(n, "agg_dist", ""):
-                with trace.span("mpp.agg", strategy=n.agg_dist,
-                                agg_kind=n.strategy):
-                    pass
+                trace.event("mpp.agg", strategy=n.agg_dist,
+                            agg_kind=n.strategy)
             for c in n.children:
                 walk(c)
 
@@ -5732,7 +5806,11 @@ class Session:
             return compact(batch)
         sel = batch.sel_mask()
         cs = jnp.cumsum(sel.astype(jnp.int32))
-        n = int(jax.device_get(cs[-1]))         # egress: one scalar fetch
+        with trace.span("egress.count"):
+            # egress: one scalar fetch.  The host's first wait for the chip
+            # in a statement whose plan has no overflow flags: the plan's
+            # program and the cumsum run, behind every other session's
+            n = int(jax.device_get(cs[-1]))
         cap = min(len(batch), max(16, 1 << max(0, n - 1).bit_length()))
         # index of the k-th live row = first i with cumsum[i] >= k; a
         # vectorized binary search, not jnp.nonzero (whose CPU lowering is

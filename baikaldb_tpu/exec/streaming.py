@@ -37,7 +37,6 @@ for everything streaming accepts.
 from __future__ import annotations
 
 import copy
-import time
 import warnings
 
 import jax
@@ -304,7 +303,11 @@ class StreamRunner:
             while True:
                 self._ensure_step(source, params)
                 acc, ovf = self._fold(source, params, qp, stats)
-                if not bool(jax.device_get(ovf)):
+                with trace.span("stream.sync"):
+                    # the host blocks here until the chip has folded the
+                    # last chunk
+                    overflowed = bool(jax.device_get(ovf))
+                if not overflowed:
                     break
                 # sorted accumulator overflowed: the only carry-dependent
                 # capacity.  Grow (bounded by the table's row count — the
@@ -330,32 +333,28 @@ class StreamRunner:
         dead = not source.keep
         ids = source.keep or [0]
 
-        def load(i):
-            t0 = time.perf_counter()
-            dev, nbytes = cs.load_chunk(i, dead=dead)
-            return dev, nbytes, (time.perf_counter() - t0) * 1e3
-
         # the shared double-buffer discipline (utils/prefetch.staged):
         # chunk i+1 stages on a daemon thread while chunk i folds — the
         # same staging the store daemons use for cold-segment fragment
         # folds, so both planes keep one prefetch truth
-        it = staged(ids, load, name="stream-prefetch")
+        it = staged(ids, lambda i: cs.load_chunk(i, dead=dead),
+                    name="stream-prefetch")
         carry = (_dead_zeros(self._acc_struct), jnp.asarray(False))
+        staged_ms: dict = {}
         try:
             for m, i in enumerate(ids):
                 if qp is not None:
                     qp.beat(operator=f"StreamScan({self.table_key})",
                             chunk_no=m, chunks_total=len(ids))
-                with trace.span("stream.prefetch", chunk=i) as sp:
-                    t0 = time.perf_counter()
-                    _i, (dev, nbytes, stage_ms) = next(it)
-                    wait = (time.perf_counter() - t0) * 1e3
-                    sp.set(wait_ms=round(wait, 3))
+                with trace.timed("stream.prefetch", chunk=i) as sp:
+                    _i, (dev, nbytes, seams) = next(it)
+                wait = sp.ms
                 metrics.stream_prefetch_wait_ms.observe(wait)
                 metrics.stream_bytes_h2d.add(nbytes)
                 stats["prefetch_wait_ms"] += wait
-                stats["stage_ms"] += stage_ms
                 stats["bytes_h2d"] += nbytes
+                for seam, ms in seams.items():
+                    staged_ms[seam] = staged_ms.get(seam, 0.0) + ms
                 with trace.span("stream.fold", chunk=i):
                     carry = self._jit_step(carry, dev, params)
                 if not dead:
@@ -365,6 +364,12 @@ class StreamRunner:
                 qp.beat(chunk_no=len(ids), chunks_total=len(ids))
         finally:
             it.close()      # stops the stager and drains on early exit
+            # the stager thread's seams (storage/streamchunks.load_chunk),
+            # credited to the statement once per fold: they overlap the
+            # loop above, and stage_ms is their sum
+            for seam, ms in staged_ms.items():
+                trace.add(seam, ms)
+                stats["stage_ms"] += ms
         return carry
 
     def _run_finalize(self, acc: ColumnBatch, params) -> ColumnBatch:

@@ -120,7 +120,7 @@ class QueryProgress:
                  "rounds_total", "chunk_no", "chunks_total",
                  "queue_wait_ms", "started", "beat_mono",
                  "token", "plan", "exchange", "stalled", "_phase_mono",
-                 "_phase_ms")
+                 "_phase_ms", "_t0", "span_depth", "_tiled_ms")
 
     def __init__(self, text: str, conn_id: int = 0, user: str = "",
                  host: str = "embedded", db=None, dbname: str = ""):
@@ -150,7 +150,13 @@ class QueryProgress:
         self.exchange = None         # exchange_summary dict when MPP ran
         self.stalled = False         # set by the watchdog, never cleared
         self._phase_mono = self.beat_mono
+        # coarse buckets (first dotted segment of the beats' phases: no
+        # dot) and the wall time of every obs/trace span (full dotted
+        # name: always a dot), in one dict
         self._phase_ms: dict[str, float] = {}
+        self._t0 = self.beat_mono    # the statement began
+        self.span_depth = 0          # open obs/trace spans on this thread
+        self._tiled_ms = 0.0         # covered by depth-0 spans so far
 
     # -- the hot hook ------------------------------------------------------
     def beat(self, phase: Optional[str] = None,
@@ -160,8 +166,8 @@ class QueryProgress:
         now = time.monotonic()
         self.beat_mono = now
         if phase is not None and phase != self.phase:
-            # close the previous phase's wall-clock bucket (the query_log
-            # fallback timing source when tracing is off)
+            # close the previous phase's wall-clock bucket (query_log's
+            # four coarse keys; obs/trace spans add the dotted ones)
             self._phase_ms[self.phase.split(".", 1)[0]] = \
                 self._phase_ms.get(self.phase.split(".", 1)[0], 0.0) + \
                 (now - self._phase_mono) * 1e3
@@ -178,10 +184,39 @@ class QueryProgress:
         self.beat_mono = time.monotonic()
         self.token.check()
 
+    # -- the obs/trace span sink (attribute writes and a dict add) --------
+    def span_open(self) -> None:
+        self.span_depth += 1
+
+    def span_close(self, name: str, ms: float) -> None:
+        """A span of the driving thread closed after ``ms``."""
+        self.span_depth -= 1
+        self._phase_ms[name] = self._phase_ms.get(name, 0.0) + ms
+        if self.span_depth == 0:
+            self._tiled_ms += ms
+
+    def span_add(self, name: str, ms: float) -> None:
+        """Time measured on another thread (the stager): overlaps the
+        driving thread's spans, so it is no part of the tiling."""
+        self._phase_ms[name] = self._phase_ms.get(name, 0.0) + ms
+
     def phase_ms(self) -> dict:
-        """Closed per-phase wall-clock buckets so far (ms), keyed by the
-        phase's first dotted segment (parse/plan/exec/egress)."""
+        """Wall-clock ms so far: the beats' closed buckets, keyed by the
+        phase's first dotted segment (parse/plan/exec/egress), and every
+        closed obs/trace span under its full dotted name."""
         return dict(self._phase_ms)
+
+    def logged_ms(self) -> dict:
+        """``phase_ms`` as a query_log row carries it: plus ``query``, the
+        statement's wall time up to now, and ``untraced``, the part of it
+        that no depth-0 span covered — the next place to put a span."""
+        wall = (time.monotonic() - self._t0) * 1e3
+        out = dict(self._phase_ms)
+        out["query"] = wall
+        # not clamped: a negative value means a depth-0 span was credited
+        # twice, and a test holds it at >= 0
+        out["untraced"] = wall - self._tiled_ms
+        return out
 
     def elapsed_s(self) -> float:
         return max(0.0, time.time() - self.started)
@@ -239,6 +274,9 @@ class _NoopProgress:
     def phase_ms(self):
         return {}
 
+    def logged_ms(self):
+        return {}
+
 
 _NOOP = _NoopProgress()
 
@@ -251,6 +289,11 @@ def current():
     safe at any host-path frequency)."""
     qp = _CUR.get()
     return qp if qp is not None else _NOOP
+
+
+def live() -> Optional[QueryProgress]:
+    """The live record or None: what an obs/trace span adds its time to."""
+    return _CUR.get()
 
 
 def cancel_token() -> Optional[CancelToken]:

@@ -9,11 +9,19 @@ Computation Runtimes", "Tailwind") argues host<->device handoffs dominate
 TCR query latency and per-stage attribution is what makes them tunable.
 This module is that attribution:
 
-- ``root(kind, text)`` opens a per-query trace at the dispatch seam
-  (session execute / wire _query); ``span(name, **attrs)`` nests stages
-  under it.  Both are context managers costing one contextvar read when
-  tracing is off (the ``debug_guards`` off-switch discipline: the
-  ``tracing`` flag off means the shared no-op singleton, no allocation).
+- ``span(name, **attrs)`` is the ONE marker of a seam, and it feeds three
+  sinks from the same two clock reads: (1) always, the live statement's
+  record (``obs/progress.py``: ``qp.phase_ms()`` / the ``db.query_log``
+  row) gets the span's wall time under its full dotted name; (2) while a
+  ``jax.profiler`` trace is being taken, the span is also a
+  ``TraceAnnotation("db." + name)`` on the host plane, on the clock the
+  chip's ``XLA Ops`` share; (3) with the ``tracing`` flag on (or a forced
+  root: EXPLAIN ANALYZE) it is a node of the per-query span tree below.
+  With nothing to feed (no statement, no profiler, no trace) it is the
+  shared no-op singleton: two contextvar reads, no allocation.
+- ``root(kind, text)`` opens a per-query trace TREE at the dispatch seam
+  (session execute / wire _query); the ``tracing`` flag governs only the
+  tree (off = the no-op singleton).
 - Sampling is head-based (``trace_sample_n``: keep 1 in N roots) with an
   always-keep override for queries slower than ``slow_query_ms`` — spans
   record while a trace is live and the keep/drop decision lands at root
@@ -40,6 +48,7 @@ import contextvars
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -49,12 +58,14 @@ from typing import Any, Optional
 
 from ..utils import metrics
 from ..utils.flags import FLAGS, define
+from . import progress
 
 define("tracing", False,
-       "query-lifecycle span tracing: off = zero-overhead no-op spans "
-       "(the debug_guards off-switch discipline); on = per-query trace "
-       "trees, head-sampled by trace_sample_n with always-keep for "
-       "queries over slow_query_ms")
+       "per-query span TREES (SHOW PROFILE, information_schema.trace_spans, "
+       "cross-RPC stitching), head-sampled by trace_sample_n with "
+       "always-keep for queries over slow_query_ms; off = no tree and an "
+       "empty store.  Span wall times reach query_log and a profiler "
+       "trace either way")
 define("trace_sample_n", 1,
        "head sampling: keep 1 in N query traces (1 = every query); "
        "slow queries (> slow_query_ms) are always kept regardless")
@@ -131,7 +142,7 @@ def _record(ctx: _Ctx, rec: dict) -> None:
 
 
 class _Noop:
-    """Shared do-nothing span: the entire cost of tracing=off."""
+    """Shared do-nothing span: what a seam costs with no sink live."""
 
     __slots__ = ()
 
@@ -148,21 +159,57 @@ class _Noop:
 _NOOP = _Noop()
 
 
-class _Span:
-    __slots__ = ("ctx", "name", "attrs", "sid", "parent", "t0", "ts")
+# the statement clock: the progress beats' clock too (progress.py), so the
+# spans' times and the wall time of ``qp.logged_ms()`` subtract without skew
+_now = time.monotonic
 
-    def __init__(self, ctx: _Ctx, name: str, attrs: dict):
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler trace is being
+    taken, else None.  jax is looked up, never imported: store and meta
+    daemons import this module, and a process that has not imported jax
+    cannot be tracing.  The guard is one static call (55 ns on this
+    sandbox's CPU against 420 ns for opening an annotation that nothing
+    records), so it is checked per span."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    ann = jax.profiler.TraceAnnotation
+    return ann if ann.is_enabled() else None
+
+
+class _Span:
+    """One open seam.  ``ctx`` (the tree), ``qp`` (the statement's record)
+    and ``ann`` (the profiler annotation) are each None when that sink is
+    not live; all three are fed by the one pair of clock reads, and ``ms``
+    holds the wall time once the span has closed."""
+
+    __slots__ = ("ctx", "qp", "ann", "name", "attrs", "sid", "parent", "t0",
+                 "ts", "ms")
+
+    def __init__(self, ctx: Optional[_Ctx], qp, ann, name: str, attrs: dict):
         self.ctx = ctx
+        self.qp = qp
+        self.ann = ann
         self.name = name
         self.attrs = attrs
+        self.ms = 0.0
 
     def __enter__(self):
         ctx = self.ctx
-        self.parent = ctx.span_id
-        self.sid = _new_sid()
-        ctx.span_id = self.sid
-        self.ts = time.time() * 1e6
-        self.t0 = time.perf_counter()
+        if ctx is not None:
+            self.parent = ctx.span_id
+            self.sid = _new_sid()
+            ctx.span_id = self.sid
+            self.ts = time.time() * 1e6
+        if self.qp is not None:
+            self.qp.span_open()
+        if self.ann is not None:
+            # the class until now: a TraceMe starts when it is built.  The
+            # ``db.`` prefix keeps program spans apart from the ``client.``
+            # calls a benchmark's trace reduction reads
+            self.ann = self.ann("db." + self.name)
+        self.t0 = _now()
         return self
 
     def set(self, **attrs):
@@ -170,31 +217,62 @@ class _Span:
         return self
 
     def __exit__(self, et, ev, tb):
+        self.ms = (_now() - self.t0) * 1e3
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+        if self.qp is not None:
+            self.qp.span_close(self.name, self.ms)
         ctx = self.ctx
-        ctx.span_id = self.parent
-        if et is not None:
-            self.attrs.setdefault("error", et.__name__)
-        _record(ctx, {"span_id": self.sid, "parent_id": self.parent,
-                      "name": self.name, "ts_us": self.ts,
-                      "dur_ms": round((time.perf_counter() - self.t0) * 1e3,
-                                      4),
-                      "node": ctx.node, "attrs": self.attrs})
+        if ctx is not None:
+            ctx.span_id = self.parent
+            if et is not None:
+                self.attrs.setdefault("error", et.__name__)
+            _record(ctx, {"span_id": self.sid, "parent_id": self.parent,
+                          "name": self.name, "ts_us": self.ts,
+                          "dur_ms": round(self.ms, 4),
+                          "node": ctx.node, "attrs": self.attrs})
         return False
 
 
 def span(name: str, /, **attrs):
-    """A child span of the active trace; the no-op singleton when no trace
-    is live (one contextvar read — safe on any host path, any frequency).
-    ``name`` is positional-only so attrs may freely use any keyword."""
+    """The marker of a seam (module docstring): feeds the statement's
+    record, the profiler's host plane and the trace tree, whichever are
+    live; the no-op singleton when none is.  ``name`` is positional-only so
+    attrs may freely use any keyword."""
     ctx = _CUR.get()
-    if ctx is None:
+    qp = progress.live()
+    ann = _annotation()
+    if ctx is None and qp is None and ann is None:
         return _NOOP
-    return _Span(ctx, name, attrs)
+    return _Span(ctx, qp, ann, name, attrs)
+
+
+def timed(name: str, /, **attrs) -> _Span:
+    """``span`` for a caller that reads the time back (``.ms`` after the
+    block): never the no-op.  On a thread with no statement (the streamed
+    scan's stager) it is a stopwatch plus the profiler annotation, and the
+    caller hands the time to the statement with ``add``."""
+    return _Span(_CUR.get(), progress.live(), _annotation(), name, attrs)
+
+
+def add(name: str, ms: float, /, **attrs) -> None:
+    """Credit the live statement with ``ms`` of ``name`` measured on
+    another thread.  Overlaps the statement's own spans, so it joins the
+    record's keys and the tree but not the tiling behind ``untraced``."""
+    qp = progress.live()
+    if qp is not None:
+        qp.span_add(name, ms)
+    ctx = _CUR.get()
+    if ctx is not None:
+        _record(ctx, {"span_id": _new_sid(), "parent_id": ctx.span_id,
+                      "name": name, "ts_us": time.time() * 1e6 - ms * 1e3,
+                      "dur_ms": round(ms, 4), "node": ctx.node,
+                      "attrs": attrs})
 
 
 def active() -> bool:
-    """True when a trace is live (one contextvar read) — lets callers skip
-    building span batches whose every member would be the no-op."""
+    """True when a trace TREE is live (one contextvar read) — lets callers
+    skip building events nothing would record."""
     return _CUR.get() is not None
 
 
@@ -242,10 +320,13 @@ class _Root:
                 # the enclosing trace (a long multi-statement batch, a
                 # floor-set trace_max_spans) already spent its cap, or
                 # EXPLAIN ANALYZE would silently lose its timing lines
+                # (64: a resident plan's EXPLAIN ANALYZE records ~25 spans
+                # and events plus one per operator)
                 outer.max_spans = max(
                     outer.max_spans,
-                    outer.n + max(16, int(FLAGS.trace_max_spans)))
-            self.inner = _Span(outer, self.kind,
+                    outer.n + max(64, int(FLAGS.trace_max_spans)))
+            # tree only: a root is not a seam of the statement's record
+            self.inner = _Span(outer, None, None, self.kind,
                                {"text": self.text} if self.text else {})
             self.inner.__enter__()
             self.ctx = None
@@ -331,7 +412,7 @@ def adopt(wire: dict, name: str, node: str = ""):
         return
     ctx = _Ctx(tid, parent=str(wire.get("parent_span") or ""), node=node)
     token = _CUR.set(ctx)
-    sp = _Span(ctx, name, {})
+    sp = _Span(ctx, None, None, name, {})
     sp.__enter__()
     try:
         yield ctx.buf

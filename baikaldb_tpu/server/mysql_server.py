@@ -312,6 +312,7 @@ class MySQLServer:
 
     def _query(self, p: Packets, session: Session, sql: str):
         from ..obs import trace
+        from ..utils import metrics
 
         # wire-level trace root: session.execute's root degrades to a child
         # span under it, so a kept trace shows protocol encode time too —
@@ -326,8 +327,11 @@ class MySQLServer:
             if res.arrow is None:
                 self._ok(p, affected=res.affected_rows)
                 return
-            with trace.span("wire.result_set"):
+            # the statement's query_log row is already written: the time
+            # also feeds a counter
+            with trace.timed("wire.result_set") as sp:
                 self._result_set(p, res)
+            metrics.wire_result_set_ms.add(sp.ms)
 
     def _result_set(self, p: Packets, res: Result, binary: bool = False):
         """Column defs + text/binary rows (reference: PacketNode encode)."""

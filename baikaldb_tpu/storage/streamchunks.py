@@ -35,6 +35,7 @@ from typing import Optional
 import numpy as np
 
 from ..column.batch import Column, ColumnBatch, _arrow_to_numpy
+from ..obs import trace
 from ..types import LType
 from ..utils import metrics
 from ..utils.flags import FLAGS, define
@@ -228,44 +229,63 @@ class StreamChunkSet:
                            None, live_prefix=True)
 
     def load_chunk(self, i: int, dead: bool = False):
-        """-> (device ColumnBatch, bytes moved host->device).
+        """-> (device ColumnBatch, bytes moved host->device, {seam: ms}).
 
         Every chunk of the set has the same structure: fixed capacity,
         explicit ``sel = arange < live`` (all-False when ``dead`` — the
         empty-input stand-in when pruning removed every chunk), validity
-        arrays exactly on the columns the whole table has them."""
+        arrays exactly on the columns the whole table has them.
+
+        The three seams (segment read; parquet decode + numpy padding;
+        host->device put, which returns once the transfer is enqueued) are
+        timed here and handed back: this runs on the stager thread, which
+        has no statement to credit (exec/streaming.py does that).  Each
+        column's put is issued before the next column is padded, so decode
+        and h2d are sums over the columns."""
         import jax.numpy as jnp
         import pyarrow.parquet as pq
 
-        t = pq.read_table(io.BytesIO(self._read_segment(i)))
+        with trace.timed("stream.stage.read") as t_read:
+            payload = self._read_segment(i)
+        with trace.timed("stream.stage.decode") as sp:
+            t = pq.read_table(io.BytesIO(payload))
+        decode_ms, h2d_ms = sp.ms, 0.0
         live = 0 if dead else self.live[i]
         cap = self.capacity
         cols, nbytes = [], 0
         for name in self.names:
-            data = t.column(name).to_numpy(zero_copy_only=False)
-            data = np.ascontiguousarray(data.astype(self._dtypes[name],
-                                                    copy=False))
-            if len(data) < cap:
-                pad = np.zeros(cap - len(data), dtype=data.dtype)
-                data = np.concatenate([data, pad])
-            validity = None
-            if self._has_validity[name]:
-                if f"__v_{name}" in t.column_names:
-                    validity = t.column(f"__v_{name}").to_numpy(
-                        zero_copy_only=False).astype(bool)
-                else:
-                    validity = np.ones(self.live[i], dtype=bool)
-                if len(validity) < cap:
-                    validity = np.concatenate(
-                        [validity, np.zeros(cap - len(validity), bool)])
+            with trace.timed("stream.stage.decode") as sp:
+                data = t.column(name).to_numpy(zero_copy_only=False)
+                data = np.ascontiguousarray(data.astype(self._dtypes[name],
+                                                        copy=False))
+                if len(data) < cap:
+                    pad = np.zeros(cap - len(data), dtype=data.dtype)
+                    data = np.concatenate([data, pad])
+                validity = None
+                if self._has_validity[name]:
+                    if f"__v_{name}" in t.column_names:
+                        validity = t.column(f"__v_{name}").to_numpy(
+                            zero_copy_only=False).astype(bool)
+                    else:
+                        validity = np.ones(self.live[i], dtype=bool)
+                    if len(validity) < cap:
+                        validity = np.concatenate(
+                            [validity, np.zeros(cap - len(validity), bool)])
+            decode_ms += sp.ms
             nbytes += data.nbytes + (validity.nbytes if validity is not None
                                      else 0)
-            cols.append(Column.from_numpy(data, self.ltypes[name], validity,
-                                          self._dicts[name]))
+            with trace.timed("stream.stage.h2d") as sp:
+                cols.append(Column.from_numpy(data, self.ltypes[name],
+                                              validity, self._dicts[name]))
+            h2d_ms += sp.ms
         sel = np.arange(cap) < live
         nbytes += sel.nbytes
-        return ColumnBatch(self.names, cols, jnp.asarray(sel), None,
-                           live_prefix=True), nbytes
+        with trace.timed("stream.stage.h2d") as sp:
+            batch = ColumnBatch(self.names, cols, jnp.asarray(sel), None,
+                                live_prefix=True)
+        return batch, nbytes, {"stream.stage.read": t_read.ms,
+                               "stream.stage.decode": decode_ms,
+                               "stream.stage.h2d": h2d_ms + sp.ms}
 
 
 class ChunkSource:

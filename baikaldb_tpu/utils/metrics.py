@@ -544,11 +544,17 @@ plan_cache_param_fallbacks = Counter("plan_cache_param_fallbacks")
 # literals hoisted into runtime params across all statements
 params_hoisted = Counter("params_hoisted")
 prepared_executes = Counter("prepared_executes")
+# SQL transactions ended by COMMIT (or the implicit commit of a new BEGIN)
+# and by ROLLBACK (exec/session.py _txn_stmt)
 txn_commits = Counter("txn_commits")
 txn_rollbacks = Counter("txn_rollbacks")
-wal_appends = Counter("wal_appends")
 connections_total = Counter("connections_total")
 point_lookups = Counter("point_lookups")
+# ms, not counts: the wall time of two obs/trace spans that close where no
+# query_log row can carry them — point.lookup (the row tier answers and no
+# row is written) and wire.result_set (encode + socket write, after the row)
+point_lookup_ms = Counter("point_lookup_ms")
+wire_result_set_ms = Counter("wire_result_set_ms")
 index_scans = Counter("index_scans")
 regions_pruned = Counter("regions_pruned")
 # XLA (re)traces of query programs: each count is one compile.  With capacity

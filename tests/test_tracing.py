@@ -6,7 +6,10 @@ assertion with tracing=off.
 
 import json
 import os
+import signal
 import sys
+import time
+from contextlib import contextmanager
 
 import pytest
 
@@ -59,14 +62,14 @@ def test_select_span_tree_shape(traced):
     assert rec is not None and rec["kind"] == "query"
     names = _names(rec)
     # the full lifecycle: parse -> plan -> execute -> egress
-    for expected in ("parse", "plan.build", "plan.cache", "exec.batches",
+    for expected in ("parse.sql", "plan.build", "plan.cache", "exec.batches",
                      "exec.run", "egress.compact", "egress.arrow", "query"):
         assert expected in names, f"missing span {expected}: {names}"
     # nesting: every stage hangs under the one root
     by_id = {sp["span_id"]: sp for sp in rec["spans"]}
     root = _by_name(rec, "query")[0]
     assert root["parent_id"] == ""
-    for nm in ("parse", "exec.run"):
+    for nm in ("parse.sql", "exec.run"):
         sp = _by_name(rec, nm)[0]
         # walk to the root
         cur = sp
@@ -400,3 +403,219 @@ def test_trace_flags_visible_in_show_variables(traced):
     s = Session()
     rows = s.execute("SHOW VARIABLES LIKE 'tracing'").rows
     assert rows and str(rows[0][1]).lower() in ("true", "1")
+
+
+# ---- one seam marker, three sinks (always-on record, profiler, tree) -------
+
+COARSE = {"parse", "plan", "exec", "egress"}
+STREAMED_SPANS = {
+    "parse.sql", "select.route", "snapshot.pin", "mvcc.dirty_check",
+    "plan.paramize", "plan.cache", "exec.batches", "mvcc.visibility",
+    "access.path", "stream.source", "plan.bind", "stream.prefetch",
+    "stream.stage.read", "stream.stage.decode", "stream.stage.h2d",
+    "stream.fold", "stream.sync", "stream.finalize", "egress.compact",
+    "egress.arrow"}
+RESIDENT_SPANS = {
+    "parse.sql", "select.route", "plan.paramize", "plan.cache",
+    "exec.batches", "access.path", "stream.source", "stage.resident",
+    "plan.bind", "exec.run", "exec.flags", "egress.compact", "egress.count",
+    "egress.arrow"}
+STREAMED_Q = "SELECT g, SUM(v) FROM st WHERE v > {} GROUP BY g"
+RESIDENT_Q = "SELECT v FROM st WHERE id BETWEEN {} AND 60 ORDER BY v"
+
+
+@pytest.fixture
+def stream_sess(tmp_path):
+    """A session whose 500-row table streams in 8 chunks (the default
+    flags but for the streaming size gates), both statements warmed."""
+    gates = ("streaming_min_rows", "streaming_chunk_rows")
+    prev = {k: getattr(FLAGS, k) for k in gates}
+    set_flag("streaming_min_rows", 1)
+    set_flag("streaming_chunk_rows", 64)
+    s = Session(Database(cold_dir=str(tmp_path / "afs")))
+    s.execute("CREATE TABLE st (id BIGINT, g BIGINT, v DOUBLE, "
+              "PRIMARY KEY (id))")
+    s.execute("INSERT INTO st VALUES " + ", ".join(
+        f"({i}, {i % 7}, {float(i % 101)})" for i in range(500)))
+    for q in (STREAMED_Q, RESIDENT_Q):
+        s.query(q.format(1))
+        s.query(q.format(2))
+    try:
+        yield s
+    finally:
+        set_flag("tracing", False)
+        for k, v in prev.items():
+            set_flag(k, v)
+        TRACER.clear()
+
+
+def _logged(s, sql):
+    """Run ``sql``; -> the dict of its query_log row."""
+    s.db.query_log.clear()
+    s.query(sql)
+    (row,) = s.db.query_log
+    assert row[0] == sql
+    return row[5]
+
+
+def _top_level(rec):
+    """Names of the seams directly under the root of a kept trace: the
+    depth-0 spans that tile the statement (events last 0 ms; the stager's
+    seams are credited from another thread and overlap the rest)."""
+    (root,) = [sp for sp in rec["spans"] if sp["parent_id"] == ""]
+    return {sp["name"] for sp in rec["spans"]
+            if sp["parent_id"] == root["span_id"] and sp["dur_ms"] > 0
+            and not sp["name"].startswith("stream.stage.")}
+
+
+@pytest.mark.parametrize("sql,spans", [(STREAMED_Q, STREAMED_SPANS),
+                                       (RESIDENT_Q, RESIDENT_SPANS)],
+                         ids=["streamed", "resident"])
+def test_span_keys_always_on_and_one_timing_truth(stream_sess, sql, spans):
+    s = stream_sess
+    before = metrics.traces_sampled.value
+    off = _logged(s, sql.format(3))
+    # default flags: nothing reaches the span store, the keys are there
+    assert TRACER.list() == [] and metrics.traces_sampled.value == before
+    assert COARSE <= set(off) and spans <= set(off)
+    assert all("." in k for k in set(off) - COARSE
+               - {"starting", "query", "untraced"})
+    set_flag("tracing", True)
+    on = _logged(s, sql.format(4))
+    assert set(on) == set(off)
+    rec = TRACER.last()
+    top = _top_level(rec)
+    for d in (off, on):
+        # the depth-0 spans and ``untraced`` tile the statement
+        assert d["untraced"] >= 0
+        assert abs(sum(d[k] for k in top) + d["untraced"] - d["query"]) < 1.0
+        assert d["query"] >= d["parse"] + d["plan"] + d["exec"] + d["egress"]
+    # the tree and the record were fed by the same clock reads
+    for name in spans:
+        tree = [sp["dur_ms"] for sp in rec["spans"] if sp["name"] == name]
+        assert tree, name
+        assert abs(sum(tree) - on[name]) < 1e-4 * len(tree) + 1e-9, name
+    # SHOW PROFILE renders those records
+    shown = {r[0].strip(): r[1] for r in
+             s.execute(f"SHOW PROFILE FOR QUERY {rec['query_id']}").rows}
+    assert abs(float(shown["exec.batches"]) - on["exec.batches"]) < 1e-3
+
+
+def test_stager_thread_times_reach_the_statement(stream_sess):
+    s = stream_sess
+    set_flag("tracing", True)
+    d = _logged(s, STREAMED_Q.format(5))
+    (ev,) = _by_name(TRACER.last(), "stream")
+    staged = sum(d[f"stream.stage.{k}"] for k in ("read", "decode", "h2d"))
+    assert staged > 0
+    assert abs(staged - ev["attrs"]["stage_ms"]) < 1e-3
+    # stager time overlaps the fold: it is no part of the tiling
+    assert d["untraced"] >= 0
+
+
+@contextmanager
+def _time_limit(seconds: int):
+    def expired(signum, frame):
+        raise TimeoutError(f"no end after {seconds} s")
+    prev = signal.signal(signal.SIGALRM, expired)
+    signal.alarm(seconds)
+    try:
+        yield
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, prev)
+
+
+def test_spans_land_on_the_profilers_host_plane(stream_sess, tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    from benchmark import trace_reduce
+    from tools.span_profile import by_program_span, program_spans
+
+    s = stream_sess
+    assert not bool(FLAGS.tracing)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with _time_limit(120):
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            s.query(STREAMED_Q.format(6))
+            s.query(RESIDENT_Q.format(6))
+        finally:
+            jax.profiler.stop_trace()
+        path = trace_reduce.find_xplane(str(tmp_path))
+        planes = list(ProfileData.from_file(path).planes)
+        names = {ev.name for plane in planes
+                 if plane.name == trace_reduce.HOST_PLANE
+                 for line in plane.lines for ev in line.events}
+    want = {"db." + n for n in STREAMED_SPANS | RESIDENT_SPANS}
+    assert want <= names, want - names
+    assert not [n for n in names if n.startswith("client.")]
+    # the stager's seams are on a thread of their own, and the reduction
+    # gives every moment of a thread to its innermost span
+    segs = program_spans(planes)
+    assert {"db.mvcc.visibility", "db.stream.stage.decode"} <= \
+        {n for _, _, n in segs}
+    assert all(e > b for b, e, _ in segs)
+    # no chip in this trace: nothing is busy, nothing idle, no module ran
+    reduced = by_program_span(str(tmp_path), 1, (0.0, 1.0))
+    assert reduced["busy_s"] == reduced["idle_s"] == reduced["modules"] == []
+    # outside a profiler session a seam opens no annotation
+    assert trace._annotation() is None
+
+
+def test_txn_counters_count():
+    s = _session()
+    c0, r0 = metrics.txn_commits.value, metrics.txn_rollbacks.value
+    s.execute("COMMIT")                     # outside a txn: counts nothing
+    s.execute("BEGIN")
+    s.execute("INSERT INTO tt VALUES (9, 9.5)")
+    s.execute("COMMIT")
+    s.execute("BEGIN")
+    s.execute("BEGIN")                      # implicit commit of the first
+    s.execute("ROLLBACK")
+    assert metrics.txn_commits.value - c0 == 2
+    assert metrics.txn_rollbacks.value - r0 == 1
+    assert not hasattr(metrics, "wal_appends")
+
+
+def test_point_lookup_and_wire_feed_their_counters():
+    from baikaldb_tpu.client.mysql_client import Connection
+    from baikaldb_tpu.server.mysql_server import MySQLServer
+
+    db = Database()
+    s = Session(db=db)
+    s.execute("CREATE TABLE pk (id BIGINT, v DOUBLE, PRIMARY KEY (id))")
+    s.execute("INSERT INTO pk VALUES (1, 1.5), (2, 2.5)")
+    srv = MySQLServer(db, port=0).start()
+    try:
+        conn = Connection(port=srv.port)
+        p0 = metrics.point_lookup_ms.value
+        w0 = metrics.wire_result_set_ms.value
+        n0 = metrics.point_lookups.value
+        assert conn.query("SELECT v FROM pk WHERE id = 2").rows
+        conn.close()
+    finally:
+        srv.stop()
+    assert metrics.point_lookups.value == n0 + 1
+    assert metrics.point_lookup_ms.value > p0
+    # the server thread feeds the counter after its last socket write
+    deadline = time.monotonic() + 10
+    while metrics.wire_result_set_ms.value <= w0 \
+            and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert metrics.wire_result_set_ms.value > w0
+
+
+def test_explain_analyze_streamed_has_no_device_line(stream_sess):
+    """A streamed statement has no executable under its plan's signature:
+    the line used to show the newest OTHER program's cost."""
+    s = stream_sess
+    resident = s.execute(
+        "EXPLAIN ANALYZE " + RESIDENT_Q.format(7)).plan_text
+    assert "-- device:" in resident
+    streamed = s.execute(
+        "EXPLAIN ANALYZE " + STREAMED_Q.format(7)).plan_text
+    assert "-- stream: chunks=8/8" in streamed
+    assert "-- device:" not in streamed
