@@ -671,18 +671,30 @@ class TableStore:
         with self._lock:
             return self._mvcc.gc(int(watermark))
 
+    def _mvcc_diverged(self, snap_ts: int) -> tuple[bool, list]:
+        """(does the live image differ from the one at ``snap_ts``?, the
+        history versions alive at it) — the one question both pinned-read
+        check sites ask, each at its own instant.  Caller holds the table
+        lock.  The live half is MvccState's O(1) summary, never a walk of
+        ``live_cts``; its error is one-sided (a popped maximum says
+        "diverged" where the walk would say "quiet")."""
+        from .mvcc import mvcc_quiet_checks, mvcc_versioned_checks
+        mv = self._mvcc
+        hist = mv.versions_at(snap_ts)
+        diverged = bool(hist) or mv.live_newer_than(snap_ts)
+        (mvcc_versioned_checks if diverged else mvcc_quiet_checks).add(1)
+        return diverged, hist
+
     def mvcc_needs_versioned(self, snap_ts: int) -> bool:
         """True when a read pinned at ``snap_ts`` cannot be served by the
         CURRENT resident image: some commit landed after the snapshot, or
-        a version alive at it has since died.  Cheap (no image build) —
+        a version alive at it has since died.  O(history), whatever the
+        table's size (no walk of the live stamps, no image build) —
         the session uses it to keep the fast paths (egress, point lookup,
         access-path gathers, streaming, pushdown) engaged on quiet tables
         under a pin, where live and snapshot images are identical."""
-        snap_ts = int(snap_ts)
         with self._lock:
-            mv = self._mvcc
-            return bool(mv.versions_at(snap_ts)) or \
-                any(c > snap_ts for c in mv.live_cts.values())
+            return self._mvcc_diverged(int(snap_ts))[0]
 
     def snapshot_versions(self, snap_ts: int):
         """The versioned read image at ``snap_ts``, or None when the
@@ -701,16 +713,14 @@ class TableStore:
         from .mvcc import MAX_TS
         snap_ts = int(snap_ts)
         with self._lock:
-            mv = self._mvcc
-            hist = mv.versions_at(snap_ts)
-            if not hist and not any(c > snap_ts
-                                    for c in mv.live_cts.values()):
+            diverged, hist = self._mvcc_diverged(snap_ts)
+            if not diverged:
                 return None
             live = self.snapshot()
             regions = self.regions
             rowids = (np.concatenate([r.rowids for r in regions])
                       if regions else np.empty(0, dtype=np.int64))
-            lc = mv.live_cts
+            lc = self._mvcc.live_cts
             cts = np.fromiter((lc.get(int(rid), 0) for rid in rowids),
                               dtype=np.int64, count=len(rowids))
             dts = np.full(len(rowids), MAX_TS, dtype=np.int64)

@@ -21,11 +21,18 @@ while OLTP writes keep flowing.  This module is the engine-side half:
   Arrow image, never inside it: the store's ``Region.data`` stays
   physically latest (the ``mvcc=0`` off-switch and the no-concurrent-write
   fast path are bit-identical for free).  ``live_cts`` maps rowid ->
-  commit_ts for rows whose stamp still matters (missing = 0 = visible to
-  every snapshot); ``history`` holds dead versions as
+  commit_ts for every row written since the table's last reset whose
+  stamp GC has not settled — bulk loads included (``insert_arrow`` stamps
+  each loaded row), so a loaded table carries one entry per row until a
+  GC sweep passes its load timestamp; missing = 0 = visible to every
+  snapshot.  ``history`` holds dead versions as
   ``(row_values, commit_ts, delete_ts)``.  Uncommitted rows carry the
   ``PENDING`` sentinel (MAX_TS — invisible to every real snapshot) and are
-  restamped with ONE decide-time commit_ts at transaction commit.
+  restamped with ONE decide-time commit_ts at transaction commit.  Beside
+  the dict the state keeps a summary — ``high_water``, an upper bound on
+  the largest committed live stamp, and ``pending``, the rowids stamped
+  PENDING — kept at every write hook, so a pinned read learns "nothing
+  live is newer than my snapshot" in O(1) instead of walking the dict.
 - ``SnapshotRegistry`` — live pins (explicit ``SET SNAPSHOT`` and
   automatic analytical pins) feeding the GC watermark: nothing at or
   above the oldest unexpired pin is ever reclaimed.
@@ -72,6 +79,10 @@ define("snapshot_max_age_s", 300.0,
 tso_allocations = metrics.Counter("tso.allocations")
 tso_batch_refills = metrics.Counter("tso.batch_refills")
 mvcc_gc_reclaimed = metrics.Counter("mvcc.gc_reclaimed")
+# how a pinned read's two "has this table moved past my snapshot?" checks
+# (TableStore.mvcc_needs_versioned / snapshot_versions) were answered
+mvcc_quiet_checks = metrics.Counter("mvcc.quiet_checks")
+mvcc_versioned_checks = metrics.Counter("mvcc.versioned_checks")
 
 #: commit_ts sentinel for uncommitted (in-transaction) rows: above every
 #: real timestamp, so no snapshot ever admits a pending version.  Rollback
@@ -173,45 +184,81 @@ class MvccState:
 
     Mutated only under the owning TableStore's table lock (the store
     passes itself in for every call) — no lock of its own, so it adds
-    nothing to the lock order.  ``live_cts``: rowid -> commit_ts for rows
-    whose stamp still matters (missing = 0: visible to every snapshot —
-    loads, truncate-reset state, and stamps GC already settled).
+    nothing to the lock order.  ``live_cts``: rowid -> commit_ts for
+    every row stamped since the last reset — autocommit DML, committed
+    transactions AND bulk loads (one entry per loaded row) — until a GC
+    sweep settles it (missing = 0: visible to every snapshot —
+    truncate-reset state and stamps GC already settled).
     ``history``: dead versions as ``(row_values, commit_ts, delete_ts)``
     dicts in arrival order; a GC sweep drops entries whose delete_ts is at
     or below the watermark.
+
+    The summary of ``live_cts``, kept by every write hook so the pinned
+    read's "has the live image moved past my snapshot?" never walks it:
+    ``high_water`` is an upper bound on the largest non-PENDING stamp in
+    the dict, ``pending`` the rowids stamped PENDING.  ``pending`` is
+    exact.  ``high_water`` errs to one side only: ``record_dead`` may pop
+    the row that held the maximum and the mark stays (finding the next
+    one would be the walk again), so it can send a read to the versioned
+    image that the walk would have served live — same rows either way —
+    and never the reverse.  A GC sweep at or above the mark zeroes it,
+    which makes it exact again.
     """
 
-    __slots__ = ("live_cts", "history", "__weakref__")
+    __slots__ = ("live_cts", "history", "high_water", "pending",
+                 "reclaimed", "__weakref__")
 
     def __init__(self):
         self.live_cts: dict[int, int] = {}
         self.history: list[tuple[dict, int, int]] = []
+        self.high_water = 0
+        self.pending: set[int] = set()
+        self.reclaimed = 0      # history versions GC has dropped, ever
         _STATES.add(self)
 
     # -- write-path hooks (caller holds the table lock) -----------------
     def stamp(self, rowids, cts: int) -> None:
         lc = self.live_cts
+        if cts == PENDING:
+            pend = self.pending
+            for rid in rowids:
+                rid = int(rid)
+                lc[rid] = cts
+                pend.add(rid)
+            return
+        rid = None
         for rid in rowids:
             lc[int(rid)] = cts
+        if self.pending:    # a committed stamp over a PENDING row settles it
+            self.pending.difference_update(int(r) for r in rowids)
+        if rid is not None and cts > self.high_water:
+            self.high_water = cts
 
     def record_dead(self, rows: list[dict], rowids, dts: int) -> None:
         """Old versions of deleted/updated rows enter history."""
         lc = self.live_cts
         hist = self.history
+        pend = self.pending
         for row, rid in zip(rows, rowids):
             rid = int(rid)
-            hist.append((row, lc.pop(rid, 0), dts))
+            cts = lc.pop(rid, 0)
+            if cts == PENDING:
+                pend.discard(rid)
+            hist.append((row, cts, dts))
 
     def restamp_pending(self, commit_ts: int) -> int:
         """Replace every PENDING stamp with the decide-time commit_ts —
         the one-timestamp-per-transaction contract.  Single-writer (the
         store's writer lease) means every pending stamp belongs to the
         committing transaction.  Returns the number restamped."""
-        n = 0
-        for rid, c in self.live_cts.items():
-            if c == PENDING:
-                self.live_cts[rid] = commit_ts
-                n += 1
+        n = len(self.pending)
+        if n:
+            lc = self.live_cts
+            for rid in self.pending:
+                lc[rid] = commit_ts
+            self.pending.clear()
+            if commit_ts > self.high_water:
+                self.high_water = commit_ts
         for i, (row, c, d) in enumerate(self.history):
             if d == PENDING:
                 self.history[i] = (row, c, commit_ts)
@@ -220,28 +267,40 @@ class MvccState:
 
     # -- preimage (transaction rollback) --------------------------------
     def capture(self) -> tuple:
-        return (dict(self.live_cts), len(self.history))
+        # the history mark counts from the table's first version, not from
+        # the list's current head: a GC sweep while the transaction is
+        # open drops older entries (never the transaction's own, whose
+        # delete_ts is PENDING) and would shift a plain length
+        return (dict(self.live_cts), self.reclaimed + len(self.history),
+                self.high_water, set(self.pending))
 
     def restore(self, pre: tuple) -> None:
-        live, hist_len = pre
+        live, hist_mark, high_water, pending = pre
         self.live_cts = dict(live)
-        del self.history[hist_len:]
+        del self.history[max(hist_mark - self.reclaimed, 0):]
+        self.high_water = high_water
+        self.pending = set(pending)
 
     def reset(self) -> None:
         """Table image replaced wholesale (truncate / load / DDL rebuild):
         all prior stamps and versions are meaningless."""
         self.live_cts.clear()
+        self.reclaimed += len(self.history)
         self.history.clear()
+        self.high_water = 0
+        self.pending.clear()
 
     # -- read-path helpers ----------------------------------------------
     def versions_at(self, snap_ts: int) -> list[tuple[dict, int, int]]:
         """History versions alive at ``snap_ts`` (cts <= snap < dts)."""
         return [h for h in self.history if h[1] <= snap_ts < h[2]]
 
-    def newest_cts(self) -> int:
-        """Largest non-pending live stamp (0 = no stamped rows)."""
-        return max((c for c in self.live_cts.values() if c != PENDING),
-                   default=0)
+    def live_newer_than(self, snap_ts: int) -> bool:
+        """May some live row carry a stamp above ``snap_ts`` (a commit
+        after the snapshot, or an open transaction's PENDING row)?  O(1),
+        from the summary: False is exact, True may be the popped-maximum
+        upper bound (class docstring)."""
+        return bool(self.pending) or self.high_water > snap_ts
 
     def gc(self, watermark: int) -> int:
         """Drop history below the watermark and settle old live stamps.
@@ -263,7 +322,10 @@ class MvccState:
                    if c <= watermark]
         for rid in settled:
             del self.live_cts[rid]
+        if self.high_water <= watermark:
+            self.high_water = 0     # every committed stamp just settled
         reclaimed = before - len(self.history)
+        self.reclaimed += reclaimed
         if reclaimed:
             mvcc_gc_reclaimed.add(reclaimed)
         return reclaimed
