@@ -119,12 +119,13 @@ class AotRawShim:
     compiles — warm_compiles stays 0 by construction) and ``join_order``
     carries :class:`AotFlagShim` entries in the artifact's flag order."""
 
-    def __init__(self, flag_meta: list):
+    def __init__(self, flag_meta: list, exchange_bytes: int = 0):
         self.join_order = [AotFlagShim(m.get("cap"), m.get("scalar", False),
                                        m.get("kind", "?"))
                            for m in (flag_meta or [])]
         self.trace_order: list = []
         self.trace_count = [0]
+        self.exchange_bytes = [int(exchange_bytes)]
 
 
 class _CapBox:
@@ -164,6 +165,10 @@ def compile_plan(plan: PlanNode, trace: bool = False, mesh=None) -> Callable:
     # session's compile telemetry (metrics.xla_retraces / compile_ms) and the
     # bucketing regression tests key off this.
     trace_count = [0]
+    # bytes the program's repartition and gather collectives move between
+    # chips per execution (metrics.exchange_bytes has how it is reckoned):
+    # static shapes, so tallied once per trace like join_order
+    exchange_bytes = [0]
 
     def run_local(batches: dict):
         if not getattr(ACCOUNTING_TRACE, "active", False):
@@ -172,7 +177,9 @@ def compile_plan(plan: PlanNode, trace: bool = False, mesh=None) -> Callable:
         overflows: list = []
         counts: list = []
         trace_order.clear()
-        ctx = (overflows, counts if trace else None, trace_order, n_shards)
+        moved: list = []
+        ctx = (overflows, counts if trace else None, trace_order, n_shards,
+               moved)
         # hoisted-literal params (plan/paramize.py) ride the batches pytree;
         # Param expr nodes read their slots from this trace-scoped binding
         with bind_params(batches.get(PARAMS_KEY, ())):
@@ -181,6 +188,7 @@ def compile_plan(plan: PlanNode, trace: bool = False, mesh=None) -> Callable:
         # time), return only the traced flags
         join_order.clear()
         join_order.extend(n for n, _ in overflows)
+        exchange_bytes[0] = sum(moved)
         flags = tuple(f for _, f in overflows)
         if n_shards:
             # flags carry NEEDED capacities: the retry must satisfy the
@@ -216,6 +224,7 @@ def compile_plan(plan: PlanNode, trace: bool = False, mesh=None) -> Callable:
     run.join_order = join_order
     run.trace_order = trace_order
     run.trace_count = trace_count
+    run.exchange_bytes = exchange_bytes
     return run
 
 
@@ -231,7 +240,7 @@ def _presort_order(node, batches: dict, expected_len: int):
 
 
 def _eval_traced(node: PlanNode, batches: dict, ctx):
-    overflows, counts, trace_order, n_shards = ctx
+    overflows, counts, trace_order, n_shards, _moved = ctx
     out = _eval(node, batches, overflows, ctx)
     trace_order.append(node)
     c = out.live_count()
@@ -372,7 +381,8 @@ def _eval(node: PlanNode, batches: dict, overflows: list, ctx=None) -> ColumnBat
                     continue
                 if box.cap is None:
                     box.cap = max(1, 2 * len(b) // n)
-                out_b, needed = _repartition_exec(b, list(keys), n, box.cap)
+                out_b, needed = _repartition_exec(b, list(keys), n, box.cap,
+                                                  ctx)
                 overflows.append((box, needed))
                 shuffled.append(out_b)
             probe, builds = shuffled[0], shuffled[1:]
@@ -388,7 +398,7 @@ def _eval(node: PlanNode, batches: dict, overflows: list, ctx=None) -> ColumnBat
     if isinstance(node, ExchangeNode):
         child = _sub(node.child(), batches, overflows, ctx)
         if node.kind == "gather":
-            return _all_gather_batch(child)
+            return _all_gather_batch(child, ctx)
         if node.reused:
             # keyed exchange scheduler: the child is already hash-
             # partitioned on this key class — rows flow through, no
@@ -398,7 +408,7 @@ def _eval(node: PlanNode, batches: dict, overflows: list, ctx=None) -> ColumnBat
         keys = node.keys if node.keys is not None else list(child.names)
         if node.cap is None:
             node.cap = max(1, 2 * len(child) // max(1, n))
-        out, ovf = _repartition_exec(child, keys, n, node.cap)
+        out, ovf = _repartition_exec(child, keys, n, node.cap, ctx)
         overflows.append((node, ovf))
         return out
 
@@ -454,7 +464,7 @@ def _eval(node: PlanNode, batches: dict, overflows: list, ctx=None) -> ColumnBat
             if box.cap is None:
                 box.cap = max(1, 2 * len(part) // n)
             shuf, needed = _repartition_exec(part, node.key_names, n,
-                                             box.cap)
+                                             box.cap, ctx)
             overflows.append((box, needed))
             final = group_aggregate_sorted(shuf, node.key_names,
                                            merge_partial_agg_specs(parts),
@@ -480,7 +490,7 @@ def _eval(node: PlanNode, batches: dict, overflows: list, ctx=None) -> ColumnBat
                 # the TopNSorter merge of per-region streams (src/runtime/
                 # topn_sorter.cpp) as two kernels + one collective
                 local = top_k(child, keys, min(k, len(child)))
-                child = _all_gather_batch(local)
+                child = _all_gather_batch(local, ctx)
             out = top_k(child, keys, k)
             if node.offset:
                 out = head(out, node.limit, node.offset)
@@ -707,8 +717,20 @@ def progress_totals(plan: PlanNode) -> dict:
             "rounds": exchange_summary(plan)["rounds"]}
 
 
-def _all_gather_batch(b: ColumnBatch) -> ColumnBatch:
+def _row_bytes(b: ColumnBatch) -> int:
+    """Bytes one row of ``b`` takes in a collective's buffers: every
+    column's data and validity, and the row mask."""
+    return 1 + sum(c.data.dtype.itemsize
+                   + (0 if c.validity is None else c.validity.dtype.itemsize)
+                   for c in b.columns)
+
+
+def _all_gather_batch(b: ColumnBatch, ctx) -> ColumnBatch:
     """Shard-partitioned rows -> replicated full batch (one all_gather)."""
+    n = ctx[3]
+    # each of n chips receives the other n-1 local slices
+    ctx[4].append(n * (n - 1) * len(b) * _row_bytes(b))
+
     def ag(x):
         return jax.lax.all_gather(x, AXIS, axis=0, tiled=True)
 
@@ -718,11 +740,14 @@ def _all_gather_batch(b: ColumnBatch) -> ColumnBatch:
     return ColumnBatch(b.names, cols, ag(b.sel_mask()), None)
 
 
-def _repartition_exec(b: ColumnBatch, keys: list[str], n: int, cap: int):
+def _repartition_exec(b: ColumnBatch, keys: list[str], n: int, cap: int,
+                      ctx):
     """Hash-partition local rows on ``keys`` + all_to_all: equal keys land on
     one shard (the ExchangeSender/Receiver pair as one collective)."""
     from ..parallel.shuffle import repartition_collective
 
+    # each of n chips sends its [n, cap] buffer less the slot it keeps
+    ctx[4].append(n * (n - 1) * cap * _row_bytes(b))
     return repartition_collective(b, keys, n, cap)
 
 

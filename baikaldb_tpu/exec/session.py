@@ -16,7 +16,7 @@ import itertools
 import threading
 import time
 from collections import OrderedDict, deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -62,6 +62,12 @@ from ..utils.flags import FLAGS, define
 define("cold_fs_dir", "",
        "external cold-storage root (posix AFS stand-in); empty = cold "
        "tier disabled")
+define("mesh_devices", 0,
+       "the deployment's device mesh: with N >= 2 the Database owns a mesh "
+       "of the first N devices and every session without a mesh of its own "
+       "(every wire connection) runs SELECTs as one shard_map program over "
+       "it, tables row-sharded; 0 or 1 = one device, no mesh.  More than "
+       "jax.devices() holds is an error, never a fallback")
 define("param_queries", True,
        "auto-parameterize WHERE literals (plan/paramize.py): one plan-cache "
        "entry and one compiled executable serve every literal variant of a "
@@ -361,6 +367,12 @@ class Database:
         # query statistics ring (reference: slow-SQL collection + print_agg_sql,
         # network_server.h:82-107) — feeds information_schema.query_log
         self.query_log = deque(maxlen=1000)
+        # the deployment's mesh (flag mesh_devices) and the row-sharded
+        # copies of its tables: one copy a table, whatever the number of
+        # connections reading it
+        self._mesh = None
+        self._mesh_mu = threading.Lock()
+        self._mesh_batches: dict = {}
         from ..storage.binlog import Binlog
         self.qos = None          # optional utils.qos.QosManager
         # cross-query batched dispatch (exec/dispatch.py): engine-wide so
@@ -435,6 +447,50 @@ class Database:
 
     def store(self, key: str) -> TableStore:
         return self.stores[key]
+
+    @property
+    def mesh(self):
+        """The mesh ``mesh_devices`` asks for, or None below two devices."""
+        n = int(FLAGS.mesh_devices)
+        if n < 2:
+            return None
+        m = self._mesh
+        if m is None or m.devices.size != n:
+            from ..parallel.mesh import make_mesh
+            with self._mesh_mu:
+                m = self._mesh
+                if m is None or m.devices.size != n:
+                    try:
+                        m = self._mesh = make_mesh(n)
+                    except ValueError as e:
+                        raise SqlError(f"mesh_devices: {e}") from None
+        return m
+
+    def sharded_batch(self, table_key: str, store: TableStore,
+                      mesh) -> ColumnBatch:
+        """Row-shard a table across ``mesh`` (cached per table version) —
+        the region-to-store placement analog: each mesh device holds one
+        horizontal slice, padded to SPMD-equal length.  Sharding runs under
+        the lock: a second connection waits for the first one's copy
+        instead of making its own."""
+        from ..parallel.mesh import shard_batch
+
+        # bucket config joins the key: flipping batch_bucketing (or the
+        # bucket floor) must re-shard, not serve a cached batch of the
+        # other shape discipline
+        ck = (table_key, mesh, store.version, bool(FLAGS.batch_bucketing),
+              int(FLAGS.batch_bucket_min))
+        with self._mesh_mu:
+            b = self._mesh_batches.get(ck)
+            if b is None:
+                # one copy a table: drop its stale versions (and a copy
+                # made for another mesh) before caching the new one
+                self._mesh_batches = {
+                    k: v for k, v in self._mesh_batches.items()
+                    if k[0] != table_key}
+                b = shard_batch(store.device_table_batch(), mesh)
+                self._mesh_batches[ck] = b
+        return b
 
     @staticmethod
     def attach_aot_peer(meta_address: str) -> None:
@@ -700,16 +756,15 @@ class Session:
         """``mesh``: a jax.sharding.Mesh with one axis — when set, every
         SELECT plans through plan/distribute.py and executes as a single
         shard_map program over the mesh (scans row-sharded across devices,
-        exchanges as ICI collectives — the MPP mode, SURVEY §3.2).
+        exchanges as ICI collectives — the MPP mode, SURVEY §3.2).  Left
+        out, the session runs on the deployment's mesh (``Database.mesh``,
+        flag ``mesh_devices``), which is none by default.
         ``user``: the authenticated account; statements are checked against
         its grants (reference: privilege_manager + per-statement checks)."""
         self.db = db or Database()
         self.current_db = database
         self.user = user
-        self.mesh = mesh
-        # sharded device batches, keyed (table_key, version); stale versions
-        # of a table are dropped on re-shard, so this is bounded by #tables
-        self._mesh_batches: dict = {}
+        self._mesh = mesh
         # SQL-text-keyed compiled plans, LRU-bounded (FLAGS.plan_cache_size;
         # a long-lived server must not leak one executable per distinct
         # query text)
@@ -733,6 +788,10 @@ class Session:
         # the snapshot ts the CURRENT query runs at (0 = unpinned read) —
         # query_log / EXPLAIN ANALYZE read it; set per-SELECT
         self._snap_ts: int = 0
+
+    @property
+    def mesh(self):
+        return self._mesh if self._mesh is not None else self.db.mesh
 
     def _log_binlog(self, event_type, db_name, table, rows=None, statement="",
                     affected=0):
@@ -1142,8 +1201,14 @@ class Session:
                 continue
             if s.scope == "global":
                 try:
+                    if name.lower() == "mesh_devices":
+                        have = len(jax.devices())
+                        if not 0 <= int(value) <= have:
+                            raise SqlError(
+                                f"mesh_devices = {value}: this process "
+                                f"has {have} device(s)")
                     FLAGS.set_flag(name, value)
-                except FlagError as e:
+                except (FlagError, ValueError) as e:
                     raise SqlError(str(e)) from None
             else:
                 self.session_vars[name] = value
@@ -2162,9 +2227,10 @@ class Session:
             wsel = getattr(self, "_where_sel_hint", None)
             if wsel is None and bool(FLAGS.adaptive_agg_selectivity):
                 wsel = self._where_selectivity(stmt)
-            plan = distribute(plan, int(self.mesh.devices.size), rows_fn,
-                              ndv_fn=ndv_fn, stats_fn=self._stats_fn,
-                              where_selectivity=wsel)
+            with trace.span("plan.distribute"):
+                plan = distribute(plan, int(self.mesh.devices.size), rows_fn,
+                                  ndv_fn=ndv_fn, stats_fn=self._stats_fn,
+                                  where_selectivity=wsel)
         return plan
 
     def _annotate_ann(self, stmt: SelectStmt, plan: PlanNode) -> None:
@@ -4170,6 +4236,12 @@ class Session:
         shows)."""
         entry = self._plan_cache.get(lookup_key) if lookup_key else None
         replanned = False
+        mesh = self.mesh
+        mesh_n = int(mesh.devices.size) if mesh is not None else 0
+        if entry is not None and entry.get("mesh_n", 0) != mesh_n:
+            # SET GLOBAL mesh_devices since this entry was planned: a plan
+            # distributed for another mesh (or none) cannot run on this one
+            entry = None
         if entry is not None:
             self._plan_cache.move_to_end(lookup_key)
             # stats-derived plan choices (dense group-by domains, key shifts)
@@ -4205,7 +4277,7 @@ class Session:
         if entry is None:
             plan = self._plan_select(stmt)
             entry = {"plan": plan, "plan_sig": plan_signature(plan),
-                     "compiled": {}, "versions": {},
+                     "compiled": {}, "versions": {}, "mesh_n": mesh_n,
                      "view_gen": self.db.catalog.view_gen,
                      "text": text_key[0] if text_key else None}
             cap = int(FLAGS.plan_cache_size)
@@ -4712,7 +4784,8 @@ class Session:
                                 sp.set(rows=len(b))
                 if b is None:
                     if self.mesh is not None:
-                        b = self._sharded_batch(n.table_key, store)
+                        b = self.db.sharded_batch(n.table_key, store,
+                                                  self.mesh)
                     else:
                         # out-of-core: an eligible scan->filter->aggregate
                         # plan over a big-enough table stages a ChunkSource
@@ -4959,26 +5032,6 @@ class Session:
             for c in n.children:
                 walk(c)
         walk(plan)
-
-    def _sharded_batch(self, table_key: str, store: TableStore) -> ColumnBatch:
-        """Row-shard a table across the mesh (cached per table version) —
-        the region-to-store placement analog: each mesh device holds one
-        horizontal slice, padded to SPMD-equal length."""
-        from ..parallel.mesh import shard_batch
-
-        # bucket config joins the key: flipping batch_bucketing (or the
-        # bucket floor) mid-session must re-shard, not serve a cached batch
-        # of the other shape discipline
-        ck = (table_key, store.version, bool(FLAGS.batch_bucketing),
-              int(FLAGS.batch_bucket_min))
-        b = self._mesh_batches.get(ck)
-        if b is None:
-            # drop stale versions of this table before caching the new one
-            self._mesh_batches = {k: v for k, v in self._mesh_batches.items()
-                                  if k[0] != table_key}
-            b = shard_batch(store.device_table_batch(), self.mesh)
-            self._mesh_batches[ck] = b
-        return b
 
     def _info_schema_table(self, name: str) -> pa.Table:
         cat = self.db.catalog
@@ -5590,7 +5643,7 @@ class Session:
             return aot_key
 
         compiled_here = False
-        for _ in range(int(FLAGS.join_retry_max) + 1):
+        for attempt in range(int(FLAGS.join_retry_max) + 1):
             # overflow-retry boundary: between device programs, no side
             # effects yet — a KILL lands here instead of paying another
             # trace+compile+run of the whole plan
@@ -5617,7 +5670,9 @@ class Session:
                     # with its settled caps baked in; the shim feeds the
                     # overflow loop below from the artifact's flag meta
                     pair = (art.run,
-                            executor.AotRawShim(art.flag_meta),
+                            executor.AotRawShim(
+                                art.flag_meta,
+                                (art.extra or {}).get("exchange_bytes", 0)),
                             versions_key)
                     entry["compiled"][shape_key] = pair
             if pair is None:
@@ -5644,30 +5699,34 @@ class Session:
             # OUTSIDE the guard scope.  The span wraps the dispatch from
             # the HOST side — spans inside the traced fn would bake into
             # the program (tpulint SPANINJIT)
-            with trace.span("exec.run") as sp:
-                with hot_path_guard():
-                    out, flags = fn(batches)
-                if raw.trace_count[0] > traces_before:
-                    # this execution paid a trace+compile (first run /
-                    # bucket crossing / overflow retry): record it so
-                    # first-run vs steady-state shows up in SHOW metrics
-                    # and the trace vs execute split shows in the span
-                    cms = (time.perf_counter() - t0) * 1e3
-                    metrics.compile_ms.observe(cms)
-                    sp.set(compiled=True)
-                    compiled_here = True
-                    # device-resource accounting (compile seam): the cost/
-                    # memory analysis itself is LAZY — only the identity,
-                    # wall-ms, and the arg shape skeleton record here
-                    if compilecache.EXECUTABLES.enabled():
-                        sig = entry.get("plan_sig")
-                        if sig is None:
-                            sig = entry["plan_sig"] = plan_signature(plan)
-                        compilecache.EXECUTABLES.record_compile(
-                            "plan", entry.get("text") or "<unnamed>", sig,
-                            ";".join(f"{p[0]}={p[1]}"
-                                     for p in shape_key[0]),
-                            cms, fn, (batches,))
+            # a retry's recompile (a cap grew: trace + compile of the whole
+            # plan again) shows under its own name beside the first run's
+            with trace.span("exec.cap_retry", attempt=attempt) \
+                    if attempt else nullcontext():
+                with trace.span("exec.run") as sp:
+                    with hot_path_guard():
+                        out, flags = fn(batches)
+                    if raw.trace_count[0] > traces_before:
+                        # this execution paid a trace+compile (first run /
+                        # bucket crossing / overflow retry): record it so
+                        # first-run vs steady-state shows up in SHOW metrics
+                        # and the trace vs execute split shows in the span
+                        cms = (time.perf_counter() - t0) * 1e3
+                        metrics.compile_ms.observe(cms)
+                        sp.set(compiled=True)
+                        compiled_here = True
+                        # device-resource accounting (compile seam): the cost/
+                        # memory analysis itself is LAZY — only the identity,
+                        # wall-ms, and the arg shape skeleton record here
+                        if compilecache.EXECUTABLES.enabled():
+                            sig = entry.get("plan_sig")
+                            if sig is None:
+                                sig = entry["plan_sig"] = plan_signature(plan)
+                            compilecache.EXECUTABLES.record_compile(
+                                "plan", entry.get("text") or "<unnamed>", sig,
+                                ";".join(f"{p[0]}={p[1]}"
+                                         for p in shape_key[0]),
+                                cms, fn, (batches,))
             grew = False
             # ONE explicit transfer for every overflow flag: int(flag) per
             # join would block on a device round-trip once per node
@@ -5705,6 +5764,8 @@ class Session:
                         # shuffle capacity — the exchange backpressure
                         # analog, worth its own counter
                         metrics.shuffle_overflow_retries.add(1)
+            if grew:
+                metrics.join_cap_retries.add(1)
             if grew and isinstance(raw, executor.AotRawShim):
                 # live data outgrew the artifact's baked capacities: an
                 # exported program cannot re-trace, so this shape compiles
@@ -5730,8 +5791,12 @@ class Session:
                         entry.get("plan_sig"),
                         compile_plan(plan, mesh=mesh), batches,
                         (out, flags),
-                        executor.flag_meta_of(raw.join_order), mesh=mesh)
+                        executor.flag_meta_of(raw.join_order),
+                        extra=None if mesh is None else
+                        {"exchange_bytes": raw.exchange_bytes[0]}, mesh=mesh)
                 if mesh is not None:
+                    metrics.mesh_programs.add(1)
+                    metrics.exchange_bytes.add(raw.exchange_bytes[0])
                     self._mpp_telemetry(plan, entry, raw.join_order,
                                         host_flags)
                 with trace.span("egress.compact"):

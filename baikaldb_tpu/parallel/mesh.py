@@ -54,10 +54,11 @@ def shard_batch(batch: ColumnBatch, mesh: Mesh) -> ColumnBatch:
     ingest boundary), so the span here is legal despite this module being
     tpulint hot scope — registered in tools/tpulint_suppressions.txt."""
     from ..obs import trace
+    from ..utils import metrics
     from ..utils.flags import FLAGS
 
-    with trace.span("mesh.shard", rows=len(batch),
-                    devices=int(mesh.devices.size)):
+    with trace.timed("mesh.shard", rows=len(batch),
+                     devices=int(mesh.devices.size)) as sp:
         n = mesh.devices.size
         if FLAGS.batch_bucketing:
             per = -(-max(len(batch), 1) // n)
@@ -72,4 +73,6 @@ def shard_batch(batch: ColumnBatch, mesh: Mesh) -> ColumnBatch:
                        else jax.device_put(c.validity, sharding),
                        c.ltype, c.dictionary) for c in b.columns]
         sel = jax.device_put(b.sel_mask(), sharding)
-        return ColumnBatch(b.names, cols, sel, None)
+        out = ColumnBatch(b.names, cols, sel, None)
+    metrics.mesh_shard_ms.add(sp.ms)
+    return out

@@ -733,12 +733,19 @@ class _Distributor:
                 # rows on every shard; collect the build side instead
                 self._gather(node, 1)
                 return REP, est
-            # both sharded: broadcast small builds, shuffle big ones
+            # both sharded: broadcast small builds, shuffle big ones.  By
+            # rows over the ICI: gathering the build brings every shard the
+            # other n-1 slices, er*(n-1) in all; repartitioning both sides
+            # moves (el+er)*(n-1)/n; so the gather is the cheaper one while
+            # er*(n-1) <= el.  (It read er*n <= el, which at n = 4 sits on
+            # TPC-H's own ratio, lineitem = 4 x orders +- 0.1% by the seed:
+            # Q3's plan flipped with the data, 544 against 1,257 ms on four
+            # v5e chips, PERF.md section 6, PR 28.)
             force = bool(FLAGS.mpp_force_shuffle) and node.how != "cross" \
                 and node.left_keys
             if not force and (node.how == "cross"
                               or er <= self.broadcast_rows
-                              or er * self.n <= el):
+                              or er * (self.n - 1) <= el):
                 self._gather(node, 1)
             else:
                 self._repartition(node, 0, node.left_keys)

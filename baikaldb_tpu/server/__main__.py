@@ -16,9 +16,14 @@ def main():
                          "store daemon cluster it places")
     ap.add_argument("--data-dir", default="",
                     help="durable single-node mode (WAL + Parquet)")
+    ap.add_argument("--mesh-devices", type=int, default=0,
+                    help="row-shard every table over a mesh of this many "
+                         "devices and run SELECTs as one program over it "
+                         "(SET GLOBAL mesh_devices; 0 = one device)")
     args = ap.parse_args()
 
     from ..exec.session import Database
+    from ..utils.flags import set_flag
     from .mysql_server import MySQLServer
 
     qos = None
@@ -31,6 +36,9 @@ def main():
                          sign_burst=args.qos_rate / 2)
     db = Database(data_dir=args.data_dir or None,
                   cluster=args.meta or None)
+    if args.mesh_devices:
+        set_flag("mesh_devices", args.mesh_devices)
+        db.mesh     # more devices than the process has: fail at start
     srv = MySQLServer(db, host=args.host, port=args.port, qos=qos).start()
     print(f"baikaldb_tpu listening on {args.host}:{srv.port}", flush=True)
     try:

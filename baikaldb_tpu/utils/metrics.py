@@ -648,6 +648,24 @@ multiway_joins_fused = Counter("multiway_joins_fused")
 # input was already hash-partitioned on the key class (transitive
 # partition reuse) — each one is an avoided all_to_all + its trace
 shuffle_rounds_saved = Counter("shuffle_rounds_saved")
+# the mesh arm of the served path (exec/session._run_plan).  mesh_programs:
+# SELECTs that ran as one shard_map program over the deployment's mesh.
+# exchange_bytes: per execution, the bytes that program's repartition and
+# gather collectives carry between chips, reckoned at trace time from static
+# shapes (exec/executor.py): a repartition's all_to_all moves every column's
+# [n, cap] send buffer (validity and the row mask too) less the 1/n that
+# stays home, from each of n chips; a gather's all_gather brings each chip
+# the other n-1 slices.  The buffers are fixed-size, so this is what the ICI
+# carries whatever the live rows; the psum/pmax merges of partial aggregates
+# and flags (a few KB) are not counted.  join_cap_retries: recompiles of the
+# cap-retry loop (a join or shuffle capacity overflowed).  mesh_shard_ms: ms,
+# not a count: the wall time of mesh.shard (parallel/mesh.shard_batch),
+# which runs where a table is first read and writes no query_log row of a
+# warmed window
+mesh_programs = Counter("mesh_programs")
+exchange_bytes = Counter("exchange_bytes")
+join_cap_retries = Counter("join_cap_retries")
+mesh_shard_ms = Counter("mesh_shard_ms")
 # equality-class constant propagation (plan/planner.py): derived
 # col = const conjuncts pushed to sibling scans at plan time
 eqclass_consts_pushed = Counter("eqclass_consts_pushed")

@@ -38,7 +38,6 @@ from baikaldb_tpu import native
 from baikaldb_tpu.client.mysql_client import Connection
 from baikaldb_tpu.exec.session import Database, Session
 from baikaldb_tpu.models import tpch
-from baikaldb_tpu.parallel.mesh import make_mesh
 from baikaldb_tpu.server.mysql_server import MySQLServer
 from baikaldb_tpu.utils import compilecache, metrics
 from baikaldb_tpu.utils.flags import FLAGS
@@ -442,23 +441,30 @@ def phase_groupby(db: Database, wire: Wire, n_rows: int, seed: int) -> None:
 
 # -- 7. four chips -------------------------------------------------------------
 
-def phase_mesh(db: Database, tpch_refs: dict) -> None:
+def phase_mesh(wire: Wire, tpch_refs: dict) -> None:
+    """The deployment's mesh, entered as the benchmark's mesh cell enters
+    it: ``SET GLOBAL mesh_devices`` over the wire connection, after which
+    every SELECT of that connection is one shard_map program; set back to
+    0 at the end, so the phases after it run on one device as before."""
     n = len(jax.devices())
     if n < MESH_DEVICES:
         say(f"mesh: not run, {n} device(s)")
         return
-    mesh = make_mesh(MESH_DEVICES)
 
-    def run(s: Session, label: str, sql: str) -> list:
-        return timed(f"mesh {label}", lambda: s.query(sql))
+    def run(label: str, sql: str, runs: int = 2) -> list:
+        m0 = metrics.mesh_programs.value
+        rows = as_dicts(*wire.select(f"mesh {label}", sql, runs))
+        check(metrics.mesh_programs.value - m0 == runs,
+              f"mesh {label}: not a mesh program")
+        return rows
 
     say(f"mesh: {MESH_DEVICES} devices, lineitem row-sharded")
-    s = Session(db=db, mesh=mesh)
-    check_q1(run(s, "q1", tpch.QUERIES["q1"]), tpch_refs["q1"])
-    check_q3(run(s, "q3 default", tpch.QUERIES["q3"]), tpch_refs["q3"])
+    wire.execute(f"SET GLOBAL mesh_devices = {MESH_DEVICES}")
+    check_q1(run("q1", tpch.QUERIES["q1"]), tpch_refs["q1"])
+    check_q3(run("q3 default", tpch.QUERIES["q3"]), tpch_refs["q3"])
     # MIN/MAX of a DOUBLE merge in-network: the chip lowers no 64-bit max
     # all-reduce, parallel/agg._pextremum all_gathers and reduces locally
-    rows = run(s, "min/max merge",
+    rows = run("min/max merge",
                "SELECT l_returnflag rf, l_linestatus ls, "
                "MIN(l_extendedprice) mn, MAX(l_extendedprice) mx "
                "FROM lineitem WHERE l_orderkey > 0 "
@@ -472,7 +478,8 @@ def phase_mesh(db: Database, tpch_refs: dict) -> None:
     check(len(rows) == len(want), "min/max merge rows")
     for r, (_, w) in zip(rows, want.iterrows()):
         check((r["rf"], r["ls"]) == (w.l_returnflag, w.l_linestatus)
-              and close(r["mn"], w["min"]) and close(r["mx"], w["max"]),
+              and close(float(r["mn"]), w["min"])
+              and close(float(r["mx"]), w["max"]),
               f"min/max merge {r} vs {tuple(w)}")
     say("  cut for the time limit: Q3 with repartition forced "
         "(mpp_broadcast_rows=0, dense_join_span_max=0) is a 118 s compile "
@@ -482,7 +489,7 @@ def phase_mesh(db: Database, tpch_refs: dict) -> None:
     # (utils/hashing.split64).  Over customer, not lineitem: it is here for
     # the lowering, and its compile alone took 125 s over lineitem's shards
     h0 = metrics.shuffle_rounds.value
-    rows = run(s, "double-key group-by",
+    rows = run("double-key group-by",
                "SELECT c_acctbal b, COUNT(*) n FROM customer "
                "GROUP BY c_acctbal ORDER BY c_acctbal")
     check(metrics.shuffle_rounds.value > h0, "double-key group-by ran no "
@@ -490,9 +497,10 @@ def phase_mesh(db: Database, tpch_refs: dict) -> None:
     want = tpch_refs["frames"]["customer"].c_acctbal.value_counts() \
         .sort_index()
     check(len(rows) == len(want)
-          and all(close(r["b"], b) and r["n"] == n
+          and all(close(float(r["b"]), b) and int(r["n"]) == n
                   for r, (b, n) in zip(rows, want.items())),
           "double-key group-by")
+    wire.execute("SET GLOBAL mesh_devices = 0")
     for d in jax.devices():
         say(f"  {d}: bytes_in_use="
             f"{(d.memory_stats() or {}).get('bytes_in_use')}")
@@ -536,7 +544,7 @@ def main() -> int:
         # ahead of the 100M-row phase, which hides the AOT publisher's work:
         # it compiles every mesh program a second time in the background
         # (~120 s for the last one) and phase_counters waits for it
-        phase_mesh(db, refs)
+        phase_mesh(wire, refs)
         phase_groupby(db, wire, NORTH_STAR_ROWS, args.seed)
         phase_counters()
         wire.conn.close()

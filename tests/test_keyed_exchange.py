@@ -275,13 +275,15 @@ def test_tpch_rounds_manifest(monkeypatch):
         for q in ("q5", "q9"):
             assert fs[q]["rounds"] < fs[q]["baseline_rounds"]
             assert fs[q]["collectives"] < fs[q]["baseline_collectives"]
-        # natural regime: q9 reuses a partition outright (fewer executed
-        # collectives); q5/q8/q9 fuse to strictly fewer join stages (q7's
-        # rider chain is a strictly sequential dependency ladder — the one
-        # shape nothing can compress; it pins at parity, never worse)
+        # natural regime: q5/q8/q9 fuse to strictly fewer join stages
+        # (q7's rider chain is a strictly sequential dependency ladder —
+        # the one shape nothing can compress; it pins at parity, never
+        # worse).  q9 no longer reuses a partition here: since the
+        # broadcast rule counts rows moved (build * (n - 1) <= probe) its
+        # partsupp build is gathered, one round and one collective fewer
+        # than the reuse saved (tests/test_multiway.py pins reuse itself)
         nat = manifest["natural"]
-        assert nat["q9"]["reused"] >= 1
-        assert nat["q9"]["collectives"] < nat["q9"]["baseline_collectives"]
+        assert nat["q9"]["rounds"] == 2 and nat["q9"]["reused"] == 0
         for q in ("q5", "q8", "q9"):
             assert nat[q]["join_steps"] < nat[q]["baseline_join_steps"]
         assert nat["q7"]["join_steps"] <= nat["q7"]["baseline_join_steps"]

@@ -173,7 +173,10 @@ def test_sorted_build_join_marked_and_exact():
 
 def test_sorted_build_join_exact_under_mesh():
     """Mesh mode: exchanges on the build side destroy the proved order —
-    the fast path must disengage and results stay exact."""
+    the fast path must disengage and results stay exact: the same rows
+    join (the count is an integer and equal), and the float sum agrees to
+    1e-12 relative — four shards' partial sums merged by psum add in
+    another order than one device does, so the last bit may differ."""
     from baikaldb_tpu.parallel.mesh import make_mesh
 
     if len(jax.devices()) < 4:
@@ -182,7 +185,9 @@ def test_sorted_build_join_exact_under_mesh():
     want = s1.query(SORTED_BUILD_Q)
     s2 = _sorted_build_session(2000, mesh=make_mesh(4))
     got = s2.query(SORTED_BUILD_Q)
-    assert got == want
+    assert len(got) == len(want) == 1
+    assert got[0]["n"] == want[0]["n"]
+    assert got[0]["s"] == pytest.approx(want[0]["s"], rel=1e-12, abs=0)
 
 
 def test_shrink_under_mesh():

@@ -71,6 +71,35 @@ def test_idle_and_busy_seconds_go_to_the_spans_open_meanwhile():
     assert sum(idle.values()) + sum(busy.values()) == pytest.approx(5.0)
     assert got["modules"] == [["jit_run_local", 1.0, 1.0],
                               ["jit_cumsum", 1.0, 0.5]]
+    assert got["op_classes"]["chips"][dev]["other"] == pytest.approx(1.5)
+    assert got["op_classes"]["skew"] == pytest.approx(1.0)
+
+
+def test_device_seconds_by_op_class_and_skew_between_chips():
+    """Two chips of a made-up mesh trace: collectives by the head of their
+    HLO name (async pairs under their collective), the rest as ``other``,
+    cut to the span; skew = busiest chip / mean."""
+    from benchmark import trace_reduce
+    from tools.span_profile import op_class, op_class_seconds
+
+    assert [op_class(n) for n in (
+        "%all-to-all.3 = f32[4,8]{1,0} all-to-all(...)", "all-reduce-start.1",
+        "all-gather-done.2", "collective-permute.7", "%fusion.12",
+        "copy-done")] == ["all-to-all", "all-reduce", "all-gather",
+                          "collective-permute", "other", "other"]
+    d0, d1 = (trace_reduce.DEVICE_PREFIX + c for c in "01")
+    got = op_class_seconds({
+        d0: [(0.0, 1.0, "%fusion.1"), (1.0, 1.5, "%all-to-all.2"),
+             (1.5, 1.75, "all-reduce.3"), (9.0, 11.0, "%fusion.9")],
+        d1: [(0.0, 0.5, "%fusion.1"), (0.5, 1.0, "%all-to-all.2"),
+             (0.75, 1.0, "all-gather-start.4")]}, 0.0, 10.0, 2)
+    assert got["chips"][d0] == pytest.approx({
+        "all-to-all": 0.5, "all-reduce": 0.25, "all-gather": 0.0,
+        "collective-permute": 0.0, "other": 2.0, "busy_s": 2.75})
+    assert got["chips"][d1] == pytest.approx({
+        "all-to-all": 0.5, "all-reduce": 0.0, "all-gather": 0.25,
+        "collective-permute": 0.0, "other": 0.5, "busy_s": 1.0})
+    assert got["skew"] == pytest.approx(2.75 / ((2.75 + 1.0) / 2))
 
 
 def test_metric_files_fit_the_benchmarks_schema():
@@ -109,8 +138,7 @@ def test_rehearsal_prints_every_span_metric(cell, tmp_path):
     got = line["metrics"]
     assert set(CELLS[cell]) <= set(got), set(CELLS[cell]) - set(got)
     # the cell's own per-layer metrics still stand beside them
-    assert any(n.startswith("retraces.") and got[n]["value"] == 0
-               for n in got)
+    assert any("retraces" in n and got[n]["value"] == 0 for n in got)
     untraced = got[[n for n in got if n.startswith("untraced_ms.")][0]]
     assert untraced["value"] >= 0
     assert spans == {"by_program_span": None, "tracing": 0}     # no chip

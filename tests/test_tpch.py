@@ -157,8 +157,9 @@ def test_tpch_shuffle_rounds_pinned(env, monkeypatch):
     scheduler regression fails loudly.  Counted from the per-execution
     metric, so a reused partition that still showed up in the plan tree
     would inflate these numbers — the counter must report EXECUTED
-    repartitions only (q9 reuses one: its pin is 3 rounds / 5 collectives,
-    not the per-edge 3 / 6).  Plan-level pins incl. the per-edge baseline
+    repartitions only.  (q9 reused one until the broadcast rule counted
+    rows moved, build * (n - 1) <= probe: its partsupp build is gathered
+    now, 2 rounds and nothing saved.)  Plan-level pins incl. the per-edge baseline
     live in tests/test_keyed_exchange.py::test_tpch_rounds_manifest."""
     s, dfs = env
     if s.mesh is None:
@@ -172,8 +173,8 @@ def test_tpch_shuffle_rounds_pinned(env, monkeypatch):
     try:
         from baikaldb_tpu.exec.session import Session
         fresh = Session(db=s.db, mesh=s.mesh)
-        pinned = {"q5": 2, "q7": 4, "q8": 2, "q9": 3}
-        saved = {"q9": 1}
+        pinned = {"q5": 2, "q7": 4, "q8": 2, "q9": 2}
+        saved = {}
         for q, want in pinned.items():
             fresh.query(tpch.QUERIES[q])        # settle caps/compiles
             r0 = metrics.shuffle_rounds.value

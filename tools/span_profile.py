@@ -25,6 +25,11 @@ this tool edits none):
   benchmark feeds it client calls.  ``modules`` has the device's seconds by
   program (``[name, runs, seconds]`` from the ``XLA Modules`` line): which
   of the busy time is a plan's program and which an eager op's.
+  ``op_classes`` has every chip's device seconds by class of operation
+  (``all-to-all``, ``all-reduce``, ``all-gather``, ``collective-permute``
+  against the rest) and the skew between the chips (busiest / mean): what a
+  mesh cell's exchanges cost on the device, which the benchmark's own
+  reduction (the ten longest ops, the mean busy time) cannot say.
 
 ``--tracing 1`` turns the ``tracing`` flag on for the run (span trees, the
 store), to read what the tree costs end to end.  Both JSON lines (the
@@ -55,6 +60,11 @@ CLIENT_SPAN = "db.client.query"
 NO_SPAN = "no_program_span"
 MODULES_LINE = "XLA Modules"
 MODULES_TOP = 16
+# classes of device operation, by the head of the op's HLO name (async pairs
+# ``-start`` / ``-done`` fall under their collective)
+COLLECTIVES = ("all-to-all", "all-reduce", "all-gather",
+               "collective-permute")
+OTHER_OPS = "other"
 
 
 def innermost(events: list) -> list:
@@ -126,6 +136,36 @@ def module_seconds(planes: list, first: float, last: float,
                   key=lambda row: -row[2])[:MODULES_TOP]
 
 
+def op_class(name: str) -> str:
+    """The class of a device operation named by its HLO text."""
+    head = re.match(r"%?([a-z][a-z\-]*)", name)
+    for c in COLLECTIVES:
+        if head and head.group(1).startswith(c):
+            return c
+    return OTHER_OPS
+
+
+def op_class_seconds(devices: dict, first: float, last: float,
+                     chips: int) -> dict:
+    """Per chip, the seconds of ``[first, last]`` its operations took by
+    class (summed durations: an async collective overlapping compute counts
+    under both) beside the chip's busy union; and ``skew``, the busiest
+    chip's busy seconds over the mean.  ``devices`` is
+    ``trace_reduce.read_planes``'s ``{plane: [(start, end, name)]}``."""
+    out: dict = {}
+    for plane in sorted(devices)[:chips]:
+        events = trace_reduce.cut(devices[plane], first, last)
+        secs = dict.fromkeys(COLLECTIVES + (OTHER_OPS,), 0.0)
+        for s, e, name in events:
+            secs[op_class(name)] += e - s
+        secs["busy_s"] = trace_reduce.covered(
+            trace_reduce.union([(s, e) for s, e, _ in events]))
+        out[plane] = secs
+    busy = [c["busy_s"] for c in out.values()]
+    mean = sum(busy) / len(busy) if busy else 0.0
+    return {"chips": out, "skew": max(busy) / mean if mean else None}
+
+
 def by_program_span(trace_dir: str, chips: int, host_span: tuple) -> dict:
     """``reduce_spans`` of the ``.xplane.pb`` under ``trace_dir``."""
     from jax.profiler import ProfileData
@@ -167,7 +207,9 @@ def reduce_spans(planes: dict, raw: list, chips: int,
 
     return {"window_s": last - first, "program_span_events": len(spans),
             "idle_s": ranked(idle_s), "busy_s": ranked(busy_s),
-            "modules": module_seconds(raw, first, last, chips)}
+            "modules": module_seconds(raw, first, last, chips),
+            "op_classes": op_class_seconds(planes["devices"], first, last,
+                                           chips)}
 
 
 def main(argv: list | None = None) -> int:
