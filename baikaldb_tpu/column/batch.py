@@ -360,6 +360,32 @@ def pad_batch(batch: ColumnBatch, capacity: int) -> ColumnBatch:
     return ColumnBatch(batch.names, cols, sel, None, live_prefix=prefix)
 
 
+@jax.jit
+def _take_rows(arrays, sel, idx, n):
+    live = jnp.arange(idx.shape[0]) < n
+    if sel is not None:
+        live = jnp.logical_and(live, jnp.take(sel, idx, mode="clip"))
+    return [None if a is None else jnp.take(a, idx, axis=0, mode="clip")
+            for a in arrays], live
+
+
+def gather_padded(batch: ColumnBatch, positions: np.ndarray,
+                  capacity: int) -> ColumnBatch:
+    """The rows of ``batch`` at host ``positions``, as ``capacity`` rows
+    with a dead tail: ``sel`` is off past ``len(positions)`` and wherever
+    the gathered row's own ``sel`` was.  Only the positions cross host ->
+    device, in one program over the arrays alone — each column keeps its
+    ``Dictionary`` OBJECT, and the program is keyed on shapes, never on the
+    literals that chose the positions nor on dictionary content."""
+    idx = np.zeros(capacity, np.int32)
+    idx[:len(positions)] = positions
+    arrays = [a for c in batch.columns for a in (c.data, c.validity)]
+    out, live = _take_rows(arrays, batch.sel, idx, len(positions))
+    cols = [replace(c, data=d, validity=v)
+            for c, d, v in zip(batch.columns, out[0::2], out[1::2])]
+    return ColumnBatch(batch.names, cols, live, None)
+
+
 def concat_batches(batches: list[ColumnBatch]) -> ColumnBatch:
     """Concatenate same-schema batches (densified) along rows."""
     assert batches

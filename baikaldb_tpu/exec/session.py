@@ -4781,7 +4781,7 @@ class Session:
                                         table=n.table_key) as sp:
                             b = self._access_path_batch(n, db, name, store)
                             if b is not None:
-                                sp.set(rows=len(b))
+                                sp.set(rows=len(b), access=n.access_desc)
                 if b is None:
                     if self.mesh is not None:
                         b = self.db.sharded_batch(n.table_key, store,
@@ -4856,11 +4856,14 @@ class Session:
 
     def _access_path_batch(self, n, db: str, name: str, store):
         """IndexSelector-driven scan input (index/selector.py): a secondary
-        equality gathers just the matching rows; zone maps drop whole
-        regions.  Returns None for a full scan (the default batch).  The
-        device program's own filter still runs — these are conservative row
-        supersets, so correctness never depends on the index choice."""
-        from ..index.selector import analyze_conjuncts, choose_access
+        equality gathers just the matching rows; a primary-key range
+        gathers its rows out of the resident image into one capacity
+        bucket; zone maps drop whole regions.  Returns None for a full
+        scan (the default batch).  The device program's own filter still
+        runs — these are conservative row supersets, so correctness never
+        depends on the index choice."""
+        from ..index.selector import (analyze_conjuncts, choose_access,
+                                      pk_range_capacity, pk_range_desc)
 
         if n.pushed_filter is None:
             return None
@@ -4908,6 +4911,20 @@ class Session:
                 cache[ck] = b
             metrics.index_scans.add(1)
             return b
+        if access[0] == "pk_range":
+            from ..column.batch import gather_padded
+            _, col, lo, hi = access
+            # positions and image of one version; the batch is NOT a
+            # full_scan member (positions differ per literal) and has no
+            # entry in the cache above: the gather is one small program,
+            # cheaper than the cache's eviction walk and its pinned arrays
+            pos, image = store.pk_range_scan(lo, hi)
+            n.access_desc = pk_range_desc(col, len(pos), store.num_rows)
+            metrics.pk_range_scans.add(1)
+            metrics.pk_range_rows.add(len(pos))
+            # every literal's rows land in one capacity bucket with the
+            # image's own dictionaries, so the plan compiles once a bucket
+            return gather_padded(image, pos, pk_range_capacity(len(pos)))
         if access[0] == "partition":
             _, parts, ptotal = access
             keep, rtotal = store.prune_parts(parts)
@@ -4992,7 +5009,8 @@ class Session:
     def _annotate_access(self, plan: PlanNode):
         """EXPLAIN display: run IndexSelector per scan without building
         batches, so the shown choice flips with the predicates."""
-        from ..index.selector import analyze_conjuncts, choose_access
+        from ..index.selector import (analyze_conjuncts, choose_access,
+                                      pk_range_desc)
         from ..plan.nodes import ScanNode
 
         def walk(n):
@@ -5013,6 +5031,11 @@ class Session:
                         elif access[0] == "global":
                             n.access_desc = \
                                 f"global_index({access[1]}:{access[2]})"
+                        elif access[0] == "pk_range":
+                            n.access_desc = pk_range_desc(
+                                access[1],
+                                store.pk_range_count(access[2], access[3]),
+                                store.num_rows)
                         elif access[0] == "partition":
                             n.access_desc = (
                                 f"partition({access[2] - len(access[1])}"

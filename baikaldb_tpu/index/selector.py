@@ -15,6 +15,11 @@ what NOT to ship to the device:
   the matching row positions; the device program runs over just those rows
   (reference: secondary-index range read).  Only chosen when the estimated
   match fraction is small — at high selectivity the full scan wins.
+- **pk_range**: a range (or a non-point equality) on a single-column
+  orderable primary key -> the host's sorted key order names the matching
+  row positions; the device program runs over a fixed-capacity gather of
+  them out of the resident image (reference: primary-index range read).
+  Same selectivity gate as the secondary arm.
 - **zonemap**: range/equality predicates on numeric/temporal columns prune
   whole regions by their min/max before upload (reference: the column
   tier's statistics pruning).
@@ -55,10 +60,31 @@ def analyze_conjuncts(e: Optional[Expr]) -> ScanPredicates:
     if e is None:
         return sp
 
+    def bound(col, op, v):
+        lo, hi = sp.ranges.get(col, [None, None])
+        try:
+            if op in ("eq", "gt", "ge"):
+                lo = v if lo is None else max(lo, v)
+            if op in ("eq", "lt", "le"):
+                hi = v if hi is None else min(hi, v)
+        except TypeError:
+            return          # mixed-type literals on one column: no constraint
+        sp.ranges[col] = [lo, hi]
+
     def visit(x):
         if isinstance(x, Call) and x.op == "and":
             for a in x.args:
                 visit(a)
+            return
+        if isinstance(x, Call) and x.op == "between" and len(x.args) == 3:
+            c, lo, hi = x.args
+            # col BETWEEN lit AND lit is col >= lo AND col <= hi; a NULL
+            # bound matches no row, which "no constraint" safely covers
+            if isinstance(c, ColRef) and isinstance(lo, Lit) \
+                    and isinstance(hi, Lit) and lo.value is not None \
+                    and hi.value is not None:
+                bound(_strip(c.name), "ge", lo.value)
+                bound(_strip(c.name), "le", hi.value)
             return
         if not isinstance(x, Call) or x.op not in _RANGE_OPS:
             return
@@ -78,21 +104,26 @@ def analyze_conjuncts(e: Optional[Expr]) -> ScanPredicates:
             return
         if op == "eq":
             sp.eq[col] = v
-        lo, hi = sp.ranges.get(col, [None, None])
-        try:
-            if op == "eq":
-                lo = v if lo is None else max(lo, v)
-                hi = v if hi is None else min(hi, v)
-            elif op in ("gt", "ge"):
-                lo = v if lo is None else max(lo, v)
-            else:                                  # lt / le
-                hi = v if hi is None else min(hi, v)
-        except TypeError:
-            return          # mixed-type literals on one column: no constraint
-        sp.ranges[col] = [lo, hi]
+        bound(col, op, v)
 
     visit(e)
     return sp
+
+
+def pk_range_capacity(matches: int) -> int:
+    """Rows of the pk_range arm's gathered scan input: the capacity bucket
+    ``matches`` pad into, by the rule full tables follow — every literal's
+    range of a statement lands in one bucket, so its plan compiles once."""
+    from ..column.batch import bucket_capacity
+    from ..utils.flags import FLAGS
+
+    return bucket_capacity(matches, int(FLAGS.batch_bucket_min))
+
+
+def pk_range_desc(col: str, matches: int, rows: int) -> str:
+    """EXPLAIN's text for the pk_range arm."""
+    return (f"pk_range({col}: {matches} of {rows} rows, "
+            f"capacity {pk_range_capacity(matches)})")
 
 
 def is_point_statement(stmt) -> bool:
@@ -156,7 +187,8 @@ def _collect_eq_terms(e, out: list) -> bool:
 def choose_access(info, store, pred: ScanPredicates,
                   secondary_max_fraction: float = 0.2, db=None):
     """-> ("secondary", index_name, col, value) |
-    ("global", index_name, col, value) | ("zonemap", ranges) | ("full",).
+    ("global", index_name, col, value) | ("pk_range", col, lo, hi) |
+    ("partition", parts, total) | ("zonemap", ranges) | ("full",).
     Point lookups are decided at the statement level, not here.  ``db``
     (the Database) resolves global indexes' backing stores; without it the
     global route is not considered."""
@@ -195,6 +227,20 @@ def choose_access(info, store, pred: ScanPredicates,
             matches = bstore.secondary_count(col, pred.eq[col])
             if matches is not None and matches / n <= secondary_max_fraction:
                 return ("global", ix.name, col, pred.eq[col])
+    # a range on the primary key (an equality in a statement that is not a
+    # point read is the range [v, v]): the key's sorted order names the row
+    # positions, so the scan reads those rows and not the table.  Decided on
+    # what the code can observe — the key's shape, the filter's bounds, and
+    # the match count: under the secondary arm's selectivity gate, and only
+    # when the capacity bucket the matches pad into is smaller than the table
+    pkc = store.pk_range_column()
+    if pkc is not None and pkc in pred.ranges:
+        lo, hi = pred.ranges[pkc]
+        n = store.num_rows
+        matches = store.pk_range_count(lo, hi)
+        if matches is not None and matches <= secondary_max_fraction * n \
+                and pk_range_capacity(matches) < n:
+            return ("pk_range", pkc, lo, hi)
     # table-partition pruning (reference: PartitionAnalyze,
     # physical_planner.cpp:27-120): a predicate on the partition column
     # drops whole partitions' regions before zone maps even look

@@ -1053,6 +1053,56 @@ class TableStore:
             pos = self.secondary_positions(column, value)
             return self.snapshot().take(pos)
 
+    def pk_range_column(self) -> Optional[str]:
+        """The primary key's column when a range over it can name row
+        positions: one column of an orderable fixed-width type (the types
+        zone maps prune on).  None for a composite, string or absent key."""
+        if not self._pk_cols or len(self._pk_cols) != 1:
+            return None
+        lt = self.info.schema.field(self._pk_cols[0]).ltype
+        if lt.is_integer or lt.is_float or lt is LType.DATE \
+                or lt.is_temporal:
+            return self._pk_cols[0]
+        return None
+
+    def _pk_range_slice(self, lo, hi):
+        """(positions in key order, i, j): the rows with pk in the closed
+        range [lo, hi] are positions[i:j].  Either bound may be None; a
+        literal the key's type cannot be compared with leaves its side
+        open (callers only need a superset)."""
+        col = self.pk_range_column()
+        lt = self.info.schema.field(col).ltype
+        svals, spos = self._secondary_order(col)
+        lo, hi = _zone_scalar(lo, lt), _zone_scalar(hi, lt)
+        if svals.dtype.kind == "M":
+            # DATE -> epoch days, DATETIME/TIMESTAMP -> epoch seconds: the
+            # unit _zone_scalar speaks; back to the column's datetime64
+            unit, scale = ("D", 1) if lt is LType.DATE else ("us", 10**6)
+            lo, hi = (None if b is None else
+                      np.datetime64(int(round(b * scale)), unit)
+                      for b in (lo, hi))
+        i = 0 if lo is None else int(np.searchsorted(svals, lo, "left"))
+        j = len(svals) if hi is None else \
+            int(np.searchsorted(svals, hi, "right"))
+        return spos, i, max(i, j)
+
+    def pk_range_count(self, lo, hi) -> Optional[int]:
+        """How many rows hold pk in [lo, hi], for a table that has a
+        pk_range_column; None when a bound does not compare with the key."""
+        try:
+            _, i, j = self._pk_range_slice(lo, hi)
+        except (TypeError, ValueError, OverflowError):
+            return None
+        return j - i
+
+    def pk_range_scan(self, lo, hi):
+        """(ascending snapshot positions of the rows with pk in [lo, hi],
+        the resident device image they index), both of ONE version: taken
+        under one lock acquisition, as secondary_scan takes its pair."""
+        with self._lock:
+            spos, i, j = self._pk_range_slice(lo, hi)
+            return np.sort(spos[i:j]), self.device_table_batch()
+
     def point_lookup(self, values: dict):
         """Primary-key point read from the host tier (no device program).
         -> row dict or None.  ``values``: pk column -> python literal."""

@@ -556,6 +556,11 @@ point_lookups = Counter("point_lookups")
 point_lookup_ms = Counter("point_lookup_ms")
 wire_result_set_ms = Counter("wire_result_set_ms")
 index_scans = Counter("index_scans")
+# statements whose scan input was the pk_range arm's fixed-capacity gather
+# out of the resident image (exec/session._access_path_batch), and the rows
+# their key ranges matched
+pk_range_scans = Counter("pk_range_scans")
+pk_range_rows = Counter("pk_range_rows")
 regions_pruned = Counter("regions_pruned")
 # XLA (re)traces of query programs: each count is one compile.  With capacity
 # bucketing on, an identical SELECT repeated across DML that stays inside one

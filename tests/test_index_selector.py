@@ -75,24 +75,32 @@ def test_secondary_skipped_at_high_selectivity():
 
 
 def test_zone_map_pruning(sess):
+    # on `score`, not the key: a selective range on `id` is the pk_range
+    # arm's (tests/test_pk_range.py), tried before the zone maps
     st = sess.db.stores["default.u"]
     st.region_rows = 200
     sess.execute("INSERT INTO u VALUES " +
                  ",".join(f"({i},'z',{i * 1.0})" for i in range(2000, 3000)))
     assert len(st.regions) > 3
     plan = sess.execute("EXPLAIN SELECT SUM(score) FROM u "
-                        "WHERE id >= 2900").plan_text
+                        "WHERE score >= 2900").plan_text
     assert "zonemap(" in plan and "regions pruned" in plan
     r0 = metrics.regions_pruned.value
-    assert sess.query("SELECT COUNT(*) c FROM u WHERE id >= 2900") == \
+    assert sess.query("SELECT COUNT(*) c FROM u WHERE score >= 2900") == \
         [{"c": 100}]
     assert metrics.regions_pruned.value > r0
     # range on both sides
-    assert sess.query("SELECT COUNT(*) c FROM u WHERE id >= 2100 "
-                      "AND id < 2300") == [{"c": 200}]
+    assert sess.query("SELECT COUNT(*) c FROM u WHERE score >= 2100 "
+                      "AND score < 2300") == [{"c": 200}]
     # predicate outside every zone -> all regions pruned, empty result
-    assert sess.query("SELECT COUNT(*) c FROM u WHERE id > 10000000") == \
+    assert sess.query("SELECT COUNT(*) c FROM u WHERE score > 10000000") == \
         [{"c": 0}]
+    # a range on the key too wide for the pk_range arm still prunes regions
+    plan = sess.execute("EXPLAIN SELECT SUM(score) FROM u "
+                        "WHERE id >= 2100").plan_text
+    assert "zonemap(" in plan
+    assert sess.query("SELECT COUNT(*) c FROM u WHERE id >= 2100") == \
+        [{"c": 900}]
 
 
 def test_zone_map_dates():
@@ -132,6 +140,30 @@ def test_point_lookup_residual_predicates_respected(sess):
     # duplicate output names keep the device path's rename behavior
     r = sess.query("SELECT name, name FROM u WHERE id = 7")
     assert len(r[0]) == 2
+
+
+def _ranges(where: str) -> dict:
+    from baikaldb_tpu.index.selector import analyze_conjuncts
+    from baikaldb_tpu.sql.parser import parse_sql
+
+    stmt, = parse_sql(f"SELECT id FROM u WHERE {where}")
+    return analyze_conjuncts(stmt.where).ranges
+
+
+@pytest.mark.parametrize("where,want", [
+    ("id BETWEEN 5 AND 104", {"id": [5, 104]}),
+    ("id BETWEEN 1 AND 9 AND id > 3", {"id": [3, 9]}),
+    ("id >= 2 AND id BETWEEN 1 AND 9 AND score < 7.5",
+     {"id": [2, 9], "score": [None, 7.5]}),
+    ("id BETWEEN NULL AND 9", {}),
+    ("id BETWEEN 1 AND NULL", {}),
+    ("id BETWEEN score AND 9", {}),
+    ("id BETWEEN 1 AND score", {}),
+    ("id NOT BETWEEN 1 AND 9", {}),
+    ("id BETWEEN 1 AND 9 OR id = 20", {}),
+])
+def test_between_is_the_closed_range(where, want):
+    assert _ranges(where) == want
 
 
 def test_mixed_type_literals_dont_crash(sess):
