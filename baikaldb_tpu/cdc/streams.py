@@ -40,10 +40,8 @@ from typing import Iterable, Iterator, Optional
 from ..chaos import failpoint
 from ..meta.service import Tso
 from ..utils import metrics
-from ..utils.flags import FLAGS, define
 
-define("cdc_fetch_batch", 512,
-       "default FETCH batch size for subscription cursors")
+FETCH_BATCH = 512       # FETCH batch size when the statement names none
 
 # binlog cursor-table namespace for subscriptions — keeps SQL-created
 # cursor names from colliding with raw Capturer names
@@ -150,7 +148,7 @@ class Subscription:
         CursorLagging once if GC ran past this cursor."""
         from ..obs import trace
 
-        limit = int(limit) or int(FLAGS.cdc_fetch_batch)
+        limit = int(limit) or FETCH_BATCH
         metrics.cdc_fetches.add(1)
         with trace.span("cdc.fetch", subscription=self.name,
                         since=self.acked):

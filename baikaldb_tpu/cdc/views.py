@@ -45,16 +45,12 @@ from ..chaos import failpoint
 from ..index.rollup import refresh_sql, rollup_schema
 from ..meta.service import Tso
 from ..utils import metrics
-from ..utils.flags import FLAGS, define
+from ..utils.flags import define
 from .streams import CursorLagging
 
 define("matview_answer", True,
        "answer matching GROUP BY queries from materialized-view state "
        "(off: always recompute from the base table — bit-identical)")
-define("matview_auto_maintain", True,
-       "fold pending change-stream deltas into a materialized view "
-       "before answering from it (off: answers serve the last folded "
-       "state and staleness grows)")
 
 MV_PREFIX = "__mv_"
 

@@ -64,8 +64,8 @@ from ..utils.flags import FLAGS, define
 
 define("chaos_enable", False,
        "master switch for failpoint evaluation; arming any failpoint also "
-       "enables the sites (the flag alone lets the overhead of evaluated-"
-       "but-unarmed sites be measured, bench.py line 5)")
+       "enables the sites (the flag alone evaluates the sites with nothing "
+       "armed)")
 define("chaos_seed", 0,
        "seed of the deterministic failpoint RNG: every armed point's "
        "trigger schedule is a pure function of (chaos_seed, point name, "

@@ -20,8 +20,7 @@ Two planes, two guarantees:
 Every scenario returns a JSON-able dict: ``fault_schedule`` (the injected
 faults, in order), ``state_digest`` (sha256 over the deterministic final
 state), assertion results, and observed counters (retries, dedupe hits,
-latency percentiles).  ``python -m tools.chaos_run --seed N`` drives them;
-bench.py reuses ``rpc_chaos`` for its seeded latency-injection line.
+latency percentiles).  ``python -m tools.chaos_run --seed N`` drives them.
 """
 
 from __future__ import annotations

@@ -75,17 +75,18 @@ define("batch_dispatch_queue_max", 1024,
        "bounded per-group queue: arrivals beyond this many waiting queries "
        "get a typed DispatchOverload rejection instead of queueing "
        "unboundedly")
-define("batch_dispatch_wait_s", 120.0,
-       "waiter safety net: a member falls back to inline execution if its "
-       "combine result does not arrive within this window (covers a leader "
-       "paying a multi-second first compile)")
-define("batch_dispatch_cache", 64,
-       "batched executables kept by the dispatcher (distinct (statement "
-       "group, shapes, padded group size) triples)")
-define("batch_dispatch_scatter_rows", 128,
-       "static per-lane scatter budget: the batched executable returns up "
-       "to this many live rows per client (the egress compact fused into "
-       "the program); a lane returning more re-runs inline")
+
+# waiter safety net: a member falls back to inline execution if its combine
+# result does not arrive within this window (covers a leader paying a
+# multi-second first compile)
+WAIT_S = 120.0
+# batched executables kept by the dispatcher (distinct (statement group,
+# shapes, padded group size) triples)
+CACHE_ENTRIES = 64
+# static per-lane scatter budget: the batched executable returns up to this
+# many live rows per client (the egress compact fused into the program); a
+# lane returning more re-runs inline
+SCATTER_ROWS = 128
 
 
 class DispatchOverload(RejectedError):
@@ -250,8 +251,7 @@ class BatchDispatcher:
             # cancellation point (the dispatch queue is a pure read path —
             # abandoning the rendezvous has no side effects; the leader's
             # combined run just carries one unread lane)
-            deadline = time.perf_counter() + \
-                float(FLAGS.batch_dispatch_wait_s)
+            deadline = time.perf_counter() + WAIT_S
             while True:
                 remaining = deadline - time.perf_counter()
                 ok = w.done.wait(timeout=min(0.05, max(0.0, remaining)))
@@ -348,8 +348,7 @@ class BatchDispatcher:
             plan = self._plans.get(ck_base)
             if plan is None:
                 self._plans[ck_base] = plan = entry["plan"]
-                while len(self._plans) > max(1, int(
-                        FLAGS.batch_dispatch_cache)):
+                while len(self._plans) > CACHE_ENTRIES:
                     self._plans.popitem(last=False)
             self.occupancy[G] = self.occupancy.get(G, 0) + 1
         metrics.batched_groups.add(1)
@@ -357,7 +356,6 @@ class BatchDispatcher:
         from ..utils import compilecache
         from .executor import AotRawShim, flag_meta_of
 
-        scap = max(1, int(FLAGS.batch_dispatch_scatter_rows))
         # AOT artifact identity for this batched program: the statement
         # group + plan signature (ck_base), the padded group size and
         # scatter budget, the input skeleton (incl. dictionary content)
@@ -370,7 +368,7 @@ class BatchDispatcher:
             if aot_key is None and compilecache.AOT.enabled():
                 aot_key = compilecache.aot_key(
                     "batched", entry.get("plan_sig"),
-                    (str(ck_base), gpad, scap),
+                    (str(ck_base), gpad, SCATTER_ROWS),
                     compilecache.input_fingerprint((table_batches,
                                                     stacked)))
             return aot_key
@@ -389,7 +387,7 @@ class BatchDispatcher:
             raw2 = compile_plan(plan)
             meta2: list = []
 
-            def batched2(tb, sp_, _raw=raw2, _meta=meta2, _cap=scap):
+            def batched2(tb, sp_, _raw=raw2, _meta=meta2, _cap=SCATTER_ROWS):
                 def one(p):
                     b = dict(tb)
                     b[PARAMS_KEY] = p
@@ -436,7 +434,7 @@ class BatchDispatcher:
                     raw = compile_plan(plan)
                     meta: list = []          # filled at trace time
 
-                    def batched(tb, sp_, _raw=raw, _meta=meta, _cap=scap):
+                    def batched(tb, sp_, _raw=raw, _meta=meta, _cap=SCATTER_ROWS):
                         def one(p):
                             b = dict(tb)
                             b[PARAMS_KEY] = p
@@ -450,8 +448,7 @@ class BatchDispatcher:
                             meta, None)
                     with self._mu:
                         self._compiled[ck] = pair
-                        while len(self._compiled) > max(1, int(
-                                FLAGS.batch_dispatch_cache)):
+                        while len(self._compiled) > CACHE_ENTRIES:
                             self._compiled.popitem(last=False)
                 fn, raw, meta, _aot_vk = pair
                 traces_before = raw.trace_count[0]

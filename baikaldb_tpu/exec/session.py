@@ -2819,7 +2819,7 @@ class Session:
 
     def _try_matview(self, stmt: SelectStmt, refresh: bool = True):
         """If a registered materialized view covers this GROUP BY SELECT,
-        fold its pending change-stream deltas (matview_auto_maintain),
+        fold its pending change-stream deltas,
         flush state into the hidden __mv_* table, and return the
         rewritten statement.  ``refresh=False`` (EXPLAIN) only rewrites.
         The same gates as _try_rollup: never inside a pinned snapshot or
@@ -2843,8 +2843,7 @@ class Session:
             if rw is None:
                 continue
             if refresh:
-                if FLAGS.matview_auto_maintain:
-                    mv.maintain(self)
+                mv.maintain(self)
                 mv.materialize(self)
                 mv.answered += 1
                 metrics.view_answered_queries.add(1)
@@ -5009,14 +5008,14 @@ class Session:
     def _annotate_access(self, plan: PlanNode):
         """EXPLAIN display: run IndexSelector per scan without building
         batches, so the shown choice flips with the predicates."""
+        from ..index.annindex import ANN_NPROBE
         from ..index.selector import (analyze_conjuncts, choose_access,
                                       pk_range_desc)
         from ..plan.nodes import ScanNode
 
         def walk(n):
             if isinstance(n, ScanNode) and getattr(n, "ann", None):
-                n.access_desc = (f"ann({n.ann[0]} "
-                                 f"nprobe={int(FLAGS.ann_nprobe)})")
+                n.access_desc = f"ann({n.ann[0]} nprobe={ANN_NPROBE})"
                 return
             if isinstance(n, ScanNode) and "." in n.table_key:
                 db, name = n.table_key.split(".", 1)

@@ -15,7 +15,7 @@ candidate rows flow through the normal pipeline.
 
 Index lifecycle: trained lazily from the store's current snapshot; on data
 change the centroids are KEPT and rows re-assigned (one matmul) while the
-row count drifts less than ``ann_rebuild_drift``, beyond which k-means
+row count drifts less than ``ANN_REBUILD_DRIFT``, beyond which k-means
 retrains — the faiss train/add split re-imagined as a drift policy.
 """
 
@@ -30,21 +30,20 @@ from ..expr.ast import Call, ColRef, Lit
 from ..sql.stmt import SelectStmt
 from ..utils.flags import FLAGS, define
 
-define("ann_nprobe", 8, "IVF clusters probed per ANN query")
-define("ann_oversample", 4,
-       "candidate factor over LIMIT k for the exact re-rank stage")
-define("ann_max_k", 1024, "largest LIMIT served through the ANN path")
 define("ann_min_rows", 4096,
        "below this row count the fused brute-force scan wins")
-define("ann_rebuild_drift", 0.2,
-       "fraction of row-count drift that triggers k-means retraining "
-       "(smaller drifts only re-assign rows to existing centroids)")
 define("ann_where_widen", 8,
        "WHERE-filtered ANN queries multiply oversample and nprobe by this: "
        "the filter drops candidates AFTER reduction, so the pre-filter pool "
        "must run deeper or LIMIT k silently under-fills; once the widened "
        "pool approaches the table the scan falls back to brute force")
-define("ann_nlist", 0, "IVF cluster count; 0 = sqrt(n)")
+
+ANN_NPROBE = 8          # IVF clusters probed per ANN query
+ANN_OVERSAMPLE = 4      # candidate factor over LIMIT k for the exact re-rank
+ANN_MAX_K = 1024        # largest LIMIT served through the ANN path
+# fraction of row-count drift that triggers k-means retraining (smaller
+# drifts only re-assign rows to existing centroids)
+ANN_REBUILD_DRIFT = 0.2
 
 # distance fn -> (ops.vector metric, ascending order expected)
 _DIST_OPS = {"l2_distance": ("l2", True),
@@ -104,7 +103,7 @@ def match_ann_query(stmt: SelectStmt, info, label: str):
            [it.expr for it in stmt.items] + [stmt.where]
            + [o.expr for o in stmt.order_by]):
         return None
-    if stmt.limit + stmt.offset > int(FLAGS.ann_max_k):
+    if stmt.limit + stmt.offset > ANN_MAX_K:
         return None
     vector_cols = (info.options or {}).get("vector_cols") or {}
     if not vector_cols:
@@ -185,8 +184,8 @@ class AnnManager:
         valid = ~np.isnan(m).any(axis=1)
         m = np.nan_to_num(m).astype(np.float32)
         drift = abs(n - st.built_rows) / max(st.built_rows, 1)
-        if st.centroids is None or drift > float(FLAGS.ann_rebuild_drift):
-            nc = int(FLAGS.ann_nlist) or max(16, int(np.sqrt(n)))
+        if st.centroids is None or drift > ANN_REBUILD_DRIFT:
+            nc = max(16, int(np.sqrt(n)))
             nc = min(nc, max(n // 8, 1))
             st.centroids, assign = kmeans(m, nc)
             st.built_rows = n
@@ -243,12 +242,10 @@ class AnnManager:
                 return None
             n = st.matrix.shape[0]
             widen = max(1, int(FLAGS.ann_where_widen)) if filtered else 1
-            k2 = min(n, max(k * int(FLAGS.ann_oversample) * widen,
-                            64 * widen))
+            k2 = min(n, max(k * ANN_OVERSAMPLE * widen, 64 * widen))
             if filtered and 2 * k2 >= n:
                 return None     # pool ~ the table: brute force is exact
-            nprobe = min(int(FLAGS.ann_nprobe) * widen,
-                         st.centroids.shape[0])
+            nprobe = min(ANN_NPROBE * widen, st.centroids.shape[0])
             scores, idx = ivf_search_host(
                 np.asarray(qvec, np.float32), st.matrix, st.valid,
                 st.centroids, st.starts, st.counts, k2, nprobe, metric,

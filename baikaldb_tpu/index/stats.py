@@ -33,10 +33,11 @@ from ..utils.flags import FLAGS, define
 define("histogram_stats", True,
        "planner selectivity from equi-depth histograms + MCVs instead of "
        "fixed constants")
-define("histogram_buckets", 64, "equi-depth histogram bucket count")
-define("histogram_mcv", 16, "most-common values kept per column")
 define("histogram_sample", 200_000,
        "stats sample cap (rows) per column collection")
+
+HISTOGRAM_BUCKETS = 64      # equi-depth histogram bucket count
+HISTOGRAM_MCV = 16          # most-common values kept per column
 
 # the pre-histogram fixed constants, kept as the fallback
 DEFAULT_EQ_SEL = 0.1
@@ -134,7 +135,7 @@ def collect(values: np.ndarray, n_total: int, n_nulls: int,
             out["ndv"] = int(min(len(uniq) + singletons * (scale - 1.0),
                                  n_total - n_nulls)) or 1
             out["ndv_method"] = "chao"
-    k = int(FLAGS.histogram_mcv)
+    k = HISTOGRAM_MCV
     if len(uniq) <= k:
         mcv_idx = np.argsort(-counts)
     else:
@@ -144,7 +145,7 @@ def collect(values: np.ndarray, n_total: int, n_nulls: int,
                    else uniq[i], float(counts[i] * scale))
                   for i in mcv_idx]
     if numeric:
-        b = int(FLAGS.histogram_buckets)
+        b = HISTOGRAM_BUCKETS
         qs = np.quantile(sample.astype(np.float64),
                          np.linspace(0.0, 1.0, b + 1))
         out["hist"] = [float(x) for x in qs]

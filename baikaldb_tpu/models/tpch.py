@@ -1,14 +1,15 @@
-"""TPC-H schema, data generator, and the full 22-query suite.
+"""TPC-H schema, a small data generator and the 22 queries — a test fixture.
 
-The "model family" of an HTAP engine is its benchmark workloads; TPC-H is
-the standard OLAP suite (BASELINE config #5).  This module carries:
+``tests/test_tpch.py``, ``tests/test_tpch_full.py``,
+``tests/test_keyed_exchange.py`` and ``chip_smoke.py`` load it.  It is a source
+for no measurement: the benchmark's data comes from
+``benchmark/loaders/tpch.py``, which follows dbgen where the queries look.
+This module carries:
 
 - the full 8-table TPC-H schema (CREATE TABLE statements),
-- a self-contained columnar data generator (a numpy dbgen stand-in: uniform
-  keys/dates/prices with the spec's categorical domains and patterned
-  strings so every LIKE/phrase predicate selects meaningfully — not the
-  official dbgen streams, but the same shapes/selectivities for engine
-  benchmarking),
+- a self-contained columnar data generator (not dbgen's distributions:
+  uniform keys/dates/prices with the spec's categorical domains and
+  patterned strings, so every LIKE/phrase predicate selects meaningfully),
 - all 22 queries adapted to this engine's SQL surface: date arithmetic
   resolved to literals, EXTRACT(YEAR ..) as YEAR(), views as CTEs.
 """

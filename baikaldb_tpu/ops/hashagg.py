@@ -287,19 +287,15 @@ def _pallas_dense_cols(batch, specs, gid, ng: int, sel):
       kernel's ~1e-7 relative error); MIN/MAX additionally need FLOAT32
       columns (f64 values would be rounded by the f32 pipeline);
     - group count in (select+reduce crossover, PALLAS_MAX_GROUPS];
-    - TPU backend + FLAGS.pallas_group_kernels.
+    - TPU backend.
 
     Returns the aggregate Columns (spec order), or None to use segments."""
-    import jax as _jax
-
-    from ..utils.flags import FLAGS
     from . import segments
     from .pallas_kernels import (PALLAS_MAX_GROUPS, filtered_group_sum,
                                  fused_group_aggregate, partition_histogram)
 
-    if not (bool(FLAGS.pallas_group_kernels)
-            and _jax.default_backend() not in ("cpu",)
-            and segments._max_segments() < ng + 1 <= PALLAS_MAX_GROUPS):
+    if not (segments._onehot_backend()
+            and segments.ONEHOT_MAX_SEGMENTS < ng + 1 <= PALLAS_MAX_GROUPS):
         return None
     for s in specs:
         if s.distinct or s.op not in ("count_star", "count", "sum", "avg",

@@ -34,14 +34,8 @@ def _onehot_backend() -> bool:
     return jax.default_backend() not in ("cpu",)
 
 
-def _max_segments() -> int:
-    """Flag-tunable crossover (utils/flags.py: onehot_max_segments)."""
-    from ..utils.flags import FLAGS
-    return int(FLAGS.onehot_max_segments)
-
-
 def _use_onehot(num_segments: int) -> bool:
-    return _onehot_backend() and num_segments <= _max_segments()
+    return _onehot_backend() and num_segments <= ONEHOT_MAX_SEGMENTS
 
 
 def seg_sum(x, gid, num_segments: int):

@@ -37,15 +37,16 @@ define("adaptive_agg", True,
        "for distributed GROUP BY, from the stats distinct-count estimate "
        "(off: the pre-round-7 static policy — dense pre-reduces, sorted "
        "shuffles raw)")
-define("agg_local_ratio", 0.5,
-       "pre-reduce locally when estimated groups <= ratio * rows-per-shard "
-       "(above it the partial pass moves more data than it saves)")
 define("adaptive_agg_selectivity", True,
        "feed the bound-value WHERE selectivity (index/stats histograms "
        "over THIS execution's literals) into the local-vs-raw decision: a "
        "highly selective predicate shrinks effective rows-per-shard and "
        "can flip local -> raw per execution.  0 restores the "
        "selectivity-blind threshold")
+
+# pre-reduce locally when estimated groups <= ratio * rows-per-shard: above
+# it the partial pass moves more data than it saves
+AGG_LOCAL_RATIO = 0.5
 
 
 def choose_strategy(est_groups: Optional[int], rows_per_shard: int,
@@ -66,8 +67,8 @@ def choose_strategy(est_groups: Optional[int], rows_per_shard: int,
         return "raw"
     if selectivity is not None and FLAGS.adaptive_agg_selectivity:
         rows_per_shard = max(1, int(rows_per_shard * float(selectivity)))
-    ratio = float(FLAGS.agg_local_ratio)
-    return "local" if est_groups <= max(1, int(rows_per_shard * ratio)) \
+    return "local" \
+        if est_groups <= max(1, int(rows_per_shard * AGG_LOCAL_RATIO)) \
         else "raw"
 
 

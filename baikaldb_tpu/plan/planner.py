@@ -46,10 +46,10 @@ from .nodes import (AggNode, DistinctNode, FilterNode, JoinNode, LimitNode,
                     MembershipNode, PlanNode, ProjectNode, ScalarSourceNode,
                     ScanNode, SortNode, UnionNode, ValuesNode, WindowNode)
 
-define("dense_group_domain_max", 1 << 23,
-       "dense group-by: max product of key domains for segment-sum "
-       "aggregation (accumulators are domain-sized: 8 bytes/slot/agg); "
-       "larger domains use the sorted strategy")
+# dense group-by: max product of key domains for segment-sum aggregation
+# (accumulators are domain-sized: 8 bytes/slot/agg); larger domains use the
+# sorted strategy
+DENSE_GROUP_DOMAIN_MAX = 1 << 23
 
 
 class PlanError(SqlError):
@@ -2049,7 +2049,7 @@ class Planner:
                 domains.append(st["dict_size"])
             elif f.ltype.is_integer and st is not None and st.get("min") is not None:
                 span = int(st["max"]) - int(st["min"]) + 1
-                if span <= 0 or span > int(FLAGS.dense_group_domain_max):
+                if span <= 0 or span > DENSE_GROUP_DOMAIN_MAX:
                     return self._sorted_strategy(plan, key_names)
                 domains.append(span)
                 if int(st["min"]) != 0:
@@ -2057,7 +2057,7 @@ class Planner:
             else:
                 return self._sorted_strategy(plan, key_names)
             total *= domains[-1] + 1
-            if total > int(FLAGS.dense_group_domain_max):
+            if total > DENSE_GROUP_DOMAIN_MAX:
                 return self._sorted_strategy(plan, key_names)
         return "dense", domains, 0, key_shift
 

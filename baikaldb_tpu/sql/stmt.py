@@ -178,7 +178,7 @@ class FetchStmt:
     """FETCH [n] FROM subscription — deliver the next batch of change
     events and durably advance the cursor past them."""
     name: str
-    limit: int = 0               # 0 = cdc_fetch_batch flag default
+    limit: int = 0               # 0 = cdc.streams.FETCH_BATCH
 
 
 @dataclass

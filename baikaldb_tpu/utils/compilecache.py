@@ -69,9 +69,9 @@ define("device_accounting", True,
        "information_schema.executables / EXPLAIN ANALYZE's '-- device:' "
        "line add lazy XLA cost/memory analysis (FLOPs, bytes accessed, "
        "peak HBM).  0 disables recording entirely")
-define("device_accounting_max", 256,
-       "executable-accounting LRU entries (distinct (kind, statement, "
-       "plan signature, shape) tuples)")
+# executable-accounting LRU entries (distinct (kind, statement, plan
+# signature, shape) tuples)
+DEVICE_ACCOUNTING_MAX = 256
 
 
 class _ExecRecord:
@@ -152,8 +152,7 @@ class ExecutableAccounting:
             if rec is None:
                 rec = self._entries[key] = _ExecRecord(
                     kind, statement, plan_sig, shape)
-                cap = max(1, int(FLAGS.device_accounting_max))
-                while len(self._entries) > cap:
+                while len(self._entries) > DEVICE_ACCOUNTING_MAX:
                     self._entries.popitem(last=False)
             else:
                 self._entries.move_to_end(key)
@@ -314,9 +313,6 @@ define("aot_cache", True,
        "compile-from-scratch cold starts")
 define("aot_cache_dir", "",
        "AOT artifact directory (empty = <repo>/.aot_cache)")
-define("aot_cache_peer_fetch", True,
-       "on a local disk miss, resolve the artifact through the meta "
-       "manifest and fetch it from the holding store daemon")
 define("aot_cache_disk_max", 256,
        "local disk tier bound (artifacts); least-recently-touched evict")
 
@@ -542,7 +538,9 @@ class AotExecutableCache:
         disk = self.disk()
         data = disk.get(key)
         source = "disk"
-        if data is None and bool(FLAGS.aot_cache_peer_fetch):
+        if data is None:
+            # local miss: resolve the artifact through the meta manifest
+            # and fetch it from the holding store daemon
             with self._mu:
                 rep = self._replicator
             if rep is not None:

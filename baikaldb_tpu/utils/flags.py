@@ -16,7 +16,8 @@ Here a single process-wide registry serves the same three channels:
 - **dynamic runtime updates**: ``set_flag(name, value)`` coerces to the
   defined type and fires registered listeners — the meta service piggybacks
   ``{name: value}`` override maps on heartbeat responses and stores apply
-  them through this call (tests/test_flags.py drives the loop end-to-end).
+  them through this call (tests/test_flags_metrics.py drives the loop
+  end-to-end).
 
 Values are typed by their default (bool/int/float/str); ``SHOW VARIABLES``
 and information_schema surface the live table.
@@ -185,11 +186,6 @@ set_flag = FLAGS.set_flag
 # -- core engine flags (module-level so they exist before first use) -------
 define("slow_query_ms", 1000.0,
        "queries slower than this land in the slow-query log counter")
-define("query_log_size", 512, "query statistics ring length")
-define("onehot_max_segments", 512,
-       "dense group-by: max segments for the TPU select+reduce lowering")
-define("pallas_group_kernels", True,
-       "use Pallas MXU kernels for mid-cardinality dense group-by on TPU")
 define("join_retry_max", 10, "static-capacity join: recompile-and-double cap")
 define("plan_cache_size", 256,
        "compiled-plan LRU entries per session (reference: plan cache, "
@@ -203,5 +199,3 @@ define("batch_bucketing", True,
        "exact-shape batches")
 define("batch_bucket_min", 1024,
        "smallest capacity bucket for padded device table batches")
-define("ttl_interval_s", 60.0, "background TTL sweep period (store daemons)")
-define("heartbeat_interval_s", 3.0, "store->meta heartbeat period")

@@ -618,7 +618,7 @@ learner_fallback_reads = Counter("learner_fallback_reads")
 # elastic regions (meta tick -> fleet): completed / aborted live splits and
 # learner-first migrations, plus the fenced-handoff window each one paid
 # (the only interval where the tier lock blocks writers).  Surfaced by
-# SHOW STATUS as region.* and gated by tools/bench_regress.py
+# SHOW STATUS as region.*
 region_splits = Counter("region.splits")
 region_split_aborts = Counter("region.split_aborts")
 region_merges = Counter("region.merges")

@@ -6,7 +6,11 @@ Hard-override JAX_PLATFORMS: unit tests never take the chip, so a test run
 beside a serving process cannot steal its device.
 """
 
+import importlib
 import os
+import pkgutil
+
+import pytest
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -27,3 +31,15 @@ import baikaldb_tpu  # noqa: E402,F401
 from baikaldb_tpu.utils.flags import set_flag  # noqa: E402
 
 set_flag("aot_cache", False)
+
+
+@pytest.fixture(scope="session")
+def all_flags():
+    """The flag registry after every module of the package has been
+    imported: flags are defined at their point of use."""
+    from baikaldb_tpu.utils.flags import FLAGS
+
+    for m in pkgutil.walk_packages(baikaldb_tpu.__path__, "baikaldb_tpu."):
+        if not m.name.endswith("__main__"):
+            importlib.import_module(m.name)
+    return FLAGS

@@ -67,26 +67,6 @@ def test_compile_cache_default_is_the_checkout():
     assert _cache_dir_of_child(env) == str(REPO / ".jax_cache")
 
 
-def test_bench_main_fails_when_a_phase_raises(monkeypatch, capsys):
-    import bench
-
-    def boom():
-        raise RuntimeError("phase blew up")
-
-    monkeypatch.setattr(bench, "_PHASES", [
-        ("A", lambda: {"metric": "a", "platform": "cpu"}),
-        ("B", boom),
-        ("C", lambda: {"metric": "c", "platform": "cpu"})])
-    assert bench.main() == 1
-    out, err = capsys.readouterr()
-    lines = [json.loads(ln) for ln in out.splitlines()]
-    assert [ln["metric"] for ln in lines] == ["a", "c"]    # no line for B
-    assert "phase blew up" in err and "FAILED phases: B" in err
-
-    monkeypatch.setattr(bench, "_PHASES", bench._PHASES[::2])
-    assert bench.main() == 0
-
-
 def test_double_key_hash_lowers_without_64bit_bitcast():
     """The TPU's x64 rewriter aborts on any 64-bit bitcast_convert (which
     jnp.frexp / jnp.signbit of a DOUBLE lower to): the key hash of a DOUBLE

@@ -234,3 +234,22 @@ def test_pallas_dense_groupby_integration(monkeypatch):
                                  [AggSpec("sum", "i", "s")])
     assert used["pallas"] is False
     assert np.asarray(out2.column("s").data)[0] == g[g == 0].astype(np.int64).sum()
+
+
+def test_every_defined_flag_is_read(all_flags):
+    """A flag nothing reads is a row of SHOW VARIABLES that lies: every
+    registered name occurs as ``FLAGS.<name>`` or as a quoted name
+    somewhere under baikaldb_tpu/ outside its own ``define(``."""
+    import re
+    from pathlib import Path
+
+    import baikaldb_tpu
+
+    pkg = Path(baikaldb_tpu.__file__).parent
+    src = "\n".join(p.read_text() for p in sorted(pkg.rglob("*.py")))
+    src = re.sub(r'\bdefine\(\s*"[a-z_0-9]+"', "define(", src)
+    names = sorted(all_flags.snapshot())
+    assert len(names) >= 60             # the walk registered the package's
+    unread = [n for n in names
+              if not re.search(rf'FLAGS\.{n}\b|["\']{n}["\']', src)]
+    assert unread == []

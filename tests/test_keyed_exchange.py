@@ -1,10 +1,10 @@
 """Keyed exchange scheduler satellites: equality classes, constant
 propagation into BOTH join sides' scans (pinned pruned-region counts),
-the bench_regress diff tool, and the pinned TPC-H q5/q7/q8/q9 exchange
-manifest (the fast tier-1 rounds check — plan-level only, no wall-clock).
+and the pinned TPC-H q5/q7/q8/q9 exchange manifest (the fast tier-1 rounds check — plan-level only, no wall-clock).
 """
 
 import json
+import os
 
 import pytest
 
@@ -135,73 +135,6 @@ def test_eqclass_const_pushdown_param_path(zoned):
     assert metrics.regions_pruned.value - r0 == 8
 
 
-# -- bench_regress ----------------------------------------------------------
-
-def _capture(tmp_path, name, rows, header=None):
-    p = tmp_path / name
-    lines = []
-    if header is not None:
-        lines.append(json.dumps({"header": header}))
-    for r in rows:
-        lines.append(json.dumps(r))
-    lines.append("not json noise")
-    p.write_text("\n".join(lines))
-    return str(p)
-
-
-def test_bench_regress_clean_and_regressions(tmp_path):
-    from tools.bench_regress import main
-
-    hdr = {"scale": 0.05, "mesh": 8, "force_shuffle": True,
-           "multiway": True}
-    base = _capture(tmp_path, "base.json", [
-        {"query": "q5", "warm_ms": 100.0, "shuffle_rounds": 4,
-         "rounds_saved": 1, "warm_compiles": 0},
-        {"query": "q9", "warm_ms": 50.0, "shuffle_rounds": 4,
-         "rounds_saved": 0, "warm_compiles": 0},
-    ], hdr)
-    same = _capture(tmp_path, "same.json", [
-        {"query": "q5", "warm_ms": 140.0, "shuffle_rounds": 4,
-         "rounds_saved": 1, "warm_compiles": 0},
-        {"query": "q9", "warm_ms": 48.0, "shuffle_rounds": 3,
-         "rounds_saved": 0, "warm_compiles": 0},
-    ], hdr)
-    # wall-clock noise and IMPROVED rounds are not regressions
-    assert main([base, same]) == 0
-    bad = _capture(tmp_path, "bad.json", [
-        {"query": "q5", "warm_ms": 90.0, "shuffle_rounds": 5,
-         "rounds_saved": 0, "warm_compiles": 2},
-        # q9 missing entirely
-    ], hdr)
-    assert main([base, bad]) == 1
-
-
-def test_bench_regress_config_mismatch(tmp_path):
-    from tools.bench_regress import compare, load_capture
-
-    a = load_capture(_capture(tmp_path, "a.json",
-                              [{"query": "q5", "shuffle_rounds": 1}],
-                              {"scale": 0.05, "mesh": 8}))
-    b = load_capture(_capture(tmp_path, "b.json",
-                              [{"query": "q5", "shuffle_rounds": 1}],
-                              {"scale": 0.05, "mesh": 1}))
-    problems = compare(a, b)
-    assert any("mesh" in p for p in problems)
-
-
-def test_bench_regress_wall_clock_opt_in(tmp_path):
-    from tools.bench_regress import main
-
-    base = _capture(tmp_path, "b.json",
-                    [{"query": "q1", "warm_ms": 100.0,
-                      "shuffle_rounds": 0, "warm_compiles": 0}])
-    cand = _capture(tmp_path, "c.json",
-                    [{"query": "q1", "warm_ms": 180.0,
-                      "shuffle_rounds": 0, "warm_compiles": 0}])
-    assert main([base, cand]) == 0                       # timing ignored
-    assert main([base, cand, "--wall-clock-pct", "50"]) == 1
-
-
 # -- pinned TPC-H exchange manifest (fast tier-1 rounds check) --------------
 
 def _plan_metrics(s, sql):
@@ -232,7 +165,7 @@ def test_tpch_rounds_manifest(monkeypatch):
     natural regime (small dims broadcast and fuse as riders) and the
     pure-MPP force-shuffle regime.  A planner/scheduler change that
     shifts ANY of these numbers fails loudly; update the manifest only
-    with the corresponding BENCH_NOTES entry.  Rounds only — wall-clock
+    with a line in `CHANGES.md`.  Rounds only — wall-clock
     never gates tier-1."""
     import jax
 
@@ -240,7 +173,8 @@ def test_tpch_rounds_manifest(monkeypatch):
     from baikaldb_tpu.parallel.mesh import make_mesh
 
     assert len(jax.devices()) >= 8
-    with open("tools/tpch_rounds_manifest.json") as f:
+    with open(os.path.join(os.path.dirname(__file__),
+                           "tpch_rounds_manifest.json")) as f:
         manifest = json.load(f)
     cfg = manifest["config"]
     monkeypatch.setattr(dist_mod, "BROADCAST_ROWS", cfg["broadcast_rows"])

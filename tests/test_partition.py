@@ -59,7 +59,10 @@ def test_range_partition_prunes_and_matches_unpartitioned():
     assert "partition(" in plan and "partitions pruned" in plan
     got = s.query(q.format(t="lineitem"))
     want = s.query(q.format(t="flat"))
-    assert got == want and got[0]["n"] > 0
+    # two region layouts reduce in two orders: the count is exact, the
+    # float sum agrees to rounding
+    assert got[0]["n"] == want[0]["n"] and got[0]["n"] > 0
+    assert got[0]["rev"] == pytest.approx(want[0]["rev"], rel=1e-12, abs=0)
 
 
 def test_rows_land_in_per_partition_regions():
