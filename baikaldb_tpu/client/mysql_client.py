@@ -260,6 +260,7 @@ class Connection:
         try:
             self.p.reset()
             self.p.write(b"\x01")
+            self.p.flush()
         except OSError:
             pass
         try:

@@ -33,6 +33,7 @@ from typing import Optional
 from ..exec.session import Database, Result, Session, next_conn_id
 from ..sql.lexer import SqlError
 from ..types import LType
+from ..utils import metrics
 from .errors import errno_for
 
 CLIENT_PROTOCOL_41 = 0x00000200
@@ -70,14 +71,33 @@ def lenenc_str(s: bytes) -> bytes:
     return lenenc_int(len(s)) + s
 
 
+# the output buffer goes to the socket once it holds this much: a large
+# result costs bounded memory beyond the res.rows it already holds, and
+# still ~100 times fewer sends than a send a row
+FLUSH_BYTES = 1 << 20
+RECV_BYTES = 1 << 16
+
+
 class Packets:
-    """Packet framing: 3-byte length + 1-byte sequence id."""
+    """Packet framing: 3-byte length + 1-byte sequence id, into and out of
+    buffers.  ``write`` frames into an output buffer; ``flush`` puts it on
+    the socket with one ``sendall``: before every ``read`` (a peer never
+    waits for input while it holds output), before a close (the callers'),
+    and whenever the buffer passes FLUSH_BYTES.  ``read`` serves from a
+    receive buffer that one ``recv`` refills, so a packet's header and
+    body, and every packet of a response that came in one segment, cost
+    no further call; bytes past a packet stay for the next ``read``.
+    Every socket call drops the interpreter lock: a response is one."""
 
     def __init__(self, sock: socket.socket):
         self.sock = sock
         self.seq = 0
+        self._out = bytearray()       # framed packets not yet on the socket
+        self._out_packets = 0
+        self._in = bytearray()        # received bytes not yet read
 
     def read(self) -> Optional[bytes]:
+        self.flush()
         hdr = self._recvn(4)
         if hdr is None:
             return None
@@ -86,23 +106,42 @@ class Packets:
         return self._recvn(ln)
 
     def _recvn(self, n: int) -> Optional[bytes]:
-        buf = b""
+        buf = self._in
         while len(buf) < n:
-            chunk = self.sock.recv(n - len(buf))
+            chunk = self.sock.recv(max(RECV_BYTES, n - len(buf)))
+            metrics.wire_recvs.add(1)
             if not chunk:
                 return None
             buf += chunk
-        return buf
+        out = bytes(buf[:n])
+        del buf[:n]
+        return out
 
     def write(self, payload: bytes):
         while True:
             part = payload[:0xFFFFFF]
             payload = payload[0xFFFFFF:]
-            hdr = struct.pack("<I", len(part))[:3] + bytes([self.seq])
+            self._out += struct.pack("<I", len(part))[:3]
+            self._out.append(self.seq)
+            self._out += part
+            self._out_packets += 1
             self.seq = (self.seq + 1) & 0xFF
-            self.sock.sendall(hdr + part)
+            if len(self._out) >= FLUSH_BYTES:
+                self.flush()
             if len(part) < 0xFFFFFF:
                 break
+
+    def flush(self):
+        """Everything written so far, in one ``sendall``."""
+        if not self._out:
+            return
+        out, packets = self._out, self._out_packets
+        self._out, self._out_packets = bytearray(), 0
+        # counted before the send: the peer that has the bytes may read
+        # the counters
+        metrics.wire_packets.add(packets)
+        metrics.wire_sends.add(1)
+        self.sock.sendall(out)
 
     def reset(self):
         self.seq = 0
@@ -155,7 +194,6 @@ class MySQLServer:
             # disable Nagle: request/response protocol, every packet small —
             # without this each query stalls ~40ms on delayed ACKs
             conn.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
-            from ..utils import metrics
             metrics.connections_total.add(1)
             t = threading.Thread(target=self._serve, args=(conn,), daemon=True)
             t.start()
@@ -239,6 +277,10 @@ class MySQLServer:
         finally:
             self.db.processlist.pop(conn_id, None)
             try:
+                p.flush()             # an ERR that precedes the close
+            except OSError:
+                pass
+            try:
                 conn.close()
             except OSError:
                 pass
@@ -312,7 +354,6 @@ class MySQLServer:
 
     def _query(self, p: Packets, session: Session, sql: str):
         from ..obs import trace
-        from ..utils import metrics
 
         # wire-level trace root: session.execute's root degrades to a child
         # span under it, so a kept trace shows protocol encode time too —
@@ -368,6 +409,9 @@ class MySQLServer:
                         out += lenenc_str(_text_value(v))
                 p.write(out)
         self._eof(p)
+        # inside the caller's wire.result_set span: it times encode + the
+        # one send; the flush in the next read then finds nothing to do
+        p.flush()
 
     # -- prepared statements (COM_STMT_*) ---------------------------------
     def _stmt_prepare_ok(self, p: Packets, sid: int, nparams: int):

@@ -555,6 +555,12 @@ point_lookups = Counter("point_lookups")
 # row is written) and wire.result_set (encode + socket write, after the row)
 point_lookup_ms = Counter("point_lookup_ms")
 wire_result_set_ms = Counter("wire_result_set_ms")
+# the MySQL framing (server/mysql_server.Packets, both ends of the wire in
+# a process that holds server and clients): packets framed, sendall calls
+# that carried them, recv calls.  packets / sends is the coalescing ratio
+wire_packets = Counter("wire_packets")
+wire_sends = Counter("wire_sends")
+wire_recvs = Counter("wire_recvs")
 index_scans = Counter("index_scans")
 # statements whose scan input was the pk_range arm's fixed-capacity gather
 # out of the resident image (exec/session._access_path_batch), and the rows
