@@ -354,6 +354,7 @@ class BatchDispatcher:
         metrics.batched_groups.add(1)
         metrics.group_occupancy.observe(float(G))
         from ..utils import compilecache
+        from . import caps
         from .executor import AotRawShim, flag_meta_of
 
         # AOT artifact identity for this batched program: the statement
@@ -378,25 +379,6 @@ class BatchDispatcher:
         # deserialized program cannot — in-bucket DML must re-derive the
         # artifact key instead of reusing a stale-dictionary executable
         vk = tuple(sorted(entry.get("versions", {}).items()))
-
-        def _fresh_batched():
-            # a publish-only clone of the combine program: the background
-            # export re-traces it, so it must own its OWN run_local
-            # closure and meta list — tracing the live pair's would mutate
-            # state a concurrent tick is reading
-            raw2 = compile_plan(plan)
-            meta2: list = []
-
-            def batched2(tb, sp_, _raw=raw2, _meta=meta2, _cap=SCATTER_ROWS):
-                def one(p):
-                    b = dict(tb)
-                    b[PARAMS_KEY] = p
-                    out, flags = _raw(b)
-                    _meta.clear()
-                    _meta.append(egress_mod.column_meta(out))
-                    return egress_mod.gather_live(out, _cap), flags
-                return jax.vmap(one)(sp_)
-            return batched2
 
         t0 = time.perf_counter()
         with trace.span("batch.combine", group=G, padded=gpad) as sp:
@@ -444,8 +426,13 @@ class BatchDispatcher:
                             return egress_mod.gather_live(out, _cap), flags
                         return jax.vmap(one)(sp_)
 
-                    pair = (jax.jit(batched), raw,  # tpulint: disable=RETRACE
-                            meta, None)
+                    # traced through jax.export where the AOT tier is on:
+                    # what this tick compiles is what the publisher
+                    # serialises (compilecache.ExportedProgram)
+                    pair = (compilecache.ExportedProgram(batched)
+                            if compilecache.AOT.enabled()
+                            else jax.jit(batched),  # tpulint: disable=RETRACE
+                            raw, meta, None)
                     with self._mu:
                         self._compiled[ck] = pair
                         while len(self._compiled) > CACHE_ENTRIES:
@@ -468,9 +455,9 @@ class BatchDispatcher:
                             str(entry.get("text") or "<unnamed>"),
                             entry.get("plan_sig"), f"group={gpad}", cms,
                             fn, (table_batches, stacked))
-                grew = False
                 # ONE fused transfer for every lane of every overflow flag
                 host_flags = jax.device_get(flags)
+                needs = []
                 for node, flag in zip(raw.join_order, host_flags):
                     fl = np.asarray(flag)
                     if isinstance(node, ScalarSourceNode) \
@@ -478,20 +465,20 @@ class BatchDispatcher:
                         for i in np.nonzero(fl[:G] > 1)[0]:
                             ws[int(i)].err = PlanError(
                                 "Subquery returns more than 1 row")
-                        continue
-                    needed = int(fl.max())
-                    if needed > (node.cap or 0):
-                        node.cap = max(16, 1 << (needed - 1).bit_length())
-                        grew = True
+                        needs.append(None)
+                    else:
+                        needs.append(int(fl.max()))
+                grew = caps.settle(
+                    None if isinstance(raw, AotRawShim) else plan,
+                    raw.join_order, needs).grew
                 if not grew:
-                    if compiled_now and not isinstance(raw, AotRawShim) \
+                    if compiled_now \
+                            and getattr(fn, "exported", None) is not None \
                             and get_aot_key() is not None:
                         compilecache.AOT.publish_async(
                             aot_key, "batched",
                             str(entry.get("text") or "<unnamed>"),
-                            entry.get("plan_sig"),
-                            lambda a, _b=_fresh_batched(): _b(a[0], a[1]),
-                            (table_batches, stacked),
+                            entry.get("plan_sig"), fn,
                             ((gdatas, gvalids, ns_dev), flags),
                             flag_meta_of(raw.join_order),
                             extra={"egress_meta": meta[0]})

@@ -49,6 +49,7 @@ class ExecError(RuntimeError):
 
 from ..utils import metrics  # noqa: E402
 from ..utils.flags import FLAGS, define  # noqa: E402
+from . import caps  # noqa: E402
 
 # Pushed-down fragments (exec/fragments.py) merge daemon partials HOST-side
 # under parallel.agg.WIRE_MERGE while this executor merges mesh partials
@@ -129,8 +130,8 @@ class AotRawShim:
 
 
 class _CapBox:
-    """A retryable capacity knob that rides the join-overflow protocol:
-    the session retry loop grows ``.cap`` to the reported need and
+    """A retryable capacity knob that rides the join-overflow protocol
+    (exec/caps.py): a retry loop grows ``.cap`` to the reported need and
     re-traces (used for the radix join's per-bucket width and the fused
     exchange's per-input shuffle capacities).  ``kind``/``site`` label the
     knob for shuffle-retry accounting and the mpp.* trace spans."""
@@ -271,10 +272,10 @@ def _eval(node: PlanNode, batches: dict, overflows: list, ctx=None) -> ColumnBat
     if isinstance(node, ShrinkNode):
         child = _sub(node.child(), batches, overflows, ctx)
         if node.cap is None:
-            # first trace: guess a 16x cut; the flag reports the true live
-            # count, so one retry lands exactly when the guess is short
-            node.cap = max(1024, 1 << (max(1, len(child) // 16)
-                                       - 1).bit_length())
+            node.cap = caps.first_cap(node, len(child),
+                                      caps.shrink_guess(node, len(child)))
+        if node.cap >= len(child):
+            return child    # no cut possible, so nothing can overflow
         out, needed = shrink(child, node.cap)
         overflows.append((node, needed))
         return out
@@ -293,7 +294,8 @@ def _eval(node: PlanNode, batches: dict, overflows: list, ctx=None) -> ColumnBat
         right = _sub(node.children[1], batches, overflows, ctx)
         if node.how == "cross":
             if node.cap is None:
-                node.cap = max(1, len(left) * len(right))
+                node.cap = caps.first_cap(node, len(left) * len(right),
+                                          max(1, len(left) * len(right)))
             out, ovf = join_ops.cross_join(left, right, cap=node.cap)
         elif node.neq is not None and node.how in ("semi", "anti"):
             # EXISTS + one <> residual: range counts, no expansion; with a
@@ -316,7 +318,8 @@ def _eval(node: PlanNode, batches: dict, overflows: list, ctx=None) -> ColumnBat
             if node.cap is None:
                 # key-FK joins emit at most max(sides) rows; true many-to-many
                 # expansion beyond that reports its exact need via the flag
-                node.cap = max(1, len(left), len(right))
+                sides = max(1, len(left), len(right))
+                node.cap = caps.first_cap(node, sides, sides)
             nb = int(FLAGS.radix_join_buckets)
             presort = _presort_order(node, batches, len(right))
             float_keys = any(right.column(k).ltype.is_float
@@ -333,8 +336,9 @@ def _eval(node: PlanNode, batches: dict, overflows: list, ctx=None) -> ColumnBat
                 if box.cap is None:
                     # 4x average occupancy as the first guess; skew reports
                     # the exact need through the flag channel
-                    box.cap = max(64, 1 << (4 * len(right) // nb - 1)
-                                  .bit_length())
+                    box.cap = caps.first_cap(
+                        box, len(right),
+                        max(64, 1 << (4 * len(right) // nb - 1).bit_length()))
                 out, ovf, wneed = join_ops.radix_join(
                     left, node.left_keys, right, node.right_keys,
                     how=node.how, cap=node.cap,
@@ -380,14 +384,16 @@ def _eval(node: PlanNode, batches: dict, overflows: list, ctx=None) -> ColumnBat
                     shuffled.append(b)
                     continue
                 if box.cap is None:
-                    box.cap = max(1, 2 * len(b) // n)
+                    box.cap = caps.first_cap(box, len(b),
+                                             max(1, 2 * len(b) // n))
                 out_b, needed = _repartition_exec(b, list(keys), n, box.cap,
                                                   ctx)
                 overflows.append((box, needed))
                 shuffled.append(out_b)
             probe, builds = shuffled[0], shuffled[1:]
         if node.cap is None:
-            node.cap = max(1, len(probe), *(len(b) for b in builds))
+            sides = max(1, len(probe), *(len(b) for b in builds))
+            node.cap = caps.first_cap(node, sides, sides)
         out, ovf = join_ops.multiway_join(
             probe, node.probe_keys, list(zip(builds, node.build_keys)),
             list(node.hows), cap=node.cap, level_keys=node.level_keys,
@@ -407,7 +413,8 @@ def _eval(node: PlanNode, batches: dict, overflows: list, ctx=None) -> ColumnBat
         n = ctx[3]
         keys = node.keys if node.keys is not None else list(child.names)
         if node.cap is None:
-            node.cap = max(1, 2 * len(child) // max(1, n))
+            node.cap = caps.first_cap(node, len(child),
+                                      max(1, 2 * len(child) // max(1, n)))
         out, ovf = _repartition_exec(child, keys, n, node.cap, ctx)
         overflows.append((node, ovf))
         return out
@@ -462,7 +469,8 @@ def _eval(node: PlanNode, batches: dict, overflows: list, ctx=None) -> ColumnBat
             if box is None:
                 box = node.agg_exch_cap = _CapBox(kind="shuffle", site="agg")
             if box.cap is None:
-                box.cap = max(1, 2 * len(part) // n)
+                box.cap = caps.first_cap(box, len(part),
+                                         max(1, 2 * len(part) // n))
             shuf, needed = _repartition_exec(part, node.key_names, n,
                                              box.cap, ctx)
             overflows.append((box, needed))

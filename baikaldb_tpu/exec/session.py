@@ -73,7 +73,7 @@ define("param_queries", True,
        "entry and one compiled executable serve every literal variant of a "
        "query shape; 0 restores SQL-text-keyed caching with baked literals")
 from .dispatch import BatchDispatcher
-from . import executor, streaming
+from . import caps, executor, streaming
 from . import fragments as _fragments  # noqa: F401 — registers the
 # fragment_pushdown / fragment_retry_max flags at session load (SET and
 # the CLI must see them before the first pushed dispatch)
@@ -2205,12 +2205,17 @@ class Session:
     def _plan_select_inner(self, stmt: SelectStmt) -> PlanNode:
         plan = self._planner().plan_select(stmt)
         self._annotate_ann(stmt, plan)
-        if self.mesh is not None:
-            from ..plan.distribute import distribute
 
-            def rows_fn(table_key: str) -> int:
-                st = self.db.stores.get(table_key)
-                return st.num_rows if st is not None else 0
+        def rows_fn(table_key: str) -> int:
+            st = self.db.stores.get(table_key)
+            return st.num_rows if st is not None else 0
+
+        if self.mesh is None:
+            # a shrink's first capacity from the row counts (a mesh traces
+            # per-shard sizes and keeps its cut)
+            caps.annotate_rows(plan, rows_fn)
+        else:
+            from ..plan.distribute import distribute
 
             def ndv_fn(table_key: str, col: str):
                 # index/stats distinct-count estimate feeding the
@@ -5705,7 +5710,13 @@ class Session:
                 # partition scatter is expensive to compile, and the eager
                 # op cache pays that once per capacity shape process-wide
                 # instead of once per cached executable
-                pair = (jax.jit(raw), raw)  # tpulint: disable=RETRACE
+                # traced through jax.export when the AOT tier is on: what
+                # this thread compiles is then what the publisher
+                # serialises and a loader compiles (compilecache.
+                # ExportedProgram) — one compile a settled executable
+                pair = (compilecache.ExportedProgram(raw)
+                        if compilecache.AOT.enabled()
+                        else jax.jit(raw), raw)  # tpulint: disable=RETRACE
                 comp = entry["compiled"]
                 # distinct shapes (bucket crossings, access-path batches)
                 # each pin an executable; without a cap one hot query would
@@ -5749,7 +5760,6 @@ class Session:
                                 ";".join(f"{p[0]}={p[1]}"
                                          for p in shape_key[0]),
                                 cms, fn, (batches,))
-            grew = False
             # ONE explicit transfer for every overflow flag: int(flag) per
             # join would block on a device round-trip once per node
             # (tpulint HOSTSYNC)
@@ -5764,55 +5774,59 @@ class Session:
                 # all rounds are behind us once the flags landed on host
                 qp.beat(round_no=int(qp.rounds_total)
                         if qp.query_id else 0)
+            needs = []
             for node, flag in zip(raw.join_order, host_flags):
                 needed = int(flag)
                 if isinstance(node, ScalarSourceNode) \
                         or getattr(node, "aot_scalar", False):
                     if needed > 1:
                         raise PlanError("Subquery returns more than 1 row")
-                    continue
-                if needed > (node.cap or 0):
-                    # flags carry the exact required capacity (join output
-                    # cardinality / max shuffle-bucket size): jump straight
-                    # there (padded to a power of two so repeated runs with
-                    # slightly different data reuse the compiled executable)
-                    node.cap = max(16, 1 << (needed - 1).bit_length())
-                    grew = True
-                    if mesh is not None and (
-                            isinstance(node, ExchangeNode)
-                            or (isinstance(node, _CapBox)
-                                and node.kind == "shuffle")):
-                        # a skewed key blew past the per-destination
-                        # shuffle capacity — the exchange backpressure
-                        # analog, worth its own counter
-                        metrics.shuffle_overflow_retries.add(1)
+                    needed = None
+                elif needed > (node.cap or 0) and mesh is not None and (
+                        isinstance(node, ExchangeNode)
+                        or (isinstance(node, _CapBox)
+                            and node.kind == "shuffle")):
+                    # a skewed key blew past the per-destination shuffle
+                    # capacity — the exchange backpressure analog, worth
+                    # its own counter
+                    metrics.shuffle_overflow_retries.add(1)
+                needs.append(needed)
+            # the capacities against the needs (exec/caps.py): what
+            # overflowed grows to its need and what is downstream of it to
+            # its input's bound, so one recompile settles the plan.  Host
+            # work on values already fetched
+            grew = False
+            if needs:       # a plan without flags has nothing to settle
+                with trace.span("exec.cap_settle"):
+                    grew, slots, live = caps.settle(
+                        None if isinstance(raw, executor.AotRawShim)
+                        else plan, raw.join_order, needs)
+                metrics.join_cap_slots.add(slots)
+                metrics.join_live_rows.add(live)
             if grew:
                 metrics.join_cap_retries.add(1)
-            if grew and isinstance(raw, executor.AotRawShim):
-                # live data outgrew the artifact's baked capacities: an
-                # exported program cannot re-trace, so this shape compiles
-                # from scratch (and never re-loads the undersized artifact
-                # in this entry's lifetime)
+                # the artifact under this key has the outgrown capacities
+                # baked in (the key does not hold them): this shape
+                # compiles from scratch, never re-loads it in this entry's
+                # lifetime, and publishes over it once settled
                 entry.setdefault("aot_bad", set()).add(shape_key)
+            if grew and isinstance(raw, executor.AotRawShim):
+                # live data outgrew a loaded artifact: an exported program
+                # cannot re-trace
                 metrics.aot_cache_fallbacks.add(1)
                 entry["compiled"].pop(shape_key, None)
                 continue
             if not grew:
-                if compiled_here and not isinstance(raw, executor.AotRawShim) \
+                if compiled_here and getattr(fn, "exported", None) is not None \
                         and get_aot_key() is not None:
-                    # settled executable: hand it to the background
-                    # publisher (export + verify + disk + peer); the query
-                    # path never waits on it.  The publisher re-traces on
-                    # its own thread, so it gets a FRESH compile_plan
-                    # closure — tracing the live `raw` would mutate the
-                    # join_order/trace_order lists a concurrent execution
-                    # of this entry is reading
+                    # settled executable: hand the module this thread
+                    # traced and compiled to the background publisher
+                    # (serialise + verify + disk + peer); the query path
+                    # never waits on it
                     compilecache.AOT.publish_async(
                         aot_key, "plan",
                         str(entry.get("text") or "<unnamed>"),
-                        entry.get("plan_sig"),
-                        compile_plan(plan, mesh=mesh), batches,
-                        (out, flags),
+                        entry.get("plan_sig"), fn, (out, flags),
                         executor.flag_meta_of(raw.join_order),
                         extra=None if mesh is None else
                         {"exchange_bytes": raw.exchange_bytes[0]}, mesh=mesh)

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from ..column.batch import Column, ColumnBatch
 from .segments import seg_max, seg_min, seg_sum
+from .sort import argsort
 from ..types import LType
 
 
@@ -178,8 +179,8 @@ def _segment_percentile(c: Column, gid_v, ng: int, p: float):
     reference approximates with t-digest (src/common/tdigest.cpp) because
     CPU sorts are expensive; on TPU the sort IS the cheap primitive."""
     x = c.data.astype(jnp.float64)
-    order = jnp.argsort(x, stable=True)
-    order = order[jnp.argsort(gid_v[order], stable=True)]
+    order = argsort(x)
+    order = order[argsort(gid_v[order])]
     g = gid_v[order]
     v = x[order]
     counts = seg_sum(jnp.ones_like(gid_v, jnp.int32), gid_v,
@@ -405,8 +406,8 @@ def _segment_one(batch: ColumnBatch, s: AggSpec, gid, ng: int, sel) -> Column:
 
 def _segment_distinct(c: Column, gid, ng: int, s: AggSpec) -> Column:
     """Per-group DISTINCT via (gid, value) sort + boundary dedup."""
-    order = jnp.argsort(c.data, stable=True)
-    order = order[jnp.argsort(gid[order], stable=True)]
+    order = argsort(c.data)
+    order = order[argsort(gid[order])]
     g = gid[order]
     v = c.data[order]
     idx = jnp.arange(g.shape[0])
@@ -466,12 +467,12 @@ def group_aggregate_sorted(batch: ColumnBatch, key_names: list[str],
                          n_live + jnp.cumsum(~live_o) - 1)
         perm = jnp.zeros(n, order.dtype).at[dest].set(order)
     else:
-        perm = jnp.arange(n)
+        perm = jnp.arange(n, dtype=jnp.int32)
         for c, d in zip(reversed(key_cols), reversed(key_data)):
-            perm = perm[jnp.argsort(d[perm], stable=True)]
+            perm = perm[argsort(d[perm])]
             if c.validity is not None:
-                perm = perm[jnp.argsort(c.validity[perm], stable=True)]  # NULLs first
-        perm = perm[jnp.argsort(~sel[perm], stable=True)]  # dead rows last
+                perm = perm[argsort(c.validity[perm])]  # NULLs first
+        perm = perm[argsort(~sel[perm])]  # dead rows last
 
     sel_s = sel[perm]
     idx = jnp.arange(n)

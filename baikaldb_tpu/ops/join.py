@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from ..column.batch import Column, ColumnBatch
 from ..column.dictionary import NULL_CODE, merge as dict_merge
 from ..types import LType
+from .sort import lexsort
 
 
 def _align_string_keys(probe: ColumnBatch, probe_keys: list[str],
@@ -170,7 +171,7 @@ def semi_join_neq(probe: ColumnBatch, probe_keys: list[str],
         key_cnt = live_range(base, base | mask32, "left", "right")
         eq_cnt = live_range(pp, pp, "left", "right")
     else:
-        order2 = jnp.lexsort((pk2, bdead))
+        order2 = lexsort((pk2, bdead))
         n_live = jnp.sum(~bdead).astype(jnp.int32)
         pk2_sorted = jnp.where(jnp.arange(len(build)) < n_live,
                                pk2[order2], _sentinel_max(pk2.dtype))
@@ -244,7 +245,7 @@ def join(probe: ColumnBatch, probe_keys: list[str],
 
         order = stable_partition(~bdead)
     else:
-        order = jnp.lexsort((bk, bdead))
+        order = lexsort((bk, bdead))
     n_live = jnp.sum(~bdead).astype(jnp.int32)
     bk_sorted = jnp.where(jnp.arange(len(build)) < n_live,
                           bk[order], _sentinel_max(bk.dtype))
@@ -440,7 +441,7 @@ def multiway_join(probe: ColumnBatch, probe_keys: list[str],
         pdead = psel_dead if pvalid is None else (psel_dead | ~pvalid)
         bk, bvalid = _key_array(bb, bkeys, wide)
         bdead = _build_dead(bb, bvalid)
-        order = jnp.lexsort((bk, bdead))
+        order = lexsort((bk, bdead))
         n_live = jnp.sum(~bdead).astype(jnp.int32)
         bk_sorted = jnp.where(jnp.arange(len(bb)) < n_live,
                               bk[order], _sentinel_max(bk.dtype))

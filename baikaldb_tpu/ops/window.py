@@ -62,7 +62,8 @@ def window_compute(batch: ColumnBatch, partition_names: list[str],
             d = jnp.where(c.validity, d, jnp.zeros((), d.dtype))
         pkey_data.append((c, d))
 
-    inv = jnp.zeros(n, perm.dtype).at[perm].set(jnp.arange(n))
+    inv = jnp.zeros(n, perm.dtype).at[perm].set(
+        jnp.arange(n, dtype=perm.dtype))
     sel_s = sel[perm]
     idx = jnp.arange(n)
 

@@ -31,6 +31,7 @@ from jax.sharding import PartitionSpec as P
 from ..column.batch import Column, ColumnBatch
 from ..ops import join as join_ops
 from ..ops.hashagg import AggSpec, group_aggregate_sorted
+from ..ops.sort import argsort
 from ..utils.hashing import partition_ids
 from .mesh import AXIS
 
@@ -67,7 +68,7 @@ def _local_repartition(b: ColumnBatch, key_names: list[str], n: int, cap: int):
     dest = partition_ids(partition_key_arrays(b, key_names), n)
     sel = b.sel_mask()
     dest = jnp.where(sel, dest, n)                    # dead rows -> bucket n
-    order = jnp.argsort(dest, stable=True)
+    order = argsort(dest)
     dest_s = dest[order]
     # rank within destination bucket
     idx = jnp.arange(dest_s.shape[0])

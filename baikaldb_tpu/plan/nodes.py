@@ -364,6 +364,8 @@ class StreamResultNode(PlanNode):
 # presort_input is rebound per execution; access_desc is EXPLAIN text.
 _SIG_SKIP = frozenset({"children", "cap", "radix_width", "presort_input",
                        "access_desc", "exch_caps", "agg_exch_cap",
+                       # exec/caps.py: what a cap is first traced with
+                       "cap_full", "live_rows",
                        # derived partition metadata (canonical class tuples
                        # recomputed per plan); the reuse DECISIONS stay in
                        # the signature via reused/reuse fields

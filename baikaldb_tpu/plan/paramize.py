@@ -20,8 +20,12 @@ consumes (expr/params.py).
 Parameterizability analysis — conservative fallback, pinned positions stay
 part of the cache key:
 
-- only the WHERE clause is hoisted, and only inside AND/OR/NOT/XOR,
-  comparison, BETWEEN, and arithmetic structure.  Everything else — IN-list
+- the WHERE clause is hoisted, and only inside AND/OR/NOT/XOR,
+  comparison, BETWEEN, and arithmetic structure; and of a HAVING clause —
+  the statement's own, or that of a subquery under IN / EXISTS in the
+  WHERE tree — a number compared with an aggregate (TPC-H Q18's
+  ``HAVING SUM(l_quantity) > 312``: one executable for every QUANTITY).
+  Everything else — IN-list
   members (host-sorted at trace time), LIKE/MATCH patterns, SUBSTR/CAST
   arguments, GROUP BY / ORDER BY positions, window-frame counts — feeds
   trace-time or plan-shape decisions and stays baked.
@@ -54,6 +58,7 @@ from ..types import LType
 _BOOL_OPS = frozenset({"and", "or", "not", "xor"})
 _CMP_OPS = frozenset({"eq", "ne", "lt", "le", "gt", "ge"})
 _ARITH_OPS = frozenset({"add", "sub", "mul", "div", "int_div", "mod", "neg"})
+_SUBQUERY_OPS = frozenset({"in_subquery", "not_in_subquery", "exists"})
 
 
 class BindError(ValueError):
@@ -167,11 +172,48 @@ def normalize(stmt: SelectStmt,
                         (rw_arith(x), rw_operand(lo, x), rw_operand(hi, x)))
         if e.op in _ARITH_OPS:
             return rw_arith(e)
+        if e.op in _SUBQUERY_OPS and isinstance(e.args[-1], Subquery):
+            sub = e.args[-1].stmt
+            if isinstance(sub, SelectStmt) and sub.having is not None:
+                having = rw_having(sub.having)
+                if having is not sub.having:
+                    return Call(e.op, e.args[:-1] + (
+                        Subquery(_dc_replace(sub, having=having)),))
         return e    # pinned subtree (IN, LIKE, functions, subqueries, ...)
 
+    def rw_having(e: Expr) -> Expr:
+        """HAVING hoists one shape only: an aggregate compared with a
+        number (``HAVING SUM(q) > 312``).  It is a filter over the
+        aggregate's output inside the traced program — never an access
+        path, a join key or a correlation — so the number is a runtime
+        argument like a WHERE literal, here and in the HAVING of a
+        subquery under IN / EXISTS.  -> ``e`` itself where nothing
+        hoisted."""
+        if not isinstance(e, Call):
+            return e
+        if e.op in _BOOL_OPS:
+            args = tuple(rw_having(a) for a in e.args)
+            return e if all(a is b for a, b in zip(args, e.args)) \
+                else Call(e.op, args)
+        if e.op in _CMP_OPS and len(e.args) == 2:
+            a, b = e.args
+            for lit, other in ((a, b), (b, a)):
+                if isinstance(lit, Lit) and _has_agg(other):
+                    p = hoist_num(lit)
+                    if p is not None:
+                        return Call(e.op, (p, b) if lit is a else (a, p))
+        return e
+
     new_where = rw(stmt.where) if stmt.where is not None else None
-    out = _dc_replace(stmt, where=new_where) if slots else stmt
+    new_having = rw_having(stmt.having) if stmt.having is not None else None
+    out = _dc_replace(stmt, where=new_where, having=new_having) \
+        if slots else stmt
     return Normalized(out, stmt_key(out), slots, _count_lits(out))
+
+
+def _has_agg(e: Expr) -> bool:
+    return isinstance(e, AggCall) or any(
+        _has_agg(a) for a in getattr(e, "args", ()))
 
 
 def _iter_exprs(stmt):

@@ -294,10 +294,11 @@ EXECUTABLES = ExecutableAccounting()
 #   since settled caps are baked into the exported program) die at
 #   ``export.deserialize`` — no plan function ever runs;
 # - the backend StableHLO->executable compile dies at the XLA persistent
-#   compilation cache, which the publish worker PRIMES by compiling its own
-#   artifact once (the deserialized module's cache key differs from the
-#   original jit compile's, so without the priming pass the first load
-#   would still pay a backend compile).
+#   compilation cache: the query's thread runs its program as
+#   ``jit(exported.call)`` (``ExportedProgram``), the module a loader
+#   compiles, so the one compile a settled executable gets in its process
+#   is the entry a loader hits (a plain ``jit`` of the plan function has
+#   another cache key: compiling that would leave the loader a compile).
 #
 # Trust boundary: artifacts are advisory.  Corrupt bytes, foreign jax
 # versions, and alien device topologies are detected before anything
@@ -313,6 +314,9 @@ define("aot_cache", True,
        "compile-from-scratch cold starts")
 define("aot_cache_dir", "",
        "AOT artifact directory (empty = <repo>/.aot_cache)")
+# programs the in-process tier keeps (least recently used go first)
+LIVE_PROGRAMS_MAX = 256
+
 define("aot_cache_disk_max", 256,
        "local disk tier bound (artifacts); least-recently-touched evict")
 
@@ -441,10 +445,96 @@ class LoadedArtifact:
                                             list(out_leaves))
 
 
+class ExportedProgram:
+    """A traceable program run the way an artifact runs it: traced once
+    through ``jax.export`` on its first call, and executed as
+    ``jax.jit(exported.call)``.  The module this process compiles is then
+    the module a published artifact carries, so the publisher serialises
+    ``exported`` without tracing or compiling anything again, and a process
+    that loads the artifact compiles the same module (a hit in an XLA
+    persistent cache the two share).  Where the program cannot be exported
+    it runs as a plain ``jax.jit`` and ``exported`` stays ``None``: nothing
+    is published for it."""
+
+    def __init__(self, raw_call):
+        self._raw = raw_call
+        self.call = None            # jitted (*leaves) -> tuple of leaves
+        self._in_tree = None
+        self._out_tree = None
+        self.exported = None
+
+    def _trace(self, leaves, treedef):
+        import jax
+        from jax import export as jax_export
+
+        from . import metrics
+
+        self._in_tree = treedef
+
+        def _flat(*xs):
+            out = self._raw(*jax.tree_util.tree_unflatten(treedef, list(xs)))
+            self._out_tree = jax.tree_util.tree_structure(out)
+            return tuple(jax.tree_util.tree_leaves(out))
+
+        try:
+            # leaves is a host list; per-leaf work reads metadata only
+            structs = [_leaf_struct(x)
+                       for x in leaves]  # tpulint: disable=RETRACE
+            self.exported = jax_export.export(jax.jit(_flat))(*structs)
+            self.call = jax.jit(self.exported.call)
+        except Exception:   # noqa: BLE001 — an op export cannot carry:
+            #   the program still runs, it only is not published
+            metrics.count_swallowed("aot.export")
+            self.exported = None
+            self.call = jax.jit(_flat)
+
+    def _stale(self, treedef) -> bool:
+        return self._in_tree is None or treedef != self._in_tree
+
+    def _leaves(self, args: tuple) -> list:
+        """The input's leaves, the program traced for them.  Like a
+        ``jax.jit`` the program is traced again when the input's static
+        half moves (a dictionary's content, a column's type): an exported
+        module has those baked in and cannot notice by itself."""
+        import jax
+
+        leaves, treedef = jax.tree_util.tree_flatten(args)
+        # a treedef is a host object: nothing is concretized here
+        if self._stale(treedef):  # tpulint: disable=RETRACE
+            self._trace(leaves, treedef)
+        return leaves
+
+    def __call__(self, *args):
+        import jax
+
+        leaves = self._leaves(args)     # first: it may trace
+        out = self.call(*leaves)
+        return jax.tree_util.tree_unflatten(self._out_tree, list(out))
+
+    def lower(self, *args):
+        """The accounting's re-lower (``ExecutableAccounting._analyze``)."""
+        leaves = self._leaves(args)
+        return self.call.lower(*leaves)
+
+
+def _leaf_struct(x):
+    """Shape and dtype of one input leaf — host attributes on jax arrays
+    and numpy feeds alike: the value is never materialized."""
+    import jax
+
+    shape = getattr(x, "shape", None)
+    dtype = getattr(x, "dtype", None)
+    if shape is None or dtype is None:
+        import numpy as np
+
+        arr = np.asarray(x)     # plain host scalar leaf
+        shape, dtype = arr.shape, arr.dtype
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
 class _PublishTask:
-    __slots__ = ("key", "kind", "statement", "plan_sig", "raw_call",
-                 "treedef", "structs", "shardings", "template", "flag_meta",
-                 "extra", "mesh")
+    __slots__ = ("key", "kind", "statement", "plan_sig", "exported",
+                 "template", "flag_meta", "extra", "mesh")
 
     def __init__(self, **kw):
         for k in self.__slots__:
@@ -453,8 +543,8 @@ class _PublishTask:
 
 class AotExecutableCache:
     """Process-wide orchestrator of the artifact tiers (one instance,
-    ``AOT``): load = disk -> peer -> miss; publish = background export +
-    verify + disk put + peer push.  Every operation is gated on
+    ``AOT``): load = disk -> peer -> miss; publish = background
+    serialise + verify + disk put + peer push.  Every operation is gated on
     FLAGS.aot_cache and degrades to a miss on any failure."""
 
     def __init__(self):
@@ -475,6 +565,12 @@ class AotExecutableCache:
         # touches of one executable (two sessions racing the same compile)
         # export exactly once — the second enqueue is a no-op
         self._pending: set = set()
+        # the programs this process compiled or loaded, by artifact key:
+        # another session of the process (a new connection's first
+        # statements) runs the compiled program as it is, instead of
+        # reading an artifact back from disk — which, for a program with a
+        # large dictionary baked in, is hundreds of MB to deserialize
+        self._live: "OrderedDict[str, LoadedArtifact]" = OrderedDict()
 
     # -- config -----------------------------------------------------------
     def enabled(self) -> bool:
@@ -535,6 +631,14 @@ class AotExecutableCache:
 
         if not self.enabled():
             return None
+        with self._mu:
+            art = self._live.get(key)
+            if art is not None:
+                self._live.move_to_end(key)
+        if art is not None:
+            metrics.aot_cache_hits.add(1)
+            self._record(key, art.meta, "memory", 0.0)
+            return art
         disk = self.disk()
         data = disk.get(key)
         source = "disk"
@@ -589,7 +693,21 @@ class AotExecutableCache:
         metrics.aot_cache_hits.add(1)
         metrics.aot_cache_deser_ms.observe(deser_ms)
         self._record(key, meta, source, deser_ms)
+        self._keep_live(art)
         return art
+
+    def _keep_live(self, art: LoadedArtifact) -> None:
+        with self._mu:
+            self._live[art.key] = art
+            self._live.move_to_end(art.key)
+            while len(self._live) > LIVE_PROGRAMS_MAX:
+                self._live.popitem(last=False)
+
+    def forget_live(self) -> None:
+        """Drop the in-process tier: what a restarted process starts with
+        (tests of the disk and peer tiers)."""
+        with self._mu:
+            self._live.clear()
 
     def _plant_xla_files(self, xla_files) -> None:
         """Write peer-fetched XLA persistent-cache entries into the local
@@ -627,64 +745,35 @@ class AotExecutableCache:
                        plan_sig=str(meta.get("plan_sig",
                                              rec.get("plan_sig", ""))),
                        source=source, deser_ms=round(deser_ms, 3))
-            if source in ("disk", "peer"):
+            if source in ("memory", "disk", "peer"):
                 rec["hits"] += 1
 
     # -- publish ----------------------------------------------------------
     def publish_async(self, key: str, kind: str, statement: str, plan_sig,
-                      raw_call, args, out, flag_meta, extra=None,
+                      program: "ExportedProgram", out, flag_meta, extra=None,
                       mesh=None) -> None:
-        """Enqueue one settled executable for background export.  ``args``
-        is the live input pytree (only its struct skeleton is kept),
-        ``out`` the full output pytree of a successful run (only its
-        structure template is kept), ``raw_call(args_pytree)`` the
-        pure traceable program."""
+        """Keep one settled executable: in this process at once (another
+        session's first run of it is a hit of the in-process tier), and on
+        disk and at the peers through the background publisher, which
+        serialises ``program.exported`` — the module the query's thread
+        traced and compiled — and neither traces nor compiles.  ``out`` is
+        the full output pytree of a successful run (only its structure
+        template is kept).  A program that could not be exported is not
+        kept."""
         import jax
 
-        if not self.enabled():
-            return
-        leaves, treedef = jax.tree_util.tree_flatten(args)
-        try:
-            def _struct(x):
-                # metadata only: .shape/.dtype are host attributes on both
-                # jax arrays and numpy feeds — never materialize the value
-                shape = getattr(x, "shape", None)
-                dtype = getattr(x, "dtype", None)
-                if shape is None or dtype is None:
-                    import numpy as np
-
-                    arr = np.asarray(x)     # plain host scalar leaf
-                    shape, dtype = arr.shape, arr.dtype
-                return jax.ShapeDtypeStruct(shape, dtype)
-
-            # live input shardings feed the verify/priming compile: a
-            # multi-device exported program can only lower in a context
-            # that knows its device assignment.  Single-device leaves stay
-            # UNANNOTATED — an explicit SingleDeviceSharding changes the
-            # XLA compile-cache key away from what the load-time call
-            # produces, and a mismatched priming is a wasted compile
-            def _multi(x):
-                sh = getattr(x, "sharding", None)
-                try:
-                    return sh if sh is not None and \
-                        len(sh.device_set) > 1 else None
-                except Exception:   # noqa: BLE001
-                    return None
-
-            # leaves is a host list; per-leaf work reads metadata only
-            structs = [_struct(x) for x in leaves]  # tpulint: disable=RETRACE
-            shardings = [_multi(x) for x in leaves]  # tpulint: disable=RETRACE
-        except Exception:   # noqa: BLE001 — an unexportable feed (object
-            #                 leaf) simply opts this executable out
-            from . import metrics
-            metrics.count_swallowed("aot.structs")
+        exported = getattr(program, "exported", None)
+        if not self.enabled() or exported is None:
             return
         template = jax.tree_util.tree_map(lambda _x: 0, out)
+        self._keep_live(LoadedArtifact(
+            key, {"kind": kind, "statement": statement,
+                  "plan_sig": str(plan_sig), "flag_meta": flag_meta},
+            "memory", program.call, template, extra))
         task = _PublishTask(key=key, kind=kind, statement=statement,
-                            plan_sig=plan_sig, raw_call=raw_call,
-                            treedef=treedef, structs=structs,
-                            shardings=shardings, template=template,
-                            flag_meta=flag_meta, extra=extra, mesh=mesh)
+                            plan_sig=plan_sig, exported=exported,
+                            template=template, flag_meta=flag_meta,
+                            extra=extra, mesh=mesh)
         with self._mu:
             if key in self._pending:
                 return          # a concurrent first touch already queued it
@@ -741,13 +830,8 @@ class AotExecutableCache:
 
         from ..storage.aot_tier import pack_artifact
         from . import metrics
-        from ..exec import executor
 
-        # the export (and the verify compile below) re-trace the plan
-        # function on THIS thread: flag it so run_local's side-effect
-        # counters (trace_count / metrics.xla_retraces) stay untouched —
-        # a background publish must not read as plan-cache churn
-        executor.ACCOUNTING_TRACE.active = True
+        t_publish = time.perf_counter()
         try:
             if task.statement == "<unnamed>" \
                     and os.path.exists(self.disk().path(task.key)):
@@ -755,33 +839,12 @@ class AotExecutableCache:
                 # executable: same key, same program — re-exporting would
                 # only overwrite the artifact's real statement label
                 return
-            raw_call, treedef = task.raw_call, task.treedef
-
-            def _flat(*leaves):
-                out = raw_call(jax.tree_util.tree_unflatten(treedef,
-                                                            list(leaves)))
-                return tuple(jax.tree_util.tree_leaves(out))
-
-            exported = jax_export.export(jax.jit(_flat))(*task.structs)
-            blob = bytes(exported.serialize())
+            blob = bytes(task.exported.serialize())
             # verify: deserializing our own bytes is the integrity check —
-            # a corrupt export dies here, not on a serving node
-            back = jax_export.deserialize(bytearray(blob))
-            try:
-                # prime the XLA persistent cache: the deserialized
-                # module's compile-cache key differs from the original jit
-                # compile's, so without this pass every future load would
-                # still pay one backend compile.  Lowering needs the live
-                # device assignment for multi-device programs — the
-                # shardings captured from the real input leaves carry it.
-                primed = [jax.ShapeDtypeStruct(st.shape, st.dtype,
-                                               sharding=sh)
-                          for st, sh in zip(task.structs, task.shardings)]
-                jax.jit(back.call).lower(*primed).compile()
-            except Exception:   # noqa: BLE001 — priming is an
-                #   optimization: without it the first load compiles once
-                from . import metrics as _m
-                _m.count_swallowed("aot.prime")
+            # a corrupt export dies here, not on a serving node.  Nothing
+            # is compiled: the query's thread compiled jit(exported.call),
+            # the module a loader compiles, so it is in the XLA cache
+            jax_export.deserialize(bytearray(blob))
             meta = {"format": AOT_FORMAT, "key": task.key,
                     "kind": task.kind, "statement": task.statement,
                     "plan_sig": str(task.plan_sig),
@@ -814,7 +877,8 @@ class AotExecutableCache:
                                 "jax": jax.__version__}, xla_files):
                     self._xla_pushed |= {n for n, _ in xla_files}
         finally:
-            executor.ACCOUNTING_TRACE.active = False
+            metrics.aot_publish_ms.add(
+                (time.perf_counter() - t_publish) * 1e3)
 
     # -- introspection (information_schema.aot_cache, tools/aotcache) -----
     def rows(self) -> list[dict]:

@@ -676,6 +676,16 @@ shuffle_rounds_saved = Counter("shuffle_rounds_saved")
 mesh_programs = Counter("mesh_programs")
 exchange_bytes = Counter("exchange_bytes")
 join_cap_retries = Counter("join_cap_retries")
+# exec/caps.settle, once an execution of a plan with capacity flags (shrink,
+# join, multiway, exchange): join_cap_slots = the capacities its flags were
+# held against, join_live_rows = the rows the flags reported (each at most
+# its capacity); their ratio is how full the static shapes ran.  A shrink
+# that cuts nothing (cap = its child's size) has no flag and counts in
+# neither.  aot_publish_ms: ms, not a count: the publisher's wall time per
+# settled executable (export + serialize + disk), off the query's thread
+join_cap_slots = Counter("join_cap_slots")
+join_live_rows = Counter("join_live_rows")
+aot_publish_ms = Counter("aot_publish_ms")
 mesh_shard_ms = Counter("mesh_shard_ms")
 # equality-class constant propagation (plan/planner.py): derived
 # col = const conjuncts pushed to sibling scans at plan time
