@@ -21,12 +21,19 @@ again at its input's static size (``cap_full``: every row to one
 destination).  Neither can overflow, whatever the chain's length.  Only a
 many-to-many join above an overflow, whose need has no bound but its flag,
 can ask for a third compile.
+
+One flag is no capacity and settles here all the same: a ``stream``
+aggregate's check of the order its planner claimed (0, or 1 when some live
+row's key lay below the row before it).  Raised, the node takes the strategy
+it would have had (``AggNode.unstream``) and the plan is traced again: one
+more compile, like a cap's, and never an answer from rows out of order.
 """
 
 from __future__ import annotations
 
 from typing import NamedTuple, Optional
 
+from ..utils import metrics
 from ..plan.nodes import (AggNode, DistinctNode, FilterNode, JoinNode,
                           LimitNode, MembershipNode, MultiJoinNode, PlanNode,
                           ProjectNode, ScalarSourceNode, ScanNode, ShrinkNode,
@@ -170,8 +177,9 @@ def _mark_downstream(plan: PlanNode, grown: set, rows: dict) -> None:
 def settle(plan: Optional[PlanNode], join_order, needs) -> Settled:
     """Hold one execution's flags against the capacities it ran with.
     ``needs[i]`` is the rows ``join_order[i]`` reported (``None`` for a flag
-    that is no capacity: a scalar subquery's count).  Grows what
-    overflowed and, given the ``plan`` the holders belong to, what is
+    that is no capacity: a scalar subquery's count; a stream aggregate's
+    order check reports 0 or 1, and 1 takes the node off that strategy).
+    Grows what overflowed and, given the ``plan`` the holders belong to, what is
     downstream of it; an AOT executable's shims have no plan (the caller
     falls back to a fresh compile)."""
     grown: set = set()
@@ -179,6 +187,19 @@ def settle(plan: Optional[PlanNode], join_order, needs) -> Settled:
     slots = live = 0
     for holder, need in zip(join_order, needs):
         if need is None:
+            continue
+        is_agg = isinstance(holder, AggNode)
+        if is_agg or getattr(holder, "kind", "") == "AggNode":
+            # a stream aggregate's order check.  An AOT executable's shim
+            # has no node to change: its caller compiles the plan afresh,
+            # and that program's own check lands on the node
+            if not need:
+                metrics.stream_agg_runs.add(1)
+            else:
+                grown.add(id(holder))
+                if is_agg:
+                    holder.unstream()
+                    metrics.stream_agg_fallbacks.add(1)
             continue
         cap = holder.cap or 0
         slots += cap

@@ -30,7 +30,8 @@ from ..ops import join as join_ops
 from ..ops.compact import compact, head
 from ..ops.hashagg import (AggSpec, MERGE_OP, finalize_partials,
                            group_aggregate_dense, group_aggregate_sorted,
-                           partial_specs, scalar_aggregate)
+                           group_aggregate_stream, partial_specs,
+                           scalar_aggregate)
 from ..ops.sort import SortKey, sort_batch, top_k
 from ..ops.compact import shrink
 from ..plan.nodes import (AggNode, DistinctNode, ExchangeNode, FilterNode,
@@ -426,6 +427,14 @@ def _eval(node: PlanNode, batches: dict, overflows: list, ctx=None) -> ColumnBat
             if merge:
                 return _scalar_agg_merged(child, node.specs)
             return scalar_aggregate(child, node.specs)
+        if node.strategy == "stream":
+            # rows already in key order: segmented scans over the lanes as
+            # they come; the kernel's own check of that order rides the
+            # flag channel (a flag that is no capacity: exec/caps.settle)
+            out, unordered = group_aggregate_stream(
+                child, node.key_names[0], node.specs)
+            overflows.append((node, unordered))
+            return out
         shift = getattr(node, "key_shift", {}) or {}
         if node.strategy == "dense":
             work = child

@@ -2155,8 +2155,10 @@ class Session:
             return None
 
     def _planner(self) -> Planner:
+        # a mesh session's scans read shards: a shard boundary splits a
+        # run of equal keys, so no aggregate there is planned as a stream
         return Planner(self.db.catalog, self.db.stores, self.current_db,
-                       self._stats_fn)
+                       self._stats_fn, lane_order=self.mesh is None)
 
     def _plan_select(self, stmt: SelectStmt) -> PlanNode:
         """Logical+physical planning, plus the distribution pass (the
@@ -4977,7 +4979,8 @@ class Session:
             return None
         if store.num_rows < int(FLAGS.streaming_min_rows):
             return None
-        if streaming.eligible(plan, n) is None:
+        hit = streaming.eligible(plan, n)
+        if hit is None:
             return None
         try:
             cs = chunk_set(store, n.table_key, self.db.cold_fs())
@@ -4998,6 +5001,11 @@ class Session:
         keep = cs.pruned(ranges)
         n.access_desc = (f"stream({len(keep)}/{cs.n_chunks} chunks, "
                          f"{cs.capacity} rows each)")
+        if hit[1].strategy == "stream":
+            # the chunk fold merges partials by group id: an aggregate
+            # planned for resident rows in key order folds as the scatter
+            # or the sort it would have been
+            hit[1].unstream()
         return ChunkSource(cs, keep)
 
     def _evict_access(self, table_key: str, version: int):
@@ -5782,7 +5790,7 @@ class Session:
                     if needed > 1:
                         raise PlanError("Subquery returns more than 1 row")
                     needed = None
-                elif needed > (node.cap or 0) and mesh is not None and (
+                elif mesh is not None and needed > (node.cap or 0) and (
                         isinstance(node, ExchangeNode)
                         or (isinstance(node, _CapBox)
                             and node.kind == "shuffle")):

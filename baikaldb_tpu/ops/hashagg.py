@@ -13,8 +13,14 @@ the VPU, so grouping is re-expressed as data-parallel primitives:
 - **sort path**: general fallback — multi-key stable sort, boundary detection,
   ``cumsum`` group ids, then segment reductions into a static ``max_groups``
   table.
+- **stream path**: when the planner can show that the live rows arrive in
+  non-decreasing order of the one integer / DATE group key (a table stored in
+  key order, carried up through filters, shrinks and the probe side of
+  unique-build joins), equal keys are adjacent and every aggregate is a
+  segmented scan over the lanes as they come: no scatter, no sort, no
+  domain-sized buffer; the output keeps the input's lanes.
 
-Both paths emit *mergeable partials* (SUM/COUNT pairs for AVG etc.), so the
+The dense and sort paths emit *mergeable partials* (SUM/COUNT pairs for AVG etc.), so the
 distributed layer can ``psum``/re-reduce them across mesh shards exactly like
 the reference merges per-region partial aggregates.
 """
@@ -28,7 +34,8 @@ import jax
 import jax.numpy as jnp
 
 from ..column.batch import Column, ColumnBatch
-from .segments import seg_max, seg_min, seg_sum
+from .segments import (scan_identity, seg_max, seg_min, seg_scan, seg_sum,
+                       shift_lanes)
 from .sort import argsort
 from ..types import LType
 
@@ -511,6 +518,112 @@ def group_aggregate_sorted(batch: ColumnBatch, key_names: list[str],
     if with_overflow:
         return out, jnp.sum(flags) > max_groups
     return out
+
+
+# ----------------------------------------------------------------------
+# stream group-by (input already in key order: segmented scans)
+
+# what _segment_one knows as an associative fold; DISTINCT, percentile and
+# HLL specs need each group's rows and keep the planner off this path
+STREAM_OPS = frozenset({"count_star", "count", "sum", "avg", "min", "max",
+                        "sumsq", "stddev", "stddev_samp", "variance",
+                        "var_samp"})
+
+
+def stream_supported(specs: list[AggSpec]) -> bool:
+    return all(s.op in STREAM_OPS and not s.distinct for s in specs)
+
+
+def group_aggregate_stream(batch: ColumnBatch, key_name: str,
+                           specs: list[AggSpec]):
+    """GROUP BY one key whose live lanes arrive in non-decreasing key order
+    (the planner's claim, from the store's ``ordered`` statistic and the
+    operator chain).  -> (out, unordered).
+
+    A live lane starts a run when its key differs from the previous LIVE
+    lane's; each aggregate is one inclusive segmented scan over
+    (starts_run, value); the group's row is the run's last live lane.  The
+    output keeps the input's lanes: ``sel`` marks each run's last live
+    lane, the key column is the input's own, the aggregates are the scans'
+    values there.  Dead lanes (padding, filtered rows, a shrink's tail)
+    take part as identities whatever they hold.
+
+    ``unordered`` (int32 0/1) is the check of the claim: some live lane's
+    key is below the previous live lane's, or NULL.  It rides the flag
+    channel; raised, the answer of this program is not used (exec/caps.py
+    gives the node the strategy it would have had, and it runs again)."""
+    key = batch.column(key_name)
+    sel = batch.sel_mask()
+    k = key.data
+    # the neighbouring live lanes' keys: a fill from each side, moved one
+    # lane on so that a lane sees its neighbour and not itself
+    def neighbour(reverse: bool):
+        seen, (nk,) = seg_scan(sel, (k,), ("left",), reverse=reverse)
+        return (shift_lanes(seen, 1, False, reverse=reverse),
+                shift_lanes(nk, 1, 0, reverse=reverse))
+
+    has_prev, prev_k = neighbour(False)
+    has_next, next_k = neighbour(True)
+    starts = sel & ~(has_prev & (prev_k == k))
+    last = sel & ~(has_next & (next_k == k))
+    unordered = jnp.any(sel & has_prev & (k < prev_k))
+    if key.validity is not None:
+        unordered = unordered | jnp.any(sel & ~key.validity)
+
+    # AVG and the variance family are finalized from SUM / SUMSQ / COUNT
+    # partials, as the streamed fold and the mesh merge do; one scan lane
+    # per distinct (fold, input)
+    parts, finalize = partial_specs(specs)
+    lanes: dict = {}
+
+    def lane(kind: str, name: Optional[str]):
+        c = None if name is None else batch.column(name)
+        if kind == "n" and c is not None and c.validity is None:
+            name = c = None     # no NULLs: the count of the live lanes
+        if (kind, name) not in lanes:
+            live = sel if c is None or c.validity is None \
+                else sel & c.validity
+            if kind == "n":
+                op, v = "add", live.astype(jnp.int32)
+            elif kind in ("sum", "sumsq"):
+                x = c.data.astype(_sum_dtype(c) if kind == "sum"
+                                  else jnp.float64)
+                op, v = "add", jnp.where(live, x * x if kind == "sumsq"
+                                         else x, 0)
+            else:
+                x = c.data.astype(jnp.int8) if c.data.dtype == jnp.bool_ \
+                    else c.data
+                op, v = kind, jnp.where(live, x, scan_identity(kind, x.dtype))
+            lanes[(kind, name)] = (op, v)
+        return (kind, name)
+
+    wanted = [(p, lane("n", p.input)) if p.op in ("count", "count_star")
+              else (p, lane(p.op, p.input), lane("n", p.input))
+              for p in parts]
+    order = list(lanes)
+    _, scanned = seg_scan(starts, tuple(lanes[i][1] for i in order),
+                          tuple(lanes[i][0] for i in order))
+    got = dict(zip(order, scanned))
+
+    names, cols = [key_name], [key]
+    for p, *at in wanted:
+        names.append(p.out_name)
+        if p.op in ("count", "count_star"):
+            cols.append(Column(got[at[0]].astype(jnp.int64), None,
+                               LType.INT64))
+            continue
+        c = batch.column(p.input)
+        # a group of a column without NULLs always holds a value
+        some = None if c.validity is None else got[at[1]] > 0
+        if p.op in ("min", "max"):
+            cols.append(Column(got[at[0]].astype(c.data.dtype), some,
+                               c.ltype, c.dictionary))
+        else:
+            cols.append(Column(got[at[0]], some,
+                               agg_result_type(p.op, c.ltype)))
+    out = finalize_partials(ColumnBatch(tuple(names), cols, last, None),
+                            finalize, [key_name])
+    return out, unordered.astype(jnp.int32)
 
 
 # ----------------------------------------------------------------------

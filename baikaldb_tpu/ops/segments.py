@@ -20,6 +20,14 @@ decided at trace time:
 - **CPU or large num_segments**: ``jax.ops.segment_*`` scatter, unchanged.
   The ~512-segment crossover is where per-segment VPU work meets the
   scatter's fixed ~8.8ns/row cost (both measured on v5e).
+
+Both of those index by group id.  The third GROUP BY strategy, ``stream``
+(``ops/hashagg.group_aggregate_stream``, chosen by the planner when an
+aggregate's live rows arrive in non-decreasing order of its one integer /
+DATE key and the key's domain is past the select+reduce and Pallas sizes),
+needs no id and no domain-sized output at all: equal keys are adjacent, so
+every aggregate is a *segmented scan* over the lanes as they come —
+:func:`seg_scan` below, elementwise passes only, on either backend.
 """
 
 from __future__ import annotations
@@ -82,3 +90,97 @@ def seg_min(x, gid, num_segments: int):
 def seg_max(x, gid, num_segments: int):
     """Drop-in ``jax.ops.segment_max`` (empty segments get dtype min/-inf)."""
     return _seg_extremum(x, gid, num_segments, False)
+
+
+# ----------------------------------------------------------------------
+# segmented scan (the ``stream`` GROUP BY's primitive)
+
+# lanes of one row of the blocked scan: the shifted passes run inside rows
+# of this many lanes, a carry runs over the rows' totals
+SCAN_BLOCK = 1024
+
+_SCAN_OPS = {
+    "add": jnp.add, "min": jnp.minimum, "max": jnp.maximum,
+    # fill: a lane without a flag takes what the lanes before it hold
+    "left": lambda prev, cur: prev,
+}
+
+
+def scan_identity(op: str, dtype):
+    """What a lane that takes no part holds, for ``op`` over ``dtype``."""
+    if op in ("add", "left"):
+        return jnp.zeros((), dtype)
+    info = (jnp.iinfo if jnp.issubdtype(dtype, jnp.integer)
+            else jnp.finfo)(dtype)
+    return jnp.asarray(info.max if op == "min" else info.min, dtype)
+
+
+def shift_lanes(x, d: int, fill, axis: int = 0, reverse: bool = False):
+    """``x`` moved ``d`` lanes along ``axis`` towards the higher index (the
+    lower with ``reverse``): lane i holds what lane i-d held, the vacated
+    lanes hold ``fill``."""
+    cfg = [(0, 0, 0)] * x.ndim
+    cfg[axis] = (-d, d, 0) if reverse else (d, -d, 0)
+    return jax.lax.pad(x, jnp.asarray(fill, x.dtype), cfg)
+
+
+def _scan_rows(flags, vals, ops, axis: int, reverse: bool):
+    """Hillis-Steele passes along ``axis``: log2(lanes) shifted combines."""
+    n = flags.shape[axis]
+    d = 1
+    while d < n:
+        vals = tuple(
+            jnp.where(flags, v, _SCAN_OPS[op](
+                shift_lanes(v, d, scan_identity(op, v.dtype), axis, reverse),
+                v))
+            for v, op in zip(vals, ops))
+        flags = flags | shift_lanes(flags, d, False, axis, reverse)
+        d *= 2
+    return flags, vals
+
+
+def seg_scan(flags, vals, ops, reverse: bool = False):
+    """Inclusive segmented scan over 1-D lanes, no gather and no scatter.
+
+    ``flags[i]`` starts a segment at lane i; ``vals`` is a tuple of arrays
+    scanned together, ``ops[j]`` in ``add | min | max | left`` combines
+    ``vals[j]`` (``left``: an unflagged lane copies the lane before it, a
+    fill).  Lane i of the result folds the lanes from its segment's flag
+    to i; with ``reverse`` a segment runs from its flag down to lower
+    indices.  A lane that should not count holds the op's
+    :func:`scan_identity` and no flag.  Returns ``(seen, outs)``:
+    ``seen[i]`` says some flag lies at or before lane i, which is what
+    makes ``outs`` meaningful there.
+
+    Blocked: the shifted passes run inside rows of ``SCAN_BLOCK`` lanes,
+    then the rows' totals are scanned the same way and carried into the
+    rows after them — 10 passes over the data whatever its length, where
+    one flat Hillis-Steele scan would make 23 over 8.4 M lanes."""
+    vals = tuple(vals)
+    n = flags.shape[0]
+    if n <= SCAN_BLOCK:
+        return _scan_rows(flags, vals, ops, 0, reverse)
+    rows = -(-n // SCAN_BLOCK)
+    tail = rows * SCAN_BLOCK - n
+
+    def blocked(x, fill):
+        if tail:
+            x = jnp.pad(x, (0, tail), constant_values=fill)
+        return x.reshape(rows, SCAN_BLOCK)
+
+    f2 = blocked(flags, False)
+    v2 = tuple(blocked(v, scan_identity(op, v.dtype))
+               for v, op in zip(vals, ops))
+    f2, v2 = _scan_rows(f2, v2, ops, 1, reverse)
+    edge = 0 if reverse else SCAN_BLOCK - 1
+    cf, cv = seg_scan(f2[:, edge], tuple(v[:, edge] for v in v2), ops,
+                      reverse)
+    # what reaches a row is the scan up to the row before it
+    cf = shift_lanes(cf, 1, False, 0, reverse)[:, None]
+    outs = tuple(
+        jnp.where(f2, v, _SCAN_OPS[op](
+            shift_lanes(c, 1, scan_identity(op, c.dtype), 0,
+                        reverse)[:, None], v))
+        for v, c, op in zip(v2, cv, ops))
+    seen = f2 | cf
+    return seen.reshape(-1)[:n], tuple(o.reshape(-1)[:n] for o in outs)

@@ -130,7 +130,7 @@ class AggNode(PlanNode):
     key_names by a child ProjectNode."""
     key_names: list[str] = field(default_factory=list)
     specs: list[AggSpec] = field(default_factory=list)
-    strategy: str = "sorted"                 # dense | sorted
+    strategy: str = "sorted"                 # dense | sorted | stream
     domains: list[int] = field(default_factory=list)     # dense: per-key domain
     max_groups: int = 0                      # sorted: static group cap
     # "collective": per-shard partials merged in-network (psum/pmin/pmax) —
@@ -147,9 +147,20 @@ class AggNode(PlanNode):
     # chain: the executor feeds store.agg_sort_permutation(cols) so the
     # kernel skips its multi-key device sort.  (table_key, (col, ...))
     presort: Optional[tuple] = None
+    # stream strategy (one key whose live rows arrive in key order:
+    # segmented scans, ops/hashagg.group_aggregate_stream): the (strategy,
+    # domains, key_shift) this node would have had without that order —
+    # what :meth:`unstream` gives it when the program's own check, or a
+    # path that does not keep the image's order, says otherwise
+    unordered: Optional[tuple] = None
+
+    def unstream(self) -> None:
+        self.strategy, self.domains, self.key_shift = self.unordered
+        self.unordered = None
 
     def _label(self):
-        s = f"dense{self.domains}" if self.strategy == "dense" else f"sorted<= {self.max_groups}"
+        s = {"dense": f"dense{self.domains}", "stream": "stream"}.get(
+            self.strategy, f"sorted<= {self.max_groups}")
         m = " merge=collective" if self.merge else ""
         a = f" agg_dist={self.agg_dist}" if self.agg_dist else ""
         return f"Agg(keys={self.key_names} {s} aggs={[sp.out_name for sp in self.specs]}{m}{a})"

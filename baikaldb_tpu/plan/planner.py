@@ -26,7 +26,8 @@ import numpy as np
 from ..expr.ast import AggCall, Call, ColRef, Expr, Lit, Subquery, WindowCall, walk
 from ..expr.compile import infer_type
 from ..meta.catalog import Catalog
-from ..ops.hashagg import AggSpec, agg_result_type
+from ..ops.hashagg import (AggSpec, agg_result_type, dense_num_groups,
+                           stream_supported)
 from ..sql.lexer import SqlError
 from ..sql.stmt import JoinClause, SelectStmt, TableRef
 from ..types import Field, LType, Schema
@@ -44,7 +45,8 @@ define("dense_join_span_max", 1 << 24,
        "strategy (memory: 4 bytes/slot); larger domains use the sort join")
 from .nodes import (AggNode, DistinctNode, FilterNode, JoinNode, LimitNode,
                     MembershipNode, PlanNode, ProjectNode, ScalarSourceNode,
-                    ScanNode, SortNode, UnionNode, ValuesNode, WindowNode)
+                    ScanNode, ShrinkNode, SortNode, UnionNode, ValuesNode,
+                    WindowNode)
 
 # dense group-by: max product of key domains for segment-sum aggregation
 # (accumulators are domain-sized: 8 bytes/slot/agg); larger domains use the
@@ -103,11 +105,14 @@ class Scope:
 
 class Planner:
     def __init__(self, catalog: Catalog, stores: dict, default_db: str,
-                 stats_fn=None):
+                 stats_fn=None, lane_order: bool = True):
         self.catalog = catalog
         self.stores = stores          # "db.table" -> TableStore
         self.default_db = default_db
         self.stats_fn = stats_fn      # (table_key, col) -> dict | None
+        # whether a scan's lanes arrive in the stored image's order (the
+        # stream group-by's premise; not so on a mesh)
+        self.lane_order = lane_order
         self._ids = itertools.count()
         self._ctes: dict[str, SelectStmt] = {}
 
@@ -1207,6 +1212,11 @@ class Planner:
             sch = psch
 
         strategy, domains, max_groups, key_shift = self._group_strategy(plan, sch, key_names)
+        unordered = None
+        if self._streams(plan, sch, key_names, specs + fd_specs, strategy,
+                         domains):
+            unordered = (strategy, domains, key_shift)
+            strategy, domains, key_shift = "stream", [], {}
         out_fields = []
         for kn in key_names:
             f = sch.field(kn)
@@ -1221,7 +1231,7 @@ class Planner:
         agg = AggNode(children=[plan], key_names=key_names,
                       specs=specs + fd_specs,
                       strategy=strategy, domains=domains, max_groups=max_groups,
-                      schema=Schema(tuple(out_fields)))
+                      unordered=unordered, schema=Schema(tuple(out_fields)))
         if strategy == "sorted" and key_names and \
                 self._position_preserving(plan):
             # all keys are base columns of the one underlying scan: the
@@ -1738,7 +1748,8 @@ class Planner:
         Returns the value expr, or None when the shape doesn't fit (not a
         single aggregate item, or conjuncts that resolve in neither
         scope)."""
-        from ..ops.hashagg import AggSpec, agg_result_type
+        from ..ops.hashagg import (AggSpec, agg_result_type, dense_num_groups,
+                           stream_supported)
 
         if stmt.table is None or stmt.group_by or stmt.having or \
                 stmt.order_by or stmt.limit is not None or stmt.ctes or \
@@ -2063,6 +2074,75 @@ class Planner:
 
     def _sorted_strategy(self, plan, key_names):
         return "sorted", [], 0, {}   # max_groups resolved at exec from batch size
+
+    def _streams(self, plan, sch: Schema, key_names: list[str], specs,
+                 strategy: str, domains: list[int]) -> bool:
+        """Whether this GROUP BY runs as segmented scans over rows already
+        in key order (ops/hashagg.group_aggregate_stream) in place of the
+        ``strategy`` chosen above: one integer / DATE key (after the
+        functional-dependency reduction), aggregates that are associative
+        folds, live rows that arrive in non-decreasing key order
+        (:meth:`_ordered_on`), and a domain past the one-pass reduces —
+        small domains keep select+reduce / Pallas, and a span too wide for
+        ``dense`` no longer pays for a sort."""
+        if not self.lane_order or len(key_names) != 1 \
+                or not stream_supported(specs):
+            return False
+        f = sch.field(key_names[0])
+        if not (f.ltype.is_integer or f.ltype is LType.DATE):
+            return False
+        if strategy == "dense":
+            from ..ops.pallas_kernels import PALLAS_MAX_GROUPS
+            from ..ops.segments import ONEHOT_MAX_SEGMENTS
+            if dense_num_groups(domains) + 1 <= max(ONEHOT_MAX_SEGMENTS,
+                                                    PALLAS_MAX_GROUPS):
+                return False
+        return self._ordered_on(plan, key_names[0])
+
+    def _ordered_on(self, plan: PlanNode, qualified: str) -> bool:
+        """Whether ``plan``'s live rows arrive in non-decreasing order of
+        the column: it is stored that way (the store's ``ordered``
+        statistic of a full scan) and nothing between the scan and here
+        moves a lane — a filter is a mask, a shrink is stable, a
+        unique-build (dense) inner / left join and a semi / anti join keep
+        the probe's lanes.  Through an inner join's equality the build
+        side's key is ordered where the probe's is: on the live lanes they
+        are equal.  Anything else (a sort, an expanding join, a set
+        operation, another aggregate) ends the claim.  Which batch a scan
+        is handed is the execution's choice (an access-path gather, a
+        pinned image): the program checks the order it was promised."""
+        node = plan
+        while True:
+            if isinstance(node, ScanNode):
+                lbl, _, col = qualified.partition(".")
+                if lbl != node.label or node.ann is not None \
+                        or self.stats_fn is None:
+                    return False
+                st = self.stats_fn(node.table_key, col)
+                return bool(st and st.get("ordered"))
+            if isinstance(node, (FilterNode, ShrinkNode)):
+                node = node.children[0]
+            elif isinstance(node, ProjectNode):
+                if qualified not in node.names:
+                    return False
+                e = node.exprs[node.names.index(qualified)]
+                if not isinstance(e, ColRef):
+                    return False
+                qualified, node = e.name, node.children[0]
+            elif isinstance(node, JoinNode) and len(node.children) == 2 \
+                    and (node.how in ("semi", "anti")
+                         or (node.strategy == "dense"
+                             and node.how in ("inner", "left"))):
+                probe = node.children[0]
+                if node.how == "inner" and qualified in node.right_keys:
+                    qualified = node.left_keys[
+                        node.right_keys.index(qualified)]
+                elif not any(f.name == qualified
+                             for f in probe.schema.fields):
+                    return False
+                node = probe
+            else:
+                return False
 
     def _key_scan(self, plan: PlanNode, qualified: str,
                   for_unique: bool = False):

@@ -772,6 +772,8 @@ class TableStore:
                     # stats stay partial; planner falls back to defaults
                     metrics.count_swallowed("column_store.zone_stats")
             st.update(self._histogram_stats(col, f) or {})
+            if f.ltype.is_integer or f.ltype is LType.DATE:
+                st["ordered"] = _non_decreasing(col)
             cache[1][column] = st
             return st
 
@@ -1857,6 +1859,24 @@ class TableStore:
             if not self.regions:
                 self.regions = [Region(self._alloc_region_id(),
                                        self.arrow_schema.empty_table())]
+
+
+def _non_decreasing(col) -> bool:
+    """The ``ordered`` statistic: the column holds no NULL and never steps
+    down in image order (the snapshot's: ``device_table_batch`` is the
+    snapshot plus a dead tail), so a GROUP BY on it finds equal keys in
+    adjacent rows (plan/planner._streams).  One comparison pass."""
+    if col.null_count:
+        return False
+    last = None
+    for chunk in col.chunks:
+        a = chunk.to_numpy(zero_copy_only=False)
+        if not len(a):
+            continue
+        if (last is not None and a[0] < last) or bool((a[1:] < a[:-1]).any()):
+            return False
+        last = a[-1]
+    return True
 
 
 def _coerce(table: pa.Table, schema: pa.Schema) -> pa.Table:

@@ -684,6 +684,12 @@ join_cap_retries = Counter("join_cap_retries")
 # neither.  aot_publish_ms: ms, not a count: the publisher's wall time per
 # settled executable (export + serialize + disk), off the query's thread
 join_cap_slots = Counter("join_cap_slots")
+# exec/caps.settle: stream_agg_runs +1 for each GROUP BY an execution ran as
+# segmented scans over rows already in key order (the planner's ``stream``
+# strategy) and whose own check of that order passed; stream_agg_fallbacks
+# +1 when the check failed: that node was traced again as a scatter or a sort
+stream_agg_runs = Counter("stream_agg_runs")
+stream_agg_fallbacks = Counter("stream_agg_fallbacks")
 join_live_rows = Counter("join_live_rows")
 aot_publish_ms = Counter("aot_publish_ms")
 mesh_shard_ms = Counter("mesh_shard_ms")
