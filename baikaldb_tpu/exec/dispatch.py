@@ -355,7 +355,8 @@ class BatchDispatcher:
         metrics.group_occupancy.observe(float(G))
         from ..utils import compilecache
         from . import caps
-        from .executor import AotRawShim, flag_meta_of
+        from .executor import (AotRawShim, count_lowerings, flag_meta_of,
+                               traced_extra)
 
         # AOT artifact identity for this batched program: the statement
         # group + plan signature (ck_base), the padded group size and
@@ -408,7 +409,7 @@ class BatchDispatcher:
                         # the vmapped program + its egress column meta
                         # round-trip from the artifact: zero traces
                         pair = (lambda tb, sp_, _art=art: _art.run((tb, sp_)),
-                                AotRawShim(art.flag_meta),
+                                AotRawShim(art.flag_meta, art.extra),
                                 [art.extra["egress_meta"]], vk)
                         with self._mu:
                             self._compiled[ck] = pair
@@ -481,7 +482,9 @@ class BatchDispatcher:
                             entry.get("plan_sig"), fn,
                             ((gdatas, gvalids, ns_dev), flags),
                             flag_meta_of(raw.join_order),
-                            extra={"egress_meta": meta[0]})
+                            extra={"egress_meta": meta[0],
+                                   **traced_extra(raw, False)})
+                    count_lowerings(raw)
                     break
                 if isinstance(raw, AotRawShim):
                     # live data outgrew the artifact's baked caps: drop it

@@ -17,7 +17,7 @@ overflow is detected via returned flags and retried with doubled caps
 from __future__ import annotations
 
 from dataclasses import replace as dreplace
-from typing import Callable
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +30,8 @@ from ..ops import join as join_ops
 from ..ops.compact import compact, head
 from ..ops.hashagg import (AggSpec, MERGE_OP, finalize_partials,
                            group_aggregate_dense, group_aggregate_sorted,
-                           group_aggregate_stream, partial_specs,
+                           group_aggregate_stream, noting_lowerings,
+                           partial_specs,
                            scalar_aggregate)
 from ..ops.sort import SortKey, sort_batch, top_k
 from ..ops.compact import shrink
@@ -121,13 +122,36 @@ class AotRawShim:
     compiles — warm_compiles stays 0 by construction) and ``join_order``
     carries :class:`AotFlagShim` entries in the artifact's flag order."""
 
-    def __init__(self, flag_meta: list, exchange_bytes: int = 0):
+    def __init__(self, flag_meta: list, extra: Optional[dict] = None):
         self.join_order = [AotFlagShim(m.get("cap"), m.get("scalar", False),
                                        m.get("kind", "?"))
                            for m in (flag_meta or [])]
         self.trace_order: list = []
         self.trace_count = [0]
-        self.exchange_bytes = [int(exchange_bytes)]
+        extra = extra or {}
+        self.exchange_bytes = [int(extra.get("exchange_bytes", 0))]
+        self.agg_lowerings = list(extra.get("agg_lowerings", ()))
+
+
+def traced_extra(raw, mesh: bool) -> dict:
+    """What a program's trace recorded beside its flags, as an AOT artifact
+    carries it (``extra``) and :class:`AotRawShim` reads it back."""
+    extra: dict = {"agg_lowerings": tuple(raw.agg_lowerings)}
+    if mesh:
+        extra["exchange_bytes"] = raw.exchange_bytes[0]
+    return extra
+
+
+def count_lowerings(raw) -> None:
+    """One execution of ``raw``'s program: +1 on ``agg_<lowering>_runs`` for
+    each dense aggregate in it."""
+    for lowering in raw.agg_lowerings:
+        _LOWERING_RUNS[lowering].add(1)
+
+
+_LOWERING_RUNS = {"select_reduce": metrics.agg_select_reduce_runs,
+                  "pallas": metrics.agg_pallas_runs,
+                  "scatter": metrics.agg_scatter_runs}
 
 
 class _CapBox:
@@ -171,6 +195,9 @@ def compile_plan(plan: PlanNode, trace: bool = False, mesh=None) -> Callable:
     # chips per execution (metrics.exchange_bytes has how it is reckoned):
     # static shapes, so tallied once per trace like join_order
     exchange_bytes = [0]
+    # the lowering each dense aggregate of the program was traced with
+    # (ops/hashagg.dense_lowering), in trace order: tallied like the above
+    agg_lowerings: list = []
 
     def run_local(batches: dict):
         if not getattr(ACCOUNTING_TRACE, "active", False):
@@ -184,8 +211,10 @@ def compile_plan(plan: PlanNode, trace: bool = False, mesh=None) -> Callable:
                moved)
         # hoisted-literal params (plan/paramize.py) ride the batches pytree;
         # Param expr nodes read their slots from this trace-scoped binding
-        with bind_params(batches.get(PARAMS_KEY, ())):
+        with bind_params(batches.get(PARAMS_KEY, ())), \
+                noting_lowerings() as lowered:
             out = _sub(plan, batches, overflows, ctx)
+        agg_lowerings[:] = lowered
         # nodes are host objects: expose them on the closure (filled at trace
         # time), return only the traced flags
         join_order.clear()
@@ -227,6 +256,7 @@ def compile_plan(plan: PlanNode, trace: bool = False, mesh=None) -> Callable:
     run.trace_order = trace_order
     run.trace_count = trace_count
     run.exchange_bytes = exchange_bytes
+    run.agg_lowerings = agg_lowerings
     return run
 
 

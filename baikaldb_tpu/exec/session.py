@@ -5705,9 +5705,7 @@ class Session:
                     # with its settled caps baked in; the shim feeds the
                     # overflow loop below from the artifact's flag meta
                     pair = (art.run,
-                            executor.AotRawShim(
-                                art.flag_meta,
-                                (art.extra or {}).get("exchange_bytes", 0)),
+                            executor.AotRawShim(art.flag_meta, art.extra),
                             versions_key)
                     entry["compiled"][shape_key] = pair
             if pair is None:
@@ -5836,8 +5834,9 @@ class Session:
                         str(entry.get("text") or "<unnamed>"),
                         entry.get("plan_sig"), fn, (out, flags),
                         executor.flag_meta_of(raw.join_order),
-                        extra=None if mesh is None else
-                        {"exchange_bytes": raw.exchange_bytes[0]}, mesh=mesh)
+                        extra=executor.traced_extra(raw, mesh is not None),
+                        mesh=mesh)
+                executor.count_lowerings(raw)
                 if mesh is not None:
                     metrics.mesh_programs.add(1)
                     metrics.exchange_bytes.add(raw.exchange_bytes[0])

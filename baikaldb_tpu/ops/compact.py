@@ -52,6 +52,8 @@ def stable_partition(live) -> "jnp.ndarray":
     row j; the live prefix preserves input order (so an input sorted over
     its live rows stays sorted)."""
     n = live.shape[0]
+    if n == 0:      # an index gather that found no row: nothing to move
+        return jnp.zeros(0, jnp.int32)
     nl = jnp.cumsum(live)
     dest = jnp.where(live, nl - 1, nl[-1] + jnp.cumsum(~live) - 1)
     return jnp.zeros(n, jnp.int32).at[dest].set(

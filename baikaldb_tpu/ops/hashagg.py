@@ -27,13 +27,16 @@ the reference merges per-region partial aggregates.
 
 from __future__ import annotations
 
+from contextlib import contextmanager
+from contextvars import ContextVar
 from dataclasses import dataclass
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 
 from ..column.batch import Column, ColumnBatch
+from . import segments
 from .segments import (scan_identity, seg_max, seg_min, seg_scan, seg_sum,
                        shift_lanes)
 from .sort import argsort
@@ -275,46 +278,82 @@ def group_aggregate_dense(batch: ColumnBatch, key_names: list[str],
         code = jnp.where(code >= dom, 0, code)
         out_names.append(name)
         out_cols.append(Column(code.astype(c.data.dtype), validity, c.ltype, c.dictionary))
-    pallas_cols = _pallas_dense_cols(batch, specs, gid, ng, sel)
-    if pallas_cols is not None:
-        out_names.extend(s.out_name for s in specs)
-        out_cols.extend(pallas_cols)
+    lowering = dense_lowering(specs, lambda n: batch.column(n).ltype, ng)
+    noted = _NOTED.get()
+    if noted is not None:
+        noted.append(lowering)
+    out_names.extend(s.out_name for s in specs)
+    if lowering == "pallas":
+        out_cols.extend(_pallas_dense_cols(batch, specs, gid, ng, sel))
     else:
-        for s in specs:
-            out_names.append(s.out_name)
-            out_cols.append(_segment_one(batch, s, gid_live, ng, sel))
+        out_cols.extend(_segment_one(batch, s, gid_live, ng, sel)
+                        for s in specs)
     return ColumnBatch(tuple(out_names), out_cols, present, None)
 
 
-def _pallas_dense_cols(batch, specs, gid, ng: int, sel):
-    """Mid-cardinality dense group-by through the Pallas MXU kernels
-    (ops/pallas_kernels.py), when they're exact enough for the spec list:
+# the three lowerings of a dense aggregate's reductions, as EXPLAIN names
+# them and utils/metrics counts them (agg_<lowering>_runs)
+LOWERINGS = ("select_reduce", "pallas", "scatter")
+_NOTED: ContextVar = ContextVar("dense_lowerings", default=None)
+
+
+@contextmanager
+def noting_lowerings():
+    """While a program is traced under this, every ``group_aggregate_dense``
+    appends the lowering it chose to the list yielded: the trace-time
+    choice, kept with the compiled program by the tracer (the executor's
+    ``compile_plan``) and counted once an execution."""
+    noted: list = []
+    token = _NOTED.set(noted)
+    try:
+        yield noted
+    finally:
+        _NOTED.reset(token)
+
+
+def dense_lowering(specs: list, ltype_of: Callable, ng: int) -> str:
+    """Which lowering a dense aggregate over ``ng`` groups takes, from what
+    is known before a row is read: the backend, the group count, the
+    aggregates and the types of their inputs (``ltype_of(column)``).
+
+    ``pallas``: the MXU one-hot kernels (ops/pallas_kernels.py), when they
+    are exact enough for the spec list —
 
     - only COUNT/COUNT(*)/SUM/AVG/MIN/MAX, no DISTINCT;
-    - value columns must be floats (counts are exact; float sums carry the
-      kernel's ~1e-7 relative error); MIN/MAX additionally need FLOAT32
-      columns (f64 values would be rounded by the f32 pipeline);
+    - value columns must be floats (counts are exact; a float sum leaves
+      the kernel as a compensated f32 pair a block row and is added up in
+      DOUBLE outside it); MIN/MAX additionally need FLOAT32 columns (f64
+      values would be rounded by the f32 pipeline);
     - group count in (select+reduce crossover, PALLAS_MAX_GROUPS];
     - TPU backend.
 
-    Returns the aggregate Columns (spec order), or None to use segments."""
-    from . import segments
-    from .pallas_kernels import (PALLAS_MAX_GROUPS, filtered_group_sum,
-                                 fused_group_aggregate, partition_histogram)
+    Else the segment reductions (ops/segments.py): ``select_reduce`` on the
+    TPU up to ONEHOT_MAX_SEGMENTS, ``scatter`` beyond and on the CPU."""
+    from .pallas_kernels import PALLAS_MAX_GROUPS
 
-    if not (segments._onehot_backend()
-            and segments.ONEHOT_MAX_SEGMENTS < ng + 1 <= PALLAS_MAX_GROUPS):
-        return None
+    if segments._use_onehot(ng + 1):
+        return "select_reduce"
+    if not (segments._onehot_backend() and ng + 1 <= PALLAS_MAX_GROUPS):
+        return "scatter"
     for s in specs:
         if s.distinct or s.op not in ("count_star", "count", "sum", "avg",
                                       "min", "max"):
-            return None
+            return "scatter"
         if s.op != "count_star":
-            lt = batch.column(s.input).ltype
+            lt = ltype_of(s.input)
             if lt not in (LType.FLOAT32, LType.FLOAT64):
-                return None
+                return "scatter"
             if s.op in ("min", "max") and lt is not LType.FLOAT32:
-                return None
+                return "scatter"
+    return "pallas"
+
+
+def _pallas_dense_cols(batch, specs, gid, ng: int, sel):
+    """The aggregate Columns (spec order) of a dense group-by that
+    :func:`dense_lowering` put on the Pallas MXU kernels."""
+    from .pallas_kernels import (filtered_group_sum, fused_group_aggregate,
+                                 partition_histogram)
+
     fused: dict = {}          # input name -> (cnt, sm, mn, mx)
     star_counts = None
     cols = []
@@ -341,12 +380,11 @@ def _pallas_dense_cols(batch, specs, gid, ng: int, sel):
         if s.op == "count":
             cols.append(Column(cnt.astype(jnp.int64), None, LType.INT64))
         elif s.op == "sum":
-            cols.append(Column(sm.astype(jnp.float64), nonempty,
+            cols.append(Column(sm, nonempty,
                                agg_result_type("sum", c.ltype)))
         elif s.op == "avg":
-            cols.append(Column(sm.astype(jnp.float64)
-                               / jnp.maximum(cnt, 1).astype(jnp.float64),
-                               nonempty, LType.FLOAT64))
+            cols.append(Column(sm / jnp.maximum(cnt, 1.0), nonempty,
+                               LType.FLOAT64))
         elif s.op == "min":
             cols.append(Column(mn.astype(c.data.dtype), nonempty, c.ltype))
         else:

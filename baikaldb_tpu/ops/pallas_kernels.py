@@ -21,8 +21,23 @@ the remote compile until restructured):
 - ``precision=HIGHEST`` is IGNORED by the Mosaic dot: f32 operands truncate
   to bf16 (relative error ~2^-8 per product).  Values are split into three
   bf16-exact components (8+8+8 significand bits) and contracted separately
-  — products against a 0/1 one-hot are then exact; a Kahan accumulator row
-  in VMEM scratch compensates the cross-step f32 adds.
+  — products against a 0/1 one-hot are then exact, and so is each
+  component's dot (a group meets a handful of the 128 lanes of a row).
+
+A SUM over a FLOAT column is DOUBLE in this SQL, and the kernel has no
+64-bit type: each block row's running sum is an f32 *pair*.  The leading
+component's dot is added by an error-free TwoSum (Knuth: the add's rounding
+error comes out exact), and that error, with the two small components'
+dots, is gathered in a second f32 row, an output like the sum and not
+scratch.  (A Kahan accumulator is not enough: it rounds ``delta -
+compensation`` at every add, ~3e-8 of each value, which leaves 1e-9 over
+the 15,000 rows of a group: PR 35 measured it.)  The pair's second row
+stays accurate while it stays small, so the accumulators start afresh every
+``CHUNK_STEPS`` grid steps: the output holds one block of rows a chunk.  The
+launcher widens every row to float64 and adds chunks and block rows up
+outside the kernel: ~1e-12 of a group's sum, the precision class the chip's
+DOUBLE (an f32 pair) has everywhere else.  Counts leave as float64 too
+(exact to 2^24 rows a block row, chunk and group inside the kernel).
 
 The public entry points pad rows to full blocks with out-of-range codes
 (their one-hot rows are all zero).  There is no other lowering behind them:
@@ -38,11 +53,13 @@ import jax
 import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 LANE = 128
 R_BLOCK = 8                  # sublane rows per grid step = out_ref sublanes
 PALLAS_MAX_GROUPS = 4096
+# grid steps (of R_BLOCK * LANE = 1,024 rows) folded into one block of
+# output rows: 2 M rows a chunk, 64 chunks over a 2^27-lane table
+CHUNK_STEPS = 2048
 
 _BIG = 3.4e38                # python float (a jnp constant would be captured
 #                              by the kernel closure, which pallas_call rejects)
@@ -60,22 +77,30 @@ def _bf16_split3(v):
     return a, b, c
 
 
-def _kahan_add(o_ref, comp_ref, row, crow, delta):
-    """out[row] += delta, compensation tracked in scratch row ``crow``."""
-    y = delta - comp_ref[crow:crow + 1, :]
-    t = o_ref[row:row + 1, :] + y
-    comp_ref[crow:crow + 1, :] = (t - o_ref[row:row + 1, :]) - y
+def _pair_sum(o_ref, row, erow, v_row, oh):
+    """out[row] + out[erow] += v_row @ oh, as an f32 pair: the leading
+    bf16-exact component's dot is TwoSum-added to out[row] and the add's
+    exact rounding error goes to out[erow] with the two small components'
+    dots (2^-8 and 2^-16 of the value: their own roundings there are
+    below 1e-12 of the sum)."""
+    a, b, c = (jnp.dot(part, oh, preferred_element_type=jnp.float32)
+               for part in _bf16_split3(v_row))
+    s = o_ref[row:row + 1, :]
+    t = s + a
+    bp = t - s
+    err = (s - (t - bp)) + (a - bp)
+    o_ref[erow:erow + 1, :] += err + (b + c)
     o_ref[row:row + 1, :] = t
 
 
-def _sum_kernel(g_ref, v_ref, o_ref, comp_ref, *, ng: int):
-    """counts -> o[0:8], sums -> o[8:16] (one sublane per block row)."""
+def _sum_kernel(g_ref, v_ref, o_ref, *, ng: int):
+    """counts -> o[0:8], sums -> o[8:16], the pairs' second rows ->
+    o[16:24] (one sublane per block row)."""
     i = pl.program_id(0)
 
-    @pl.when(i == 0)
+    @pl.when(i % CHUNK_STEPS == 0)
     def _init():
         o_ref[:, :] = jnp.zeros_like(o_ref)
-        comp_ref[:, :] = jnp.zeros_like(comp_ref)
 
     it = jax.lax.broadcasted_iota(jnp.int32, (LANE, ng), 1)
     gt = jnp.transpose(g_ref[:, :])                    # (LANE, R)
@@ -84,23 +109,20 @@ def _sum_kernel(g_ref, v_ref, o_ref, comp_ref, *, ng: int):
         oh = (gt[:, r:r + 1] == it).astype(jnp.float32)   # (LANE, ng)
         o_ref[r:r + 1, :] += jnp.dot(ones, oh,
                                      preferred_element_type=jnp.float32)
-        va, vb, vc = _bf16_split3(v_ref[r:r + 1, :])
-        sm = (jnp.dot(va, oh, preferred_element_type=jnp.float32)
-              + jnp.dot(vb, oh, preferred_element_type=jnp.float32)
-              + jnp.dot(vc, oh, preferred_element_type=jnp.float32))
-        _kahan_add(o_ref, comp_ref, 8 + r, r, sm)
+        _pair_sum(o_ref, 8 + r, 16 + r, v_ref[r:r + 1, :], oh)
 
 
-def _agg_kernel(g_ref, v_ref, o_ref, comp_ref, *, ng: int):
-    """counts/sums as _sum_kernel, plus mins -> o[16:24], maxs -> o[24:32]."""
+def _agg_kernel(g_ref, v_ref, o_ref, *, ng: int):
+    """counts/sums as _sum_kernel with the pairs' second rows -> o[32:40],
+    plus mins -> o[16:24], maxs -> o[24:32]."""
     i = pl.program_id(0)
 
-    @pl.when(i == 0)
+    @pl.when(i % CHUNK_STEPS == 0)
     def _init():
         o_ref[0:16, :] = jnp.zeros_like(o_ref[0:16, :])
         o_ref[16:24, :] = jnp.full_like(o_ref[16:24, :], _BIG)
         o_ref[24:32, :] = jnp.full_like(o_ref[24:32, :], -_BIG)
-        comp_ref[:, :] = jnp.zeros_like(comp_ref)
+        o_ref[32:40, :] = jnp.zeros_like(o_ref[32:40, :])
 
     it = jax.lax.broadcasted_iota(jnp.int32, (LANE, ng), 1)
     gt = jnp.transpose(g_ref[:, :])
@@ -111,11 +133,7 @@ def _agg_kernel(g_ref, v_ref, o_ref, comp_ref, *, ng: int):
         oh = hit.astype(jnp.float32)
         o_ref[r:r + 1, :] += jnp.dot(ones, oh,
                                      preferred_element_type=jnp.float32)
-        va, vb, vc = _bf16_split3(v_ref[r:r + 1, :])
-        sm = (jnp.dot(va, oh, preferred_element_type=jnp.float32)
-              + jnp.dot(vb, oh, preferred_element_type=jnp.float32)
-              + jnp.dot(vc, oh, preferred_element_type=jnp.float32))
-        _kahan_add(o_ref, comp_ref, 8 + r, r, sm)
+        _pair_sum(o_ref, 8 + r, 32 + r, v_ref[r:r + 1, :], oh)
         vcol = vt[:, r:r + 1]                             # (LANE, 1)
         # typed f32 sentinel: the weak python float would promote the select
         # to f64 under the enclosing x64 program (Mosaic verifier rejects it)
@@ -129,7 +147,7 @@ def _agg_kernel(g_ref, v_ref, o_ref, comp_ref, *, ng: int):
 def _hist_kernel(g_ref, o_ref, *, ng: int):
     i = pl.program_id(0)
 
-    @pl.when(i == 0)
+    @pl.when(i % CHUNK_STEPS == 0)
     def _init():
         o_ref[:, :] = jnp.zeros_like(o_ref)
 
@@ -168,27 +186,45 @@ def _prep(codes, mask, num_groups, values=None):
     return out, rows // R_BLOCK, ng_pad
 
 
+def _launch(kernel, rows_out: int, ins, steps: int, ng_pad: int,
+            interpret: bool):
+    """Run ``kernel`` over ``steps`` blocks of the prepared inputs.  ->
+    [chunks, rows_out, ng_pad] f32: one block of output rows for each
+    ``CHUNK_STEPS`` steps, resident in VMEM while its chunk runs."""
+    chunks = -(-steps // CHUNK_STEPS)
+    out = pl.pallas_call(
+        functools.partial(kernel, ng=ng_pad),
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((R_BLOCK, LANE), lambda i: (i, 0))] * len(ins),
+        out_specs=pl.BlockSpec((rows_out, ng_pad),
+                               lambda i: (i // CHUNK_STEPS, 0)),
+        out_shape=jax.ShapeDtypeStruct((chunks * rows_out, ng_pad),
+                                       jnp.float32),
+        interpret=interpret,
+    )(*ins)
+    return out.reshape(chunks, rows_out, ng_pad)
+
+
+def _total(*rows):
+    """Rows of every chunk ([chunks, 8, ng] each) widened and added up in
+    DOUBLE: a count's eight block rows, or a sum's pairs."""
+    return sum(r.astype(jnp.float64).sum(axis=(0, 1)) for r in rows)
+
+
 @functools.partial(jax.jit, static_argnames=("num_groups", "interpret"))
 def filtered_group_sum(codes, values, mask, num_groups: int,
                        interpret: bool = False):
     """Fused filter + dense group-by COUNT/SUM.
 
-    codes: int [N]; values: [N] (contracted as f32); mask: bool [N].
-    -> (counts [num_groups] f32, sums [num_groups] f32).  Rows failing the
-    mask or with out-of-range codes drop."""
+    codes: int [N]; values: [N] (read as f32); mask: bool [N].
+    -> (counts [num_groups] f64, sums [num_groups] f64: the block rows'
+    f32 pairs added up in DOUBLE).  Rows failing the mask or with
+    out-of-range codes drop."""
     with jax.enable_x64(False):
-        (g2, v2), steps, ng_pad = _prep(codes, mask, num_groups, values)
-        out = pl.pallas_call(
-            functools.partial(_sum_kernel, ng=ng_pad),
-            grid=(steps,),
-            in_specs=[pl.BlockSpec((R_BLOCK, LANE), lambda i: (i, 0))] * 2,
-            out_specs=pl.BlockSpec((16, ng_pad), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((16, ng_pad), jnp.float32),
-            scratch_shapes=[pltpu.VMEM((8, ng_pad), jnp.float32)],
-            interpret=interpret,
-        )(g2, v2)
-    counts = out[0:8].astype(jnp.float64).sum(axis=0).astype(jnp.float32)
-    sums = out[8:16].astype(jnp.float64).sum(axis=0).astype(jnp.float32)
+        ins, steps, ng_pad = _prep(codes, mask, num_groups, values)
+        out = _launch(_sum_kernel, 24, ins, steps, ng_pad, interpret)
+    counts = _total(out[:, 0:8])
+    sums = _total(out[:, 8:16], out[:, 16:24])
     return counts[:num_groups], sums[:num_groups]
 
 
@@ -197,23 +233,15 @@ def fused_group_aggregate(codes, values, mask, num_groups: int,
                           interpret: bool = False):
     """Fused filter + dense group-by COUNT/SUM/MIN/MAX in ONE VMEM pass.
 
-    -> (counts, sums, mins, maxs) [num_groups] f32; min/max lanes of empty
-    groups hold +/-3.4e38 (count==0 marks them)."""
+    -> (counts f64, sums f64, mins f32, maxs f32) [num_groups]; min/max
+    lanes of empty groups hold +/-3.4e38 (count==0 marks them)."""
     with jax.enable_x64(False):
-        (g2, v2), steps, ng_pad = _prep(codes, mask, num_groups, values)
-        out = pl.pallas_call(
-            functools.partial(_agg_kernel, ng=ng_pad),
-            grid=(steps,),
-            in_specs=[pl.BlockSpec((R_BLOCK, LANE), lambda i: (i, 0))] * 2,
-            out_specs=pl.BlockSpec((32, ng_pad), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((32, ng_pad), jnp.float32),
-            scratch_shapes=[pltpu.VMEM((8, ng_pad), jnp.float32)],
-            interpret=interpret,
-        )(g2, v2)
-    counts = out[0:8].astype(jnp.float64).sum(axis=0).astype(jnp.float32)
-    sums = out[8:16].astype(jnp.float64).sum(axis=0).astype(jnp.float32)
-    mins = jnp.minimum(out[16:24].min(axis=0), _BIG)
-    maxs = jnp.maximum(out[24:32].max(axis=0), -_BIG)
+        ins, steps, ng_pad = _prep(codes, mask, num_groups, values)
+        out = _launch(_agg_kernel, 40, ins, steps, ng_pad, interpret)
+    counts = _total(out[:, 0:8])
+    sums = _total(out[:, 8:16], out[:, 32:40])
+    mins = jnp.minimum(out[:, 16:24].min(axis=(0, 1)), _BIG)
+    maxs = jnp.maximum(out[:, 24:32].max(axis=(0, 1)), -_BIG)
     return (counts[:num_groups], sums[:num_groups],
             mins[:num_groups], maxs[:num_groups])
 
@@ -221,17 +249,10 @@ def fused_group_aggregate(codes, values, mask, num_groups: int,
 @functools.partial(jax.jit, static_argnames=("num_partitions", "interpret"))
 def partition_histogram(dest, mask, num_partitions: int,
                         interpret: bool = False):
-    """Per-destination row counts for a hash shuffle, as one MXU pass (sizes
-    exchange capacities exactly so the repartition compiles with the right
-    cap on the FIRST attempt)."""
+    """Rows per code (a dense group-by's COUNT(*), a hash shuffle's
+    per-destination counts) as one MXU pass.  -> [num_partitions] f64,
+    whole numbers."""
     with jax.enable_x64(False):
-        (g2,), steps, ng_pad = _prep(dest, mask, num_partitions)
-        out = pl.pallas_call(
-            functools.partial(_hist_kernel, ng=ng_pad),
-            grid=(steps,),
-            in_specs=[pl.BlockSpec((R_BLOCK, LANE), lambda i: (i, 0))],
-            out_specs=pl.BlockSpec((8, ng_pad), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((8, ng_pad), jnp.float32),
-            interpret=interpret,
-        )(g2)
-    return out.astype(jnp.float64).sum(axis=0).astype(jnp.float32)[:num_partitions]
+        ins, steps, ng_pad = _prep(dest, mask, num_partitions)
+        out = _launch(_hist_kernel, 8, ins, steps, ng_pad, interpret)
+    return _total(out)[:num_partitions]

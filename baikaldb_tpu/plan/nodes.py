@@ -16,7 +16,7 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 from ..expr.ast import Expr
-from ..ops.hashagg import AggSpec
+from ..ops.hashagg import AggSpec, dense_lowering, dense_num_groups
 from ..types import Schema
 
 
@@ -158,8 +158,21 @@ class AggNode(PlanNode):
         self.strategy, self.domains, self.key_shift = self.unordered
         self.unordered = None
 
+    def lowering(self) -> str:
+        """The lowering a ``dense`` node's reductions take (select_reduce |
+        pallas | scatter): the choice its trace makes, from what the plan
+        already knows (ops/hashagg.dense_lowering); "" for a node no
+        planner gave a typed child."""
+        sch = self.children[0].schema if self.children else None
+        if self.strategy != "dense" or sch is None:
+            return ""
+        return dense_lowering(self.specs, lambda n: sch.field(n).ltype,
+                              dense_num_groups(self.domains))
+
     def _label(self):
-        s = {"dense": f"dense{self.domains}", "stream": "stream"}.get(
+        low = self.lowering()
+        s = {"dense": f"dense{self.domains}" + (f"[{low}]" if low else ""),
+             "stream": "stream"}.get(
             self.strategy, f"sorted<= {self.max_groups}")
         m = " merge=collective" if self.merge else ""
         a = f" agg_dist={self.agg_dist}" if self.agg_dist else ""

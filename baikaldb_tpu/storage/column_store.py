@@ -38,8 +38,9 @@ import pyarrow.parquet as pq
 from ..column.batch import ColumnBatch
 from ..meta.catalog import TableInfo
 from ..types import Field, LType, Schema
-from .rowstore import ConflictError, KeyCodec, RowTable, Txn
+from ..obs import trace
 from ..utils import metrics
+from .rowstore import ConflictError, KeyCodec, RowTable, Txn
 
 DEFAULT_REGION_ROWS = 1 << 20  # split threshold on the row axis
 ROWID = "__rowid"              # hidden parquet column carrying row identity
@@ -631,15 +632,21 @@ class TableStore:
             self._tso = TsoClient()
         return self._tso.next_ts()
 
-    def _mvcc_stamp_new(self, rowids, tctx) -> None:
+    def _mvcc_stamp_new(self, rowids, tctx, bulk: bool = False) -> None:
         """Stamp freshly-appended rows: PENDING inside a transaction
-        (restamped at decide time), a fresh ts for autocommit."""
+        (restamped at decide time), a fresh ts for autocommit.  A ``bulk``
+        append's rowids (``_alloc_rowids``: contiguous) are one run."""
         from ..utils.flags import FLAGS
         from .mvcc import PENDING
         if not FLAGS.mvcc:
             return
         cts = PENDING if tctx is not None else self._mvcc_ts()
-        self._mvcc.stamp(rowids, cts)
+        if bulk:
+            if len(rowids):
+                self._mvcc.stamp_range(int(rowids[0]), int(rowids[-1]) + 1,
+                                       cts)
+        else:
+            self._mvcc.stamp(rowids, cts)
         if tctx is None:
             self._mvcc_maybe_gc(cts)
 
@@ -720,9 +727,7 @@ class TableStore:
             regions = self.regions
             rowids = (np.concatenate([r.rowids for r in regions])
                       if regions else np.empty(0, dtype=np.int64))
-            lc = self._mvcc.live_cts
-            cts = np.fromiter((lc.get(int(rid), 0) for rid in rowids),
-                              dtype=np.int64, count=len(rowids))
+            cts = self._mvcc.stamps_for(rowids)
             dts = np.full(len(rowids), MAX_TS, dtype=np.int64)
             if hist:
                 htbl = pa.Table.from_pylist([h[0] for h in hist],
@@ -739,8 +744,6 @@ class TableStore:
     def column_stats(self, column: str) -> dict:
         """Host-side column statistics for planner decisions (the analog of
         the reference's statistics.proto CM-sketch/histogram feed)."""
-        import pyarrow.compute as pc
-
         with self._lock:
             v = self.version
             cache = getattr(self, "_stats_cache", None)
@@ -749,33 +752,43 @@ class TableStore:
                 self._stats_cache = cache
             if column in cache[1]:
                 return cache[1][column]
-            snap = self.snapshot()
-            col = snap.column(column)
-            st: dict = {}
-            f = self.info.schema.field(column)
-            if f.ltype is LType.STRING:
-                batch = self.device_table_batch()
-                d = batch.column(column).dictionary
-                st["dict_size"] = 0 if d is None else len(d)
-            elif snap.num_rows:
-                try:
-                    mm = pc.min_max(col).as_py()
-                    mn, mx = mm["min"], mm["max"]
-                    if hasattr(mn, "toordinal") and not hasattr(mn, "hour"):
-                        import datetime
-                        epoch = datetime.date(1970, 1, 1)
-                        mn = (mn - epoch).days
-                        mx = (mx - epoch).days
-                    if isinstance(mn, (int,)) or f.ltype.is_integer or f.ltype is LType.DATE:
-                        st["min"], st["max"] = mn, mx
-                except Exception:
-                    # stats stay partial; planner falls back to defaults
-                    metrics.count_swallowed("column_store.zone_stats")
-            st.update(self._histogram_stats(col, f) or {})
-            if f.ltype.is_integer or f.ltype is LType.DATE:
-                st["ordered"] = _non_decreasing(col)
-            cache[1][column] = st
+            with trace.timed("stats.column", column=column,
+                             rows=self.num_rows) as sp:
+                st = cache[1][column] = self._collect_stats(column)
+            metrics.column_stats_ms.add(sp.ms)
             return st
+
+    def _collect_stats(self, column: str) -> dict:
+        """A ``column_stats`` miss: one column's statistics from the
+        snapshot (caller holds the table lock)."""
+        import pyarrow.compute as pc
+
+        snap = self.snapshot()
+        col = snap.column(column)
+        st: dict = {}
+        f = self.info.schema.field(column)
+        if f.ltype is LType.STRING:
+            batch = self.device_table_batch()
+            d = batch.column(column).dictionary
+            st["dict_size"] = 0 if d is None else len(d)
+        elif snap.num_rows:
+            try:
+                mm = pc.min_max(col).as_py()
+                mn, mx = mm["min"], mm["max"]
+                if hasattr(mn, "toordinal") and not hasattr(mn, "hour"):
+                    import datetime
+                    epoch = datetime.date(1970, 1, 1)
+                    mn = (mn - epoch).days
+                    mx = (mx - epoch).days
+                if isinstance(mn, (int,)) or f.ltype.is_integer or f.ltype is LType.DATE:
+                    st["min"], st["max"] = mn, mx
+            except Exception:
+                # stats stay partial; planner falls back to defaults
+                metrics.count_swallowed("column_store.zone_stats")
+        st.update(self._histogram_stats(col, f) or {})
+        if f.ltype.is_integer or f.ltype is LType.DATE:
+            st["ordered"] = _non_decreasing(col)
+        return st
 
     def _histogram_stats(self, col, f) -> Optional[dict]:
         """Equi-depth histogram + MCVs per column version (index/stats —
@@ -1400,8 +1413,15 @@ class TableStore:
         spec = self.partition_spec()
         if spec is None:
             last = self.regions[-1]
-            last.data = pa.concat_tables([last.data, table]).combine_chunks()
-            last.rowids = np.concatenate([last.rowids, rowids])
+            if last.num_rows:
+                last.data = pa.concat_tables([last.data, table]) \
+                    .combine_chunks()
+                last.rowids = np.concatenate([last.rowids, rowids])
+            else:
+                # the first rows of an empty region: the table's own
+                # buffers, not a copy of them (a 100M-row load is 1.6 GB)
+                last.data = table.combine_chunks()
+                last.rowids = rowids
             last.version += 1
             if split:
                 self._maybe_split(last)
@@ -1455,7 +1475,7 @@ class TableStore:
             self._mutations += 1
             self._pk_stale = True
             self._append_table(table, rowids)
-            self._mvcc_stamp_new(rowids, tctx)
+            self._mvcc_stamp_new(rowids, tctx, bulk=True)
 
     def insert_rows(self, rows: list[dict], tctx: Optional[TxnContext] = None):
         """Hot insert (SQL INSERT ... VALUES): duplicate-PK checked, written
@@ -1861,21 +1881,28 @@ class TableStore:
                                        self.arrow_schema.empty_table())]
 
 
+# rows compared at a time by ``_non_decreasing``: a column out of order
+# says so within its first piece, whatever its length
+_ORDER_PIECE = 1 << 20
+
+
 def _non_decreasing(col) -> bool:
     """The ``ordered`` statistic: the column holds no NULL and never steps
     down in image order (the snapshot's: ``device_table_batch`` is the
     snapshot plus a dead tail), so a GROUP BY on it finds equal keys in
-    adjacent rows (plan/planner._streams).  One comparison pass."""
+    adjacent rows (plan/planner._streams).  One comparison pass, in pieces,
+    left at the first step down."""
     if col.null_count:
         return False
     last = None
     for chunk in col.chunks:
-        a = chunk.to_numpy(zero_copy_only=False)
-        if not len(a):
-            continue
-        if (last is not None and a[0] < last) or bool((a[1:] < a[:-1]).any()):
-            return False
-        last = a[-1]
+        whole = chunk.to_numpy(zero_copy_only=False)
+        for at in range(0, len(whole), _ORDER_PIECE):
+            a = whole[at:at + _ORDER_PIECE]
+            if (last is not None and a[0] < last) \
+                    or bool((a[1:] < a[:-1]).any()):
+                return False
+            last = a[-1]
     return True
 
 

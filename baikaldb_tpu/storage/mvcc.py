@@ -21,18 +21,21 @@ while OLTP writes keep flowing.  This module is the engine-side half:
   Arrow image, never inside it: the store's ``Region.data`` stays
   physically latest (the ``mvcc=0`` off-switch and the no-concurrent-write
   fast path are bit-identical for free).  ``live_cts`` maps rowid ->
-  commit_ts for every row written since the table's last reset whose
-  stamp GC has not settled — bulk loads included (``insert_arrow`` stamps
-  each loaded row), so a loaded table carries one entry per row until a
-  GC sweep passes its load timestamp; missing = 0 = visible to every
-  snapshot.  ``history`` holds dead versions as
+  commit_ts for every row written one at a time since the table's last
+  reset whose stamp GC has not settled; a bulk load (``insert_arrow``:
+  contiguous fresh rowids) is one entry of ``runs``, ``(first_rowid,
+  stop_rowid, commit_ts)``, whatever its size — 100 M loaded rows are one
+  tuple, not 100 M dict entries.  A row's stamp is the dict's, else its
+  run's, else 0 = visible to every snapshot.  ``history`` holds dead
+  versions as
   ``(row_values, commit_ts, delete_ts)``.  Uncommitted rows carry the
   ``PENDING`` sentinel (MAX_TS — invisible to every real snapshot) and are
   restamped with ONE decide-time commit_ts at transaction commit.  Beside
   the dict the state keeps a summary — ``high_water``, an upper bound on
-  the largest committed live stamp, and ``pending``, the rowids stamped
-  PENDING — kept at every write hook, so a pinned read learns "nothing
-  live is newer than my snapshot" in O(1) instead of walking the dict.
+  the largest committed live stamp, ``pending``, the rowids stamped
+  PENDING, and ``pending_runs``, how many runs are — kept at every write
+  hook, so a pinned read learns "nothing live is newer than my snapshot"
+  in O(1) instead of walking the stamps.
 - ``SnapshotRegistry`` — live pins (explicit ``SET SNAPSHOT`` and
   automatic analytical pins) feeding the GC watermark: nothing at or
   above the oldest unexpired pin is ever reclaimed.
@@ -50,8 +53,10 @@ from __future__ import annotations
 import threading
 import time
 import weakref
+from bisect import bisect_right
 
 import jax.numpy as jnp
+import numpy as np
 
 from ..analysis.runtime import LOCK_RANKS, GuardedLock
 from ..chaos import failpoint
@@ -83,6 +88,9 @@ mvcc_gc_reclaimed = metrics.Counter("mvcc.gc_reclaimed")
 # (TableStore.mvcc_needs_versioned / snapshot_versions) were answered
 mvcc_quiet_checks = metrics.Counter("mvcc.quiet_checks")
 mvcc_versioned_checks = metrics.Counter("mvcc.versioned_checks")
+# bulk appends recorded as one run of rowids (MvccState.stamp_range); no
+# dot in the name: a loader's SHOW STATUS check reads names up to the first
+mvcc_range_stamps = metrics.Counter("mvcc_range_stamps")
 
 #: commit_ts sentinel for uncommitted (in-transaction) rows: above every
 #: real timestamp, so no snapshot ever admits a pending version.  Rollback
@@ -184,19 +192,24 @@ class MvccState:
 
     Mutated only under the owning TableStore's table lock (the store
     passes itself in for every call) — no lock of its own, so it adds
-    nothing to the lock order.  ``live_cts``: rowid -> commit_ts for
-    every row stamped since the last reset — autocommit DML, committed
-    transactions AND bulk loads (one entry per loaded row) — until a GC
-    sweep settles it (missing = 0: visible to every snapshot —
-    truncate-reset state and stamps GC already settled).
+    nothing to the lock order.  A live row's stamp is kept in one of two
+    places, read through :meth:`stamp_of` / :meth:`stamps_for`:
+    ``live_cts``, rowid -> commit_ts for every row stamped one at a time
+    since the last reset (autocommit DML, committed transactions), and
+    ``runs``, ``(first_rowid, stop_rowid, commit_ts)`` in rowid order,
+    one for each bulk append (``stamp_range``) whatever its size.  The
+    dict wins over a run (an UPDATE of a loaded row restamps that row);
+    a rowid in neither reads 0: visible to every snapshot —
+    truncate-reset state and stamps GC already settled.
     ``history``: dead versions as ``(row_values, commit_ts, delete_ts)``
     dicts in arrival order; a GC sweep drops entries whose delete_ts is at
     or below the watermark.
 
-    The summary of ``live_cts``, kept by every write hook so the pinned
-    read's "has the live image moved past my snapshot?" never walks it:
-    ``high_water`` is an upper bound on the largest non-PENDING stamp in
-    the dict, ``pending`` the rowids stamped PENDING.  ``pending`` is
+    The summary of the live stamps, kept by every write hook so the pinned
+    read's "has the live image moved past my snapshot?" never walks them:
+    ``high_water`` is an upper bound on the largest non-PENDING stamp,
+    ``pending`` the rowids of the dict stamped PENDING and
+    ``pending_runs`` the number of runs that are.  The last two are
     exact.  ``high_water`` errs to one side only: ``record_dead`` may pop
     the row that held the maximum and the mark stays (finding the next
     one would be the walk again), so it can send a read to the versioned
@@ -205,14 +218,16 @@ class MvccState:
     which makes it exact again.
     """
 
-    __slots__ = ("live_cts", "history", "high_water", "pending",
-                 "reclaimed", "__weakref__")
+    __slots__ = ("live_cts", "runs", "history", "high_water", "pending",
+                 "pending_runs", "reclaimed", "__weakref__")
 
     def __init__(self):
         self.live_cts: dict[int, int] = {}
+        self.runs: list[tuple[int, int, int]] = []
         self.history: list[tuple[dict, int, int]] = []
         self.high_water = 0
         self.pending: set[int] = set()
+        self.pending_runs = 0
         self.reclaimed = 0      # history versions GC has dropped, ever
         _STATES.add(self)
 
@@ -234,6 +249,57 @@ class MvccState:
         if rid is not None and cts > self.high_water:
             self.high_water = cts
 
+    def stamp_range(self, first: int, stop: int, cts: int) -> None:
+        """Stamp the fresh contiguous rowids ``[first, stop)`` of one bulk
+        append: one run, no per-row object."""
+        if stop <= first:
+            return
+        run = (int(first), int(stop), cts)
+        # rowids are handed out in rising order: the end, as a rule
+        self.runs.insert(bisect_right(self.runs, run[0], key=_first), run)
+        if cts == PENDING:
+            self.pending_runs += 1
+        elif cts > self.high_water:
+            self.high_water = cts
+        mvcc_range_stamps.add(1)
+
+    def _run_stamp(self, rid: int) -> int:
+        """The stamp of the run that holds ``rid``, else 0."""
+        i = bisect_right(self.runs, rid, key=_first) - 1
+        return self.runs[i][2] if i >= 0 and rid < self.runs[i][1] else 0
+
+    def stamp_of(self, rid: int) -> int:
+        """A live row's commit stamp: the dict's, else its run's, else 0."""
+        cts = self.live_cts.get(rid)
+        return self._run_stamp(rid) if cts is None else cts
+
+    def stamps_for(self, rowids: np.ndarray) -> np.ndarray:
+        """:meth:`stamp_of` over an int64 array of live rowids, without a
+        Python step a row: a search of the run starts, then the dict's
+        entries laid over by a search of its sorted keys."""
+        cts = np.zeros(len(rowids), np.int64)
+        if self.runs:
+            first, stop, stamp = (np.array(c, np.int64)
+                                  for c in zip(*self.runs))
+            i = np.searchsorted(first, rowids, side="right") - 1
+            hit = (i >= 0) & (rowids < stop[np.maximum(i, 0)])
+            cts[hit] = stamp[i[hit]]
+        lc = self.live_cts
+        if lc:
+            keys = np.fromiter(lc.keys(), np.int64, count=len(lc))
+            vals = np.fromiter(lc.values(), np.int64, count=len(lc))
+            order = np.argsort(keys, kind="stable")
+            keys, vals = keys[order], vals[order]
+            i = np.minimum(np.searchsorted(keys, rowids), len(keys) - 1)
+            hit = keys[i] == rowids
+            cts[hit] = vals[i[hit]]
+        return cts
+
+    def live_stamps(self) -> int:
+        """Rows that carry a stamp GC has not settled (a row restamped
+        over its run counts twice)."""
+        return len(self.live_cts) + sum(r[1] - r[0] for r in self.runs)
+
     def record_dead(self, rows: list[dict], rowids, dts: int) -> None:
         """Old versions of deleted/updated rows enter history."""
         lc = self.live_cts
@@ -241,8 +307,10 @@ class MvccState:
         pend = self.pending
         for row, rid in zip(rows, rowids):
             rid = int(rid)
-            cts = lc.pop(rid, 0)
-            if cts == PENDING:
+            cts = lc.pop(rid, None)
+            if cts is None:
+                cts = self._run_stamp(rid)
+            elif cts == PENDING:
                 pend.discard(rid)
             hist.append((row, cts, dts))
 
@@ -257,8 +325,14 @@ class MvccState:
             for rid in self.pending:
                 lc[rid] = commit_ts
             self.pending.clear()
-            if commit_ts > self.high_water:
-                self.high_water = commit_ts
+        if self.pending_runs:
+            for i, (first, stop, c) in enumerate(self.runs):
+                if c == PENDING:
+                    self.runs[i] = (first, stop, commit_ts)
+                    n += stop - first
+            self.pending_runs = 0
+        if n and commit_ts > self.high_water:
+            self.high_water = commit_ts
         for i, (row, c, d) in enumerate(self.history):
             if d == PENDING:
                 self.history[i] = (row, c, commit_ts)
@@ -272,23 +346,28 @@ class MvccState:
         # open drops older entries (never the transaction's own, whose
         # delete_ts is PENDING) and would shift a plain length
         return (dict(self.live_cts), self.reclaimed + len(self.history),
-                self.high_water, set(self.pending))
+                self.high_water, set(self.pending), list(self.runs),
+                self.pending_runs)
 
     def restore(self, pre: tuple) -> None:
-        live, hist_mark, high_water, pending = pre
+        live, hist_mark, high_water, pending, runs, pending_runs = pre
         self.live_cts = dict(live)
         del self.history[max(hist_mark - self.reclaimed, 0):]
         self.high_water = high_water
         self.pending = set(pending)
+        self.runs = list(runs)
+        self.pending_runs = pending_runs
 
     def reset(self) -> None:
         """Table image replaced wholesale (truncate / load / DDL rebuild):
         all prior stamps and versions are meaningless."""
         self.live_cts.clear()
+        self.runs.clear()
         self.reclaimed += len(self.history)
         self.history.clear()
         self.high_water = 0
         self.pending.clear()
+        self.pending_runs = 0
 
     # -- read-path helpers ----------------------------------------------
     def versions_at(self, snap_ts: int) -> list[tuple[dict, int, int]]:
@@ -300,7 +379,8 @@ class MvccState:
         after the snapshot, or an open transaction's PENDING row)?  O(1),
         from the summary: False is exact, True may be the popped-maximum
         upper bound (class docstring)."""
-        return bool(self.pending) or self.high_water > snap_ts
+        return bool(self.pending) or bool(self.pending_runs) \
+            or self.high_water > snap_ts
 
     def gc(self, watermark: int) -> int:
         """Drop history below the watermark and settle old live stamps.
@@ -310,7 +390,8 @@ class MvccState:
         lower-bounds every current and future pin, so nothing pinned can
         still see it.  A live stamp at or below the watermark degrades to
         the implicit 0 (visible to everything that can still pin) and
-        leaves the dict.  Returns reclaimed version count.
+        leaves the dict; a run at or below it is dropped whole.  Returns
+        reclaimed version count.
         """
         if failpoint.ENABLED and failpoint.hit("mvcc.gc",
                                                watermark=watermark):
@@ -322,6 +403,8 @@ class MvccState:
                    if c <= watermark]
         for rid in settled:
             del self.live_cts[rid]
+        if self.runs:
+            self.runs = [r for r in self.runs if r[2] > watermark]
         if self.high_water <= watermark:
             self.high_water = 0     # every committed stamp just settled
         reclaimed = before - len(self.history)
@@ -329,6 +412,10 @@ class MvccState:
         if reclaimed:
             mvcc_gc_reclaimed.add(reclaimed)
         return reclaimed
+
+
+def _first(run: tuple) -> int:
+    return run[0]
 
 
 class SnapshotRegistry:
@@ -480,7 +567,7 @@ _REGISTRIES: "weakref.WeakSet[SnapshotRegistry]" = weakref.WeakSet()
 
 
 def _live_versions() -> int:
-    return sum(len(s.history) + len(s.live_cts) for s in list(_STATES))
+    return sum(len(s.history) + s.live_stamps() for s in list(_STATES))
 
 
 def _oldest_pin() -> int:

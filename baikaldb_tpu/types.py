@@ -119,8 +119,13 @@ def promote(a: LType, b: LType) -> LType:
     if (a.is_numeric and b.is_numeric) or a.is_temporal or b.is_temporal:
         ra, rb = _RANK[a], _RANK[b]
         hi = a if ra >= rb else b
-        # mixed signed/float handling: any float wins as FLOAT64
-        if (a.is_float or b.is_float) and not hi.is_float:
+        # a float against a non-float is DOUBLE, as MySQL has it (FLOAT
+        # arithmetic is done in double precision): ``f * 2 + 1 > x`` over a
+        # FLOAT column keeps the rows a float64 evaluation keeps, where a
+        # float32 one rounds ~1 row in 1e8 across the boundary (PR 35; the
+        # test below this one was written as ``not hi.is_float``, which no
+        # pair of ranks can meet)
+        if a.is_float != b.is_float and a.is_numeric and b.is_numeric:
             return LType.FLOAT64
         if hi.is_temporal:
             return LType.INT64 if hi is not LType.DATE else LType.INT32

@@ -554,6 +554,10 @@ point_lookups = Counter("point_lookups")
 # query_log row can carry them — point.lookup (the row tier answers and no
 # row is written) and wire.result_set (encode + socket write, after the row)
 point_lookup_ms = Counter("point_lookup_ms")
+# ms, not a count: the wall time of stats.column, a TableStore.column_stats
+# miss (min/max, histogram sample, HLL, the ordered pass: once a column a
+# table version, on the thread of the statement that first plans over it)
+column_stats_ms = Counter("column_stats_ms")
 wire_result_set_ms = Counter("wire_result_set_ms")
 # the MySQL framing (server/mysql_server.Packets, both ends of the wire in
 # a process that holds server and clients): packets framed, sendall calls
@@ -689,6 +693,15 @@ join_cap_slots = Counter("join_cap_slots")
 # strategy) and whose own check of that order passed; stream_agg_fallbacks
 # +1 when the check failed: that node was traced again as a scatter or a sort
 stream_agg_runs = Counter("stream_agg_runs")
+# +1 for each dense GROUP BY an execution of a compiled plan ran, by the
+# lowering its program was traced with (ops/hashagg.dense_lowering: the
+# fused select+reduce, the Pallas one-hot kernels, the segment scatter);
+# the choice is recorded with the program at trace time (exec/executor.
+# compile_plan, an AOT artifact's ``extra``) and counted where an execution
+# settles.  A streamed fold's per-chunk aggregate counts in none
+agg_select_reduce_runs = Counter("agg_select_reduce_runs")
+agg_pallas_runs = Counter("agg_pallas_runs")
+agg_scatter_runs = Counter("agg_scatter_runs")
 stream_agg_fallbacks = Counter("stream_agg_fallbacks")
 join_live_rows = Counter("join_live_rows")
 aot_publish_ms = Counter("aot_publish_ms")

@@ -14,6 +14,11 @@ setting: where the compile cache lives is utils/compilecache.enable's call.
 The one jax option it touches is ``jax_dump_ir_to``, around the Pallas
 GROUP BYs: the modules jax hands the compiler there land in
 ``chiprun_out/chip_smoke_ir/`` and must call the Mosaic kernels by name.
+This is the bring-up record.  Where the north-star table is *measured* is
+the benchmark's cell ``tpu_northstar_100m.groupby`` (PR 35: the same table
+and statement at 16 / 1,000 / 4,000 groups, resident, every answer held to
+float64 at 1e-10; ``PERF.md`` sections 4-6); this file's phase stays as it
+was, with its own looser tolerances.
 Its limit is 1,200 s and most of it is first compiles (Q18's two join-cap
 recompiles alone are 210 s; CHANGES.md PR 21 has the readings): trim before
 adding, and name every cut in the output as the "cut for the time limit"

@@ -150,3 +150,28 @@ def test_concurrent_backfill_and_dml_lose_nothing():
     assert db.stores["default.__gidx__b__g"].num_rows == n
     with pytest.raises(ConflictError):
         s.execute("INSERT INTO b VALUES (99999, 'k3')")
+
+
+def test_sorted_index_read_of_no_rows_answers_no_rows():
+    """What made the readers test above fail once in a while (PR 35 found
+    it): a reader that met the table empty — before the writer's first
+    INSERT, or after its DELETE of row 0 — ran ORDER BY over an index
+    gather of zero lanes, and ``ops/compact.stable_partition`` indexed the
+    last element of an empty prefix sum."""
+    import jax.numpy as jnp
+
+    from baikaldb_tpu.ops.compact import stable_partition
+
+    assert stable_partition(jnp.zeros(0, bool)).shape == (0,)
+    db = Database()
+    s = Session(db)
+    s.execute("CREATE TABLE t (id BIGINT, v BIGINT, tag VARCHAR(16), "
+              "PRIMARY KEY (id), GLOBAL INDEX g (tag)) ")
+    q = "SELECT id FROM t WHERE tag = 'w3' ORDER BY id"
+    assert s.query(q) == []
+    s.execute("INSERT INTO t VALUES (0, 0, 'w0')")
+    s.execute("DELETE FROM t WHERE id = 0")
+    assert s.query(q) == []
+    s.execute("INSERT INTO t VALUES (3, 3, 'w3')")
+    assert s.query(q) == [{"id": 3}]
+    assert s.query("SELECT id FROM t WHERE tag = 'w0' ORDER BY id") == []

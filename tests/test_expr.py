@@ -211,3 +211,41 @@ def test_infer_cast_type():
     t = pa.table({"x": pa.array([1], type=pa.int64())})
     b = ColumnBatch.from_arrow(t)
     assert infer_type(call("cast", col("x"), lit(LType.FLOAT64)), b.schema()) == LType.FLOAT64
+
+
+@pytest.mark.parametrize("other, want", [
+    (LType.INT64, LType.FLOAT64), (LType.INT32, LType.FLOAT64),
+    (LType.BOOL, LType.FLOAT64), (LType.FLOAT64, LType.FLOAT64),
+    (LType.FLOAT32, LType.FLOAT32)])
+def test_float_against_a_non_float_is_double(other, want):
+    """MySQL does FLOAT arithmetic in double precision: a FLOAT against an
+    integer promotes to DOUBLE, either way round (PR 35; a FLOAT against a
+    FLOAT stays one)."""
+    from baikaldb_tpu.types import promote
+
+    assert promote(LType.FLOAT32, other) is want
+    assert promote(other, LType.FLOAT32) is want
+    assert promote(LType.FLOAT64, other) is LType.FLOAT64
+
+
+def test_float_column_filter_keeps_the_rows_float64_keeps():
+    """``v*2+1 > x`` over a FLOAT column: the value next above the boundary
+    passes, as it does in float64; in float32 the sum rounds onto x.  One
+    such row in 1e8 was BASELINE's filter's whole disagreement with a
+    float64 reference on the chip (PERF.md section 6, PR 35)."""
+    edge = np.float32(-0.25)
+    v = np.array([edge, np.nextafter(edge, np.float32(0)),
+                  np.nextafter(edge, np.float32(-1)), 2.0 ** -30, -2.0 ** -30,
+                  0.0], np.float32)
+    b = ColumnBatch.from_arrow(pa.table({"v": v}))
+    e = call("add", call("mul", col("v"), lit(2)), lit(1))
+    assert infer_type(e, b.schema()) is LType.FLOAT64
+    got = np.asarray(eval_expr(e, b).data)
+    assert got.dtype == np.float64
+    assert np.array_equal(got, v.astype(np.float64) * 2 + 1)
+    for x in (0.5, 1.0):
+        keep = np.asarray(eval_predicate(call("gt", e, lit(x)), b))
+        assert np.array_equal(keep[:len(v)],
+                              v.astype(np.float64) * 2 + 1 > x)
+    assert not np.array_equal(v * np.float32(2) + np.float32(1) > 0.5,
+                              v.astype(np.float64) * 2 + 1 > 0.5)

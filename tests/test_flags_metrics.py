@@ -193,13 +193,13 @@ def test_pallas_dense_groupby_integration(monkeypatch):
         functools.partial(pallas_kernels.filtered_group_sum.__wrapped__,
                           interpret=True))
     used = {}
-    real = hashagg._pallas_dense_cols
+    real = hashagg.dense_lowering       # the one decision (PR 35)
 
     def spy(*a, **k):
         r = real(*a, **k)
-        used["pallas"] = r is not None
+        used["pallas"] = r == "pallas"
         return r
-    monkeypatch.setattr(hashagg, "_pallas_dense_cols", spy)
+    monkeypatch.setattr(hashagg, "dense_lowering", spy)
 
     out = group_aggregate_dense(batch, ["g"], [ng], specs)
     assert used["pallas"] is True

@@ -362,15 +362,21 @@ def test_explain_analyze_snapshot_line():
 # every live stamp; MvccState now answers from a summary kept at the write
 # hooks.  The walk stays HERE, as the plain reference the summary is held to.
 
+def _walk_stamps(mv: MvccState) -> list:
+    """Every live stamp: the dict's and, since a bulk load is one run
+    (PR 35), each run's."""
+    return list(mv.live_cts.values()) + [r[2] for r in mv.runs]
+
+
 def _walk_diverged(mv: MvccState, snap: int) -> bool:
     """The pre-summary answer: history alive at snap, or any live stamp
     above it (PENDING is MAX_TS, so an open transaction counts)."""
     return bool(mv.versions_at(snap)) or \
-        any(c > snap for c in mv.live_cts.values())
+        any(c > snap for c in _walk_stamps(mv))
 
 
 def _walk_max(mv: MvccState) -> int:
-    return max((c for c in mv.live_cts.values() if c != PENDING), default=0)
+    return max((c for c in _walk_stamps(mv) if c != PENDING), default=0)
 
 
 def _check_summary(store, stamps, rng) -> None:
@@ -599,7 +605,8 @@ def test_quiet_check_never_walks_live_stamps():
         "g": pa.array([i % 2 for i in ids], pa.int64()),
         "v": pa.array(ids, pa.int64())}))
     mv = store._mvcc
-    assert len(mv.live_cts) == 8 + len(ids)     # loads stamp every row
+    # a load stamps every row, as one run beside the eight INSERTs' entries
+    assert len(mv.live_cts) == 8 and mv.live_stamps() == 8 + len(ids)
     snap = db.mvcc.now_ts()
     mv.live_cts = _NoWalk(mv.live_cts)
     with pytest.raises(AssertionError):

@@ -76,3 +76,39 @@ def test_partition_histogram_interpret():
                                        p, interpret=True))
     want = np.bincount(dest[mask], minlength=p)
     assert np.array_equal(h.astype(np.int64), want)
+
+
+@pytest.mark.parametrize("chunk_steps, n", [(2048, 120_000), (16, 120_001),
+                                            (7, 50_003)])
+@pytest.mark.parametrize("kernel", ["sum", "fused"])
+def test_float_sums_leave_the_kernel_as_f32_pairs(monkeypatch, kernel,
+                                                  chunk_steps, n):
+    """SUM over a FLOAT column is DOUBLE: each block row's running sum is
+    an f32 pair (an error-free TwoSum and its error row), both outputs, one
+    block of rows a chunk of grid steps, widened and added up outside the
+    kernel (PR 35).  Values chosen so that a float32 running sum loses
+    seven digits: the pairs keep twelve, over one chunk, over several with
+    a ragged last one, and with the accumulators started afresh often."""
+    from baikaldb_tpu.ops import pallas_kernels
+    from baikaldb_tpu.ops.pallas_kernels import fused_group_aggregate
+
+    monkeypatch.setattr(pallas_kernels, "CHUNK_STEPS", chunk_steps)
+    rng = np.random.default_rng(35)
+    ng = 29
+    codes = rng.integers(0, ng, n).astype(np.int32)
+    vals = (rng.standard_normal(n) * 3e3 + 1e4).astype(np.float32)
+    mask = rng.random(n) < 0.8
+    f = filtered_group_sum if kernel == "sum" else fused_group_aggregate
+    c, s = f(jnp.asarray(codes), jnp.asarray(vals), jnp.asarray(mask), ng,
+             interpret=True)[:2]
+    assert c.dtype == jnp.float64 and s.dtype == jnp.float64
+    want_c = np.bincount(codes[mask], minlength=ng)
+    want_s = np.bincount(codes[mask], weights=vals[mask].astype(np.float64),
+                         minlength=ng)
+    assert np.array_equal(np.asarray(c), want_c)
+    gap = np.abs(np.asarray(s) - want_s) / np.abs(want_s)
+    assert gap.max() < 1e-12, gap.max()
+    # the control: the same adds in one float32 a group
+    low = np.zeros(ng, np.float32)
+    np.add.at(low, codes[mask], vals[mask])
+    assert (np.abs(low - want_s) / np.abs(want_s)).max() > 1e-8

@@ -78,3 +78,86 @@ def test_skewed_predicate_flips_join_order():
     # histograms: b survives at 90%, c at 5% -> c joins first
     assert pb0 < pc0, without
     assert pc1 < pb1, with_stats
+
+
+# ---- HLL in cache-sized pieces (PR 35) vs the whole-array reduction --------
+
+def _hll_ndv_whole_array(values: np.ndarray, p: int = 12) -> int:
+    """``index/stats.hll_ndv`` as it was before PR 35, kept here as the
+    reference: every step over the whole array at once, ``np.frexp`` for
+    the bit length, one ``np.maximum.at`` for the registers."""
+    v = np.ascontiguousarray(values)
+    if v.dtype.kind == "f":
+        v = v + 0.0
+        v = v.view(np.uint64 if v.dtype.itemsize == 8
+                   else np.uint32).astype(np.uint64)
+    else:
+        v = v.astype(np.int64).view(np.uint64)
+    with np.errstate(over="ignore"):
+        h = v * np.uint64(0x9E3779B97F4A7C15)
+        h ^= h >> np.uint64(29)
+        h *= np.uint64(0xBF58476D1CE4E5B9)
+        h ^= h >> np.uint64(32)
+    m = 1 << p
+    idx = (h >> np.uint64(64 - p)).astype(np.int64)
+    nz = 64 - p
+    rem = h & np.uint64((1 << nz) - 1)
+    _, exp = np.frexp(rem.astype(np.float64))
+    rho = np.where(rem == 0, nz + 1, nz - exp + 1).astype(np.int64)
+    reg = np.zeros(m, np.int64)
+    np.maximum.at(reg, idx, rho)
+    alpha = 0.7213 / (1.0 + 1.079 / m)
+    est = alpha * m * m / np.sum(np.exp2(-reg.astype(np.float64)))
+    zeros = int((reg == 0).sum())
+    if est <= 2.5 * m and zeros:
+        est = m * np.log(m / zeros)
+    return max(1, int(round(est)))
+
+
+@pytest.mark.parametrize("seed", [0, 35, 2**31 + 35])
+@pytest.mark.parametrize("kind", ["int32_16", "int32_4000", "int64_wide",
+                                  "float32", "float64", "bool", "zeros"])
+def test_hll_in_pieces_gives_the_whole_array_estimate(kind, seed):
+    """Same registers, same estimate, bit for bit: over several pieces
+    with a ragged tail, signed zeros, and a length under one piece."""
+    from baikaldb_tpu.index import stats
+
+    rng = np.random.default_rng(seed)
+    n = 3 * stats._HLL_PIECE + 1234
+    values = {
+        "int32_16": lambda: rng.integers(0, 16, n, dtype=np.int32),
+        "int32_4000": lambda: rng.integers(0, 4000, n, dtype=np.int32),
+        "int64_wide": lambda: rng.integers(-2**62, 2**62, n),
+        "float32": lambda: rng.standard_normal(n, dtype=np.float32),
+        "float64": lambda: np.where(rng.random(n) < .1, -0.0,
+                                    rng.standard_normal(n)),
+        "bool": lambda: rng.random(n) < .5,
+        "zeros": lambda: np.zeros(n, np.int64),
+    }[kind]()
+    for arr in (values, values[:1000], values[:stats._HLL_PIECE],
+                values[:0]):
+        assert stats.hll_ndv(arr) == _hll_ndv_whole_array(arr)
+    assert stats.hll_ndv(np.array(["a", "b"], dtype=object)) is None
+    assert stats.hll_ndv(np.ones(4, np.float16)) is None
+
+
+def test_column_stats_miss_is_a_span_and_a_counter():
+    """A first touch feeds ``column_stats_ms`` and leaves a ``stats.column``
+    span in the statement's log row; a second touch is a hit and feeds
+    neither."""
+    from baikaldb_tpu.utils import metrics
+
+    s = Session()
+    s.execute("CREATE TABLE ns (g INT, v FLOAT)")
+    s.execute("INSERT INTO ns VALUES (1, 0.5), (2, 1.5), (1, 2.5)")
+    before = metrics.column_stats_ms.value
+    s.db.query_log.clear()
+    assert len(s.query("SELECT g, SUM(v) s FROM ns GROUP BY g")) == 2
+    grew = metrics.column_stats_ms.value - before
+    assert grew > 0
+    assert s.db.query_log[-1][5].get("stats.column", 0) > 0
+    assert s.query("SELECT g, SUM(v) s FROM ns GROUP BY g")
+    assert metrics.column_stats_ms.value - before == grew
+    assert "stats.column" not in s.db.query_log[-1][5]
+    store = [v for k, v in s.db.stores.items() if k.endswith(".ns")][0]
+    assert store.column_stats("g")["ordered"] is False
