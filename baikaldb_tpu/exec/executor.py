@@ -131,12 +131,14 @@ class AotRawShim:
         extra = extra or {}
         self.exchange_bytes = [int(extra.get("exchange_bytes", 0))]
         self.agg_lowerings = list(extra.get("agg_lowerings", ()))
+        self.agg_count_passes = [int(extra.get("agg_count_passes", 0))]
 
 
 def traced_extra(raw, mesh: bool) -> dict:
     """What a program's trace recorded beside its flags, as an AOT artifact
     carries it (``extra``) and :class:`AotRawShim` reads it back."""
-    extra: dict = {"agg_lowerings": tuple(raw.agg_lowerings)}
+    extra: dict = {"agg_lowerings": tuple(raw.agg_lowerings),
+                   "agg_count_passes": raw.agg_count_passes[0]}
     if mesh:
         extra["exchange_bytes"] = raw.exchange_bytes[0]
     return extra
@@ -144,14 +146,17 @@ def traced_extra(raw, mesh: bool) -> dict:
 
 def count_lowerings(raw) -> None:
     """One execution of ``raw``'s program: +1 on ``agg_<lowering>_runs`` for
-    each dense aggregate in it."""
+    each dense aggregate in it, and on ``agg_count_passes`` the passes they
+    were traced with only to count rows."""
     for lowering in raw.agg_lowerings:
-        _LOWERING_RUNS[lowering].add(1)
+        _AGG_COUNTERS[lowering].add(1)
+    _AGG_COUNTERS["count_passes"].add(raw.agg_count_passes[0])
 
 
-_LOWERING_RUNS = {"select_reduce": metrics.agg_select_reduce_runs,
-                  "pallas": metrics.agg_pallas_runs,
-                  "scatter": metrics.agg_scatter_runs}
+_AGG_COUNTERS = {"select_reduce": metrics.agg_select_reduce_runs,
+                 "pallas": metrics.agg_pallas_runs,
+                 "scatter": metrics.agg_scatter_runs,
+                 "count_passes": metrics.agg_count_passes}
 
 
 class _CapBox:
@@ -196,8 +201,10 @@ def compile_plan(plan: PlanNode, trace: bool = False, mesh=None) -> Callable:
     # static shapes, so tallied once per trace like join_order
     exchange_bytes = [0]
     # the lowering each dense aggregate of the program was traced with
-    # (ops/hashagg.dense_lowering), in trace order: tallied like the above
+    # (ops/hashagg.dense_lowering), in trace order, and the passes over
+    # their input they make only to count rows: tallied like the above
     agg_lowerings: list = []
+    agg_count_passes = [0]
 
     def run_local(batches: dict):
         if not getattr(ACCOUNTING_TRACE, "active", False):
@@ -214,7 +221,8 @@ def compile_plan(plan: PlanNode, trace: bool = False, mesh=None) -> Callable:
         with bind_params(batches.get(PARAMS_KEY, ())), \
                 noting_lowerings() as lowered:
             out = _sub(plan, batches, overflows, ctx)
-        agg_lowerings[:] = lowered
+        agg_lowerings[:] = [lowering for lowering, _ in lowered]
+        agg_count_passes[0] = sum(passes for _, passes in lowered)
         # nodes are host objects: expose them on the closure (filled at trace
         # time), return only the traced flags
         join_order.clear()
@@ -257,6 +265,7 @@ def compile_plan(plan: PlanNode, trace: bool = False, mesh=None) -> Callable:
     run.trace_count = trace_count
     run.exchange_bytes = exchange_bytes
     run.agg_lowerings = agg_lowerings
+    run.agg_count_passes = agg_count_passes
     return run
 
 

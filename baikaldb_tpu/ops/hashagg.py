@@ -144,7 +144,8 @@ def _scalar_one(batch: ColumnBatch, s: AggSpec, sel) -> Column:
         return Column(_hll_estimate(regs)[:1], None, LType.INT64)
     if s.op == "percentile":
         gid = jnp.where(live, 0, 1)
-        v, ok = _segment_percentile(c, gid, 1, s.param)
+        v, ok = _segment_percentile(c, gid, 1, s.param,
+                                    jnp.sum(live, dtype=jnp.int32)[None])
         return Column(v, ok, LType.FLOAT64)
     raise ValueError(f"unknown aggregate {s.op}")
 
@@ -183,18 +184,16 @@ def _hll_estimate(regs):
     return jnp.round(est).astype(jnp.int64)
 
 
-def _segment_percentile(c: Column, gid_v, ng: int, p: float):
+def _segment_percentile(c: Column, gid_v, ng: int, p: float, counts):
     """Exact percentile per group: sort by (group, value), index into each
     group's run with linear interpolation (PERCENTILE_CONT semantics).  The
     reference approximates with t-digest (src/common/tdigest.cpp) because
-    CPU sorts are expensive; on TPU the sort IS the cheap primitive."""
+    CPU sorts are expensive; on TPU the sort IS the cheap primitive.
+    ``counts``: the rows of each group (``gid_v < ng``), int32 [ng]."""
     x = c.data.astype(jnp.float64)
     order = argsort(x)
     order = order[argsort(gid_v[order])]
-    g = gid_v[order]
     v = x[order]
-    counts = seg_sum(jnp.ones_like(gid_v, jnp.int32), gid_v,
-                                 num_segments=ng + 1)[:ng]
     starts = jnp.cumsum(counts) - counts
     tpos = starts.astype(jnp.float64) + p * jnp.maximum(counts - 1, 0)
     lo = jnp.floor(tpos).astype(jnp.int32)
@@ -254,14 +253,26 @@ def group_aggregate_dense(batch: ColumnBatch, key_names: list[str],
                           domains: list[int], specs: list[AggSpec]) -> ColumnBatch:
     """GROUP BY over dense-coded keys: one segment reduction per aggregate.
 
-    Output capacity = prod(domain+1); absent groups are masked via sel."""
+    Output capacity = prod(domain+1); absent groups are masked via sel.
+
+    The rows of each group are counted once, in the arm that runs, and
+    ``present`` (rows > 0), ``COUNT(*)`` (rows) and the count of every
+    column without a validity array (its live lanes are the selected ones)
+    are read off that one vector; a nullable column is counted once more,
+    by name.  On the segment arms the vector is one ``seg_sum`` of int32
+    ones (:func:`_group_rows`); on the Pallas arm it is the ``cnt`` a fused
+    kernel already returns for a column without NULLs, else one
+    ``partition_histogram``.  At 2^27 lanes on the v5e (PR 35's ledger
+    line, this PR's traces) a count of its own costs: the int32 scatter of
+    ones past ONEHOT_MAX_SEGMENTS 0.9-1.0 s, the histogram 0.13 s at 1,000
+    groups and 0.25 s at 4,000, a select+reduce lane ~5.5 ms at 16 groups:
+    COUNT(*), SUM, AVG, MIN of a FLOAT column made five such passes and
+    makes one (none on the Pallas arm).  ``noting_lowerings`` is told how
+    many were traced: ``agg_count_passes``."""
     key_cols = [batch.column(k) for k in key_names]
     ng = dense_num_groups(domains)
     gid = combined_dense_id(key_cols, domains)
     sel = batch.sel_mask()
-    gid_live = jnp.where(sel, gid, ng)  # dead rows -> overflow bucket
-    present = seg_sum(jnp.ones_like(gid_live, dtype=jnp.int32), gid_live,
-                      num_segments=ng + 1)[:ng] > 0
     # reconstruct key columns from slot index
     out_names, out_cols = [], []
     slot = jnp.arange(ng, dtype=jnp.int32)
@@ -279,16 +290,22 @@ def group_aggregate_dense(batch: ColumnBatch, key_names: list[str],
         out_names.append(name)
         out_cols.append(Column(code.astype(c.data.dtype), validity, c.ltype, c.dictionary))
     lowering = dense_lowering(specs, lambda n: batch.column(n).ltype, ng)
-    noted = _NOTED.get()
-    if noted is not None:
-        noted.append(lowering)
     out_names.extend(s.out_name for s in specs)
     if lowering == "pallas":
-        out_cols.extend(_pallas_dense_cols(batch, specs, gid, ng, sel))
+        rows, cols, count_passes = _pallas_dense_cols(batch, specs, gid, ng,
+                                                      sel)
     else:
-        out_cols.extend(_segment_one(batch, s, gid_live, ng, sel)
-                        for s in specs)
-    return ColumnBatch(tuple(out_names), out_cols, present, None)
+        gid_live = jnp.where(sel, gid, ng)  # dead rows -> overflow bucket
+        counts: dict = {}
+        cols = [_segment_one(batch, s, gid_live, ng, sel, counts)
+                for s in specs]
+        rows = _group_rows(counts, None, gid_live, ng)
+        count_passes = len(counts)
+    out_cols.extend(cols)
+    noted = _NOTED.get()
+    if noted is not None:
+        noted.append((lowering, count_passes))
+    return ColumnBatch(tuple(out_names), out_cols, rows > 0, None)
 
 
 # the three lowerings of a dense aggregate's reductions, as EXPLAIN names
@@ -300,9 +317,11 @@ _NOTED: ContextVar = ContextVar("dense_lowerings", default=None)
 @contextmanager
 def noting_lowerings():
     """While a program is traced under this, every ``group_aggregate_dense``
-    appends the lowering it chose to the list yielded: the trace-time
-    choice, kept with the compiled program by the tracer (the executor's
-    ``compile_plan``) and counted once an execution."""
+    appends ``(lowering, count_passes)`` to the list yielded — the lowering
+    it chose and the passes over its input lanes it traced only to count
+    rows: the trace-time facts, kept with the compiled program by the
+    tracer (the executor's ``compile_plan``) and counted once an
+    execution."""
     noted: list = []
     token = _NOTED.set(noted)
     try:
@@ -349,32 +368,45 @@ def dense_lowering(specs: list, ltype_of: Callable, ng: int) -> str:
 
 
 def _pallas_dense_cols(batch, specs, gid, ng: int, sel):
-    """The aggregate Columns (spec order) of a dense group-by that
-    :func:`dense_lowering` put on the Pallas MXU kernels."""
+    """A dense group-by that :func:`dense_lowering` put on the Pallas MXU
+    kernels.  -> (rows per group [ng] as whole-number f64, the aggregate
+    Columns in spec order, the passes made only to count rows: 0 or 1).
+
+    Every value column goes through one fused kernel, whose ``cnt`` counts
+    the selected lanes that hold a value.  For a column without a validity
+    array those are the selected lanes, so its ``cnt`` is the rows of each
+    group; only where every value column is nullable (or there is none)
+    does a ``partition_histogram`` count them.  A group whose selected
+    rows all hold NULL keeps its rows and an empty ``cnt``: present,
+    COUNT(*) its rows, SUM NULL."""
     from .pallas_kernels import (filtered_group_sum, fused_group_aggregate,
                                  partition_histogram)
 
     fused: dict = {}          # input name -> (cnt, sm, mn, mx)
-    star_counts = None
+    rows = None
+    for s in specs:
+        if s.op == "count_star" or s.input in fused:
+            continue
+        c = batch.column(s.input)
+        live = sel if c.validity is None else c.validity & sel
+        # min/max lanes cost extra VPU work per group: only the full
+        # kernel when some spec on this column asks for them
+        if any(x.op in ("min", "max") and x.input == s.input for x in specs):
+            fused[s.input] = fused_group_aggregate(gid, c.data, live, ng)
+        else:
+            fused[s.input] = (*filtered_group_sum(gid, c.data, live, ng),
+                              None, None)
+        if rows is None and c.validity is None:
+            rows = fused[s.input][0]
+    count_passes = 0
+    if rows is None:
+        rows, count_passes = partition_histogram(gid, sel, ng), 1
     cols = []
     for s in specs:
         if s.op == "count_star":
-            if star_counts is None:
-                star_counts = partition_histogram(gid, sel, ng)
-            cols.append(Column(star_counts.astype(jnp.int64), None,
-                               LType.INT64))
+            cols.append(Column(rows.astype(jnp.int64), None, LType.INT64))
             continue
         c = batch.column(s.input)
-        if s.input not in fused:
-            live = c.valid_mask() & sel
-            # min/max lanes cost extra VPU work per group: only the full
-            # kernel when some spec on this column asks for them
-            if any(x.op in ("min", "max") and x.input == s.input
-                   for x in specs):
-                fused[s.input] = fused_group_aggregate(gid, c.data, live, ng)
-            else:
-                cnt_, sm_ = filtered_group_sum(gid, c.data, live, ng)
-                fused[s.input] = (cnt_, sm_, None, None)
         cnt, sm, mn, mx = fused[s.input]
         nonempty = cnt > 0
         if s.op == "count":
@@ -389,62 +421,72 @@ def _pallas_dense_cols(batch, specs, gid, ng: int, sel):
             cols.append(Column(mn.astype(c.data.dtype), nonempty, c.ltype))
         else:
             cols.append(Column(mx.astype(c.data.dtype), nonempty, c.ltype))
-    return cols
+    return rows, cols, count_passes
 
 
-def _segment_one(batch: ColumnBatch, s: AggSpec, gid, ng: int, sel) -> Column:
-    """One aggregate via segment reduction; gid==ng is the dead-row bucket."""
+def _group_rows(counts: dict, name: Optional[str], gid, ng: int):
+    """Rows per group over ``gid`` (``ng``, the dead-row bucket, on every
+    lane that does not count), reduced once a ``name`` and kept in
+    ``counts``: the cache the aggregates of one GROUP BY share, ``None`` ->
+    the selected rows, a nullable column's name -> those that hold a value
+    in it.  int32: a group holds fewer rows than the batch has lanes.
+    ``len(counts)`` is the passes traced only to count rows."""
+    if name not in counts:
+        counts[name] = seg_sum(jnp.ones_like(gid, jnp.int32), gid,
+                               num_segments=ng + 1)[:ng]
+    return counts[name]
+
+
+def _segment_one(batch: ColumnBatch, s: AggSpec, gid, ng: int, sel,
+                 counts: dict) -> Column:
+    """One aggregate via segment reduction; gid==ng is the dead-row bucket.
+    ``counts``: :func:`_group_rows`' cache over the same ``gid``."""
     if s.op == "count_star":
-        v = seg_sum(jnp.ones_like(gid, jnp.int64), gid, num_segments=ng + 1)[:ng]
-        return Column(v, None, LType.INT64)
+        return Column(_group_rows(counts, None, gid, ng).astype(jnp.int64),
+                      None, LType.INT64)
     c = batch.column(s.input)
     live = c.valid_mask() & sel
     gid_v = jnp.where(live, gid, ng)
     if s.distinct:
         return _segment_distinct(c, gid_v, ng, s)
+    if s.op == "approx_count_distinct":
+        regs = _hll_registers(c, live, gid_v, ng)
+        return Column(_hll_estimate(regs), None, LType.INT64)
+    # a column without a validity array holds a value on every selected row
+    ct = _group_rows(counts, None, gid, ng) if c.validity is None \
+        else _group_rows(counts, s.input, gid_v, ng)
     if s.op == "count":
-        v = seg_sum(jnp.ones_like(gid, jnp.int64), gid_v, num_segments=ng + 1)[:ng]
-        return Column(v, None, LType.INT64)
+        return Column(ct.astype(jnp.int64), None, LType.INT64)
     if s.op == "sum":
         dt = _sum_dtype(c)
         v = seg_sum(c.data.astype(dt), gid_v, num_segments=ng + 1)[:ng]
-        ct = seg_sum(jnp.ones_like(gid, jnp.int32), gid_v, num_segments=ng + 1)[:ng]
         return Column(v, ct > 0, agg_result_type("sum", c.ltype))
     if s.op == "avg":
         sm = seg_sum(c.data.astype(jnp.float64), gid_v, num_segments=ng + 1)[:ng]
-        ct = seg_sum(jnp.ones_like(gid, jnp.int32), gid_v, num_segments=ng + 1)[:ng]
         return Column(sm / jnp.maximum(ct, 1), ct > 0, LType.FLOAT64)
-    if s.op == "min":
-        v = seg_min(jnp.where(live, c.data, _minmax_identity(c, True)),
-                                jnp.where(live, gid, ng), num_segments=ng + 1)[:ng]
-        ct = seg_sum(jnp.where(live, 1, 0), gid_v, num_segments=ng + 1)[:ng]
-        return Column(v, ct > 0, c.ltype, c.dictionary)
-    if s.op == "max":
-        v = seg_max(jnp.where(live, c.data, _minmax_identity(c, False)),
-                                jnp.where(live, gid, ng), num_segments=ng + 1)[:ng]
-        ct = seg_sum(jnp.where(live, 1, 0), gid_v, num_segments=ng + 1)[:ng]
+    if s.op in ("min", "max"):
+        is_min = s.op == "min"
+        v = (seg_min if is_min else seg_max)(
+            jnp.where(live, c.data, _minmax_identity(c, is_min)), gid_v,
+            num_segments=ng + 1)[:ng]
         return Column(v, ct > 0, c.ltype, c.dictionary)
     if s.op == "sumsq":
         x = c.data.astype(jnp.float64)
         v = seg_sum(jnp.where(live, x * x, 0.0), gid_v, num_segments=ng + 1)[:ng]
-        ct = seg_sum(jnp.where(live, 1, 0), gid_v, num_segments=ng + 1)[:ng]
         return Column(v, ct > 0, LType.FLOAT64)
     if s.op in ("stddev", "stddev_samp", "variance", "var_samp"):
         x = c.data.astype(jnp.float64)
         sm = seg_sum(jnp.where(live, x, 0.0), gid_v, num_segments=ng + 1)[:ng]
         s2 = seg_sum(jnp.where(live, x * x, 0.0), gid_v, num_segments=ng + 1)[:ng]
-        n = seg_sum(jnp.where(live, 1.0, 0.0), gid_v, num_segments=ng + 1)[:ng]
+        n = ct.astype(jnp.float64)
         n1 = jnp.maximum(n, 1.0)
         var = s2 / n1 - (sm / n1) ** 2
         denom_n = n1 if s.op in ("stddev", "variance") else jnp.maximum(n - 1.0, 1.0)
         var = jnp.maximum(var * (n1 / denom_n), 0.0)
         v = jnp.sqrt(var) if s.op.startswith("stddev") else var
-        return Column(v, n > 0, LType.FLOAT64)
-    if s.op == "approx_count_distinct":
-        regs = _hll_registers(c, live, gid_v, ng)
-        return Column(_hll_estimate(regs), None, LType.INT64)
+        return Column(v, ct > 0, LType.FLOAT64)
     if s.op == "percentile":
-        v, ok = _segment_percentile(c, gid_v, ng, s.param)
+        v, ok = _segment_percentile(c, gid_v, ng, s.param, ct)
         return Column(v, ok, LType.FLOAT64)
     raise ValueError(f"unknown aggregate {s.op}")
 
@@ -550,7 +592,11 @@ def group_aggregate_sorted(batch: ColumnBatch, key_names: list[str],
     sorted_batch.sel = sel_s
     for s in specs:
         out_names.append(s.out_name)
-        out_cols.append(_segment_one(sorted_batch, s, gid, max_groups, sel_s))
+        # a cache of its own: each aggregate counts for itself here;
+        # sharing the counts is the dense strategy's, whose count
+        # reductions are the ones measured at 2^27 lanes
+        out_cols.append(_segment_one(sorted_batch, s, gid, max_groups,
+                                     sel_s, {}))
     present = jnp.arange(max_groups) < ngroups
     out = ColumnBatch(tuple(out_names), out_cols, present, ngroups)
     if with_overflow:

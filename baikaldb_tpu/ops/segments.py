@@ -13,13 +13,20 @@ decided at trace time:
   XLA fuses the compare into the reduction (nothing materializes in HBM; an
   einsum against a one-hot does NOT fuse — XLA allocates the full
   ``[n, k]`` one-hot, 54GB at 100M x 17 x f64), so the pass is one
-  bandwidth-bound read of the data plus ~1.5ms of VPU work per segment per
-  100M rows.  Accumulation is exact-width (int sums in the integer dtype,
+  bandwidth-bound read of the data plus VPU work per segment: at 2^27
+  lanes the north-star statement (COUNT(*), SUM, AVG, MIN of a FLOAT) is
+  72 ms + 2.06 ms a segment (PR 35's chip runs, 16 and 1,000 groups
+  forced).  Accumulation is exact-width (int sums in the integer dtype,
   wrapping exactly like the scatter path; float sums in f64), so results are
   in the same rounding class as ``jax.ops.segment_*``.
 - **CPU or large num_segments**: ``jax.ops.segment_*`` scatter, unchanged.
-  The ~512-segment crossover is where per-segment VPU work meets the
-  scatter's fixed ~8.8ns/row cost (both measured on v5e).
+  At 2^27 lanes an int32 scatter of ones costs 0.9-1.0 s alone in a
+  program (the Pallas arm's ``present`` until PR 36: PR 35's ledger line)
+  and the north-star statement's eight scatters (five of them counts; one
+  since PR 36) ~4.6 s each, 37-43 s a statement at any segment count
+  (PR 35's forced runs), so the crossover
+  is where the per-segment line meets the sum of a statement's scatters;
+  512 is stated, not derived (ROADMAP S8 (3)).
 
 Both of those index by group id.  The third GROUP BY strategy, ``stream``
 (``ops/hashagg.group_aggregate_stream``, chosen by the planner when an
@@ -28,6 +35,12 @@ DATE key and the key's domain is past the select+reduce and Pallas sizes),
 needs no id and no domain-sized output at all: equal keys are adjacent, so
 every aggregate is a *segmented scan* over the lanes as they come —
 :func:`seg_scan` below, elementwise passes only, on either backend.
+
+Counting rows is the reduction every aggregate shares: ``present``,
+``COUNT(*)``, every SUM / AVG / MIN / MAX's "has a value" mask.  The callers
+in ops/hashagg.py reduce ones once a GROUP BY (``_group_rows``: ``seg_sum`` of
+int32 ones over the selected lanes, and once more for each nullable column)
+and read every count off that; this module reduces what it is handed.
 """
 
 from __future__ import annotations

@@ -702,6 +702,13 @@ stream_agg_runs = Counter("stream_agg_runs")
 agg_select_reduce_runs = Counter("agg_select_reduce_runs")
 agg_pallas_runs = Counter("agg_pallas_runs")
 agg_scatter_runs = Counter("agg_scatter_runs")
+# counted beside them: the passes over their input lanes those aggregates
+# make only to count rows (``present``, COUNT(*), a column's non-NULL
+# count), as traced.  ops/hashagg.group_aggregate_dense counts a group's
+# rows once and reads the rest off it: 1 an aggregate on the segment arms
+# (+1 for each nullable column aggregated), 0 on the Pallas arm where a
+# fused kernel's own count serves, else 1
+agg_count_passes = Counter("agg_count_passes")
 stream_agg_fallbacks = Counter("stream_agg_fallbacks")
 join_live_rows = Counter("join_live_rows")
 aot_publish_ms = Counter("aot_publish_ms")

@@ -379,15 +379,15 @@ def compiled_kernels(label: str, query):
 
 
 # (group count, label, aggregates beside COUNT(*), the Pallas kernels the
-# query must compile — ops/hashagg._pallas_dense_cols: COUNT(*) is
-# _hist_kernel, SUM/AVG with MIN/MAX on the same column _agg_kernel, SUM/AVG
-# alone _sum_kernel)
+# query must compile — ops/hashagg._pallas_dense_cols: SUM/AVG with MIN/MAX
+# on the same column _agg_kernel, SUM/AVG alone _sum_kernel; COUNT(*) is the
+# fused kernel's own count (v holds no NULL) and _hist_kernel only where no
+# value column is aggregated)
 PALLAS_QUERIES = [
-    (1000, "agg", "SUM(v) s, AVG(v) a, MIN(v) mn, MAX(v) mx",
-     {"_hist_kernel", "_agg_kernel"}),
-    (1000, "sum", "SUM(v) s, AVG(v) a", {"_hist_kernel", "_sum_kernel"}),
-    (4000, "agg", "SUM(v) s, AVG(v) a, MIN(v) mn, MAX(v) mx",
-     {"_hist_kernel", "_agg_kernel"}),
+    (1000, "agg", "SUM(v) s, AVG(v) a, MIN(v) mn, MAX(v) mx", {"_agg_kernel"}),
+    (1000, "sum", "SUM(v) s, AVG(v) a", {"_sum_kernel"}),
+    (1000, "count", "", {"_hist_kernel"}),
+    (4000, "agg", "SUM(v) s, AVG(v) a, MIN(v) mn, MAX(v) mx", {"_agg_kernel"}),
 ]
 
 
@@ -415,9 +415,10 @@ def phase_groupby(db: Database, wire: Wire, n_rows: int, seed: int) -> None:
             for ng in GROUP_COUNTS}
 
     say("  cut for the time limit: every query over t runs once, not twice "
-        "(a pass takes 10-15 s); SUM/AVG without MIN/MAX runs at one group "
-        "count only; at 16 groups the north-star query is the select+reduce "
-        "GROUP BY (COUNT, SUM, AVG, MIN), the one that adds MAX is cut")
+        "(a pass takes 10-15 s); SUM/AVG without MIN/MAX and COUNT(*) alone "
+        "run at one group count only; at 16 groups the north-star query is "
+        "the select+reduce GROUP BY (COUNT, SUM, AVG, MIN), the one that "
+        "adds MAX is cut")
     # BASELINE.json's headline query, default flags then resident.  16
     # groups: the select+reduce lowering (ops/segments.py)
     north = ("SELECT g, COUNT(*) n, SUM(v) s, AVG(v) a, MIN(v) mn FROM t "
@@ -435,8 +436,8 @@ def phase_groupby(db: Database, wire: Wire, n_rows: int, seed: int) -> None:
     # 1,000 and 4,000 groups: the Pallas kernels, named in what was compiled
     for ng, kind, aggs, want in PALLAS_QUERIES:
         g, label = _group_col(ng), f"dense {ng} {kind}"
-        sql = (f"SELECT {g} g, COUNT(*) n, {aggs} FROM t "
-               f"WHERE v*2+1 > 0.5 GROUP BY {g}")
+        items = ", ".join(filter(None, (f"{g} g", "COUNT(*) n", aggs)))
+        sql = f"SELECT {items} FROM t WHERE v*2+1 > 0.5 GROUP BY {g}"
         (c, r), kernels = compiled_kernels(
             label, lambda: wire.select(label, sql, runs=1))
         check(want <= kernels, f"{label} compiled no Mosaic call of "

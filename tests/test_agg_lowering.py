@@ -135,14 +135,16 @@ def test_show_status_lists_the_three_counters(north):
 
 # -- lowered for the TPU from the CPU -----------------------------------------
 
-@pytest.mark.parametrize("key, domain, want", [
-    ("g", 16, "select_reduce"), ("g1000", 1000, "pallas"),
-    ("g4000", 4000, "pallas"), ("g9000", 9000, "scatter")])
+@pytest.mark.parametrize("key, domain, want, passes", [
+    ("g", 16, "select_reduce", 1), ("g1000", 1000, "pallas", 0),
+    ("g4000", 4000, "pallas", 0), ("g9000", 9000, "scatter", 1)])
 def test_tpu_plan_names_and_traces_its_lowering(north, monkeypatch, key,
-                                                domain, want):
+                                                domain, want, passes):
     """The plan of a north-star statement, labelled and lowered as the chip
     would: EXPLAIN says which lowering, the traced program records the same
-    one, and the module holds a Mosaic call exactly where it is Pallas."""
+    one and the passes it makes only to count rows (``v`` holds no NULL: one
+    on a segment arm, none beside the fused kernel), and the module holds a
+    Mosaic call exactly where it is Pallas: the fused kernel alone."""
     sql = NORTH.format(k=key, x="0.50")
     captured = {}
     real = Session._run_plan
@@ -159,18 +161,25 @@ def test_tpu_plan_names_and_traces_its_lowering(north, monkeypatch, key,
     raw = executor.compile_plan(captured["plan"])
     text = jax.jit(raw).trace(captured["batches"]) \
         .lower(lowering_platforms=("tpu",)).as_text()
-    assert raw.agg_lowerings == [want]
-    assert ("tpu_custom_call" in text) == (want == "pallas")
-    assert executor.traced_extra(raw, False) == {"agg_lowerings": (want,)}
+    assert raw.agg_lowerings == [want] and raw.agg_count_passes == [passes]
+    assert text.count("tpu_custom_call") == (want == "pallas")
+    assert "_hist_kernel" not in text
+    assert ("stablehlo.scatter" in text) == (want == "scatter")
+    assert executor.traced_extra(raw, False) == {
+        "agg_lowerings": (want,), "agg_count_passes": passes}
 
 
 def test_an_aot_loaded_program_counts_what_its_artifact_recorded():
     shim = executor.AotRawShim([], {"agg_lowerings": ("select_reduce",
-                                                      "pallas", "pallas")})
-    before = _counts()
+                                                      "pallas", "pallas"),
+                                    "agg_count_passes": 2})
+    before, passes = _counts(), metrics.agg_count_passes.value
     executor.count_lowerings(shim)
     assert _grew(before) == {"select_reduce": 1, "pallas": 2}
+    assert metrics.agg_count_passes.value == passes + 2
     old = executor.AotRawShim([{"cap": 8}], None)   # an artifact from before
     assert old.agg_lowerings == [] and old.exchange_bytes == [0]
+    assert old.agg_count_passes == [0]
     executor.count_lowerings(old)
     assert _grew(before) == {"select_reduce": 1, "pallas": 2}
+    assert metrics.agg_count_passes.value == passes + 2

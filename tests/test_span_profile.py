@@ -139,10 +139,16 @@ def test_rehearsal_prints_every_span_metric(cell, tmp_path):
     assert set(CELLS[cell]) <= set(got), set(CELLS[cell]) - set(got)
     # the cell's own per-layer metrics still stand beside them
     assert any("retraces" in n and got[n]["value"] == 0 for n in got)
-    untraced = got[[n for n in got if n.startswith("untraced_ms.")][0]]
-    assert untraced["value"] >= 0
+    for n in got:
+        if n.startswith("untraced_ms."):
+            assert got[n]["value"] >= 0
     assert spans == {"by_program_span": None, "tracing": 0}     # no chip
     assert len(list((tmp_path / "out").glob("*.jsonl"))) == 1
+    if cell.startswith("tpu_northstar"):
+        # on the CPU every dense aggregate is the scatter: rows once, and
+        # v holds no NULL, so one pass a statement where five were
+        assert got["count_passes.northstar"]["value"] == 1.0
+        assert line["counters"]["agg_count_passes"] == line["attempted"]
     if cell.startswith("sysbench"):
         assert line["counters"]["txn_commits"] == line["attempted"]
         assert got["point_lookup_ms.oltp"]["value"] > 0
