@@ -132,13 +132,15 @@ class AotRawShim:
         self.exchange_bytes = [int(extra.get("exchange_bytes", 0))]
         self.agg_lowerings = list(extra.get("agg_lowerings", ()))
         self.agg_count_passes = [int(extra.get("agg_count_passes", 0))]
+        self.agg_minmax_passes = [int(extra.get("agg_minmax_passes", 0))]
 
 
 def traced_extra(raw, mesh: bool) -> dict:
     """What a program's trace recorded beside its flags, as an AOT artifact
     carries it (``extra``) and :class:`AotRawShim` reads it back."""
     extra: dict = {"agg_lowerings": tuple(raw.agg_lowerings),
-                   "agg_count_passes": raw.agg_count_passes[0]}
+                   "agg_count_passes": raw.agg_count_passes[0],
+                   "agg_minmax_passes": raw.agg_minmax_passes[0]}
     if mesh:
         extra["exchange_bytes"] = raw.exchange_bytes[0]
     return extra
@@ -146,17 +148,20 @@ def traced_extra(raw, mesh: bool) -> dict:
 
 def count_lowerings(raw) -> None:
     """One execution of ``raw``'s program: +1 on ``agg_<lowering>_runs`` for
-    each dense aggregate in it, and on ``agg_count_passes`` the passes they
-    were traced with only to count rows."""
+    each dense aggregate in it, on ``agg_count_passes`` the passes they were
+    traced with only to count rows, and on ``agg_minmax_passes`` the dense
+    MIN / MAX passes of their Pallas kernels."""
     for lowering in raw.agg_lowerings:
         _AGG_COUNTERS[lowering].add(1)
     _AGG_COUNTERS["count_passes"].add(raw.agg_count_passes[0])
+    _AGG_COUNTERS["minmax_passes"].add(raw.agg_minmax_passes[0])
 
 
 _AGG_COUNTERS = {"select_reduce": metrics.agg_select_reduce_runs,
                  "pallas": metrics.agg_pallas_runs,
                  "scatter": metrics.agg_scatter_runs,
-                 "count_passes": metrics.agg_count_passes}
+                 "count_passes": metrics.agg_count_passes,
+                 "minmax_passes": metrics.agg_minmax_passes}
 
 
 class _CapBox:
@@ -201,10 +206,12 @@ def compile_plan(plan: PlanNode, trace: bool = False, mesh=None) -> Callable:
     # static shapes, so tallied once per trace like join_order
     exchange_bytes = [0]
     # the lowering each dense aggregate of the program was traced with
-    # (ops/hashagg.dense_lowering), in trace order, and the passes over
-    # their input they make only to count rows: tallied like the above
+    # (ops/hashagg.dense_lowering), in trace order, the passes over their
+    # input they make only to count rows, and their Pallas kernels' dense
+    # MIN / MAX passes: tallied like the above
     agg_lowerings: list = []
     agg_count_passes = [0]
+    agg_minmax_passes = [0]
 
     def run_local(batches: dict):
         if not getattr(ACCOUNTING_TRACE, "active", False):
@@ -221,8 +228,9 @@ def compile_plan(plan: PlanNode, trace: bool = False, mesh=None) -> Callable:
         with bind_params(batches.get(PARAMS_KEY, ())), \
                 noting_lowerings() as lowered:
             out = _sub(plan, batches, overflows, ctx)
-        agg_lowerings[:] = [lowering for lowering, _ in lowered]
-        agg_count_passes[0] = sum(passes for _, passes in lowered)
+        agg_lowerings[:] = [lowering for lowering, _, _ in lowered]
+        agg_count_passes[0] = sum(passes for _, passes, _ in lowered)
+        agg_minmax_passes[0] = sum(passes for _, _, passes in lowered)
         # nodes are host objects: expose them on the closure (filled at trace
         # time), return only the traced flags
         join_order.clear()
@@ -266,6 +274,7 @@ def compile_plan(plan: PlanNode, trace: bool = False, mesh=None) -> Callable:
     run.exchange_bytes = exchange_bytes
     run.agg_lowerings = agg_lowerings
     run.agg_count_passes = agg_count_passes
+    run.agg_minmax_passes = agg_minmax_passes
     return run
 
 

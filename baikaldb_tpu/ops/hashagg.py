@@ -292,19 +292,19 @@ def group_aggregate_dense(batch: ColumnBatch, key_names: list[str],
     lowering = dense_lowering(specs, lambda n: batch.column(n).ltype, ng)
     out_names.extend(s.out_name for s in specs)
     if lowering == "pallas":
-        rows, cols, count_passes = _pallas_dense_cols(batch, specs, gid, ng,
-                                                      sel)
+        rows, cols, count_passes, minmax_passes = _pallas_dense_cols(
+            batch, specs, gid, ng, sel)
     else:
         gid_live = jnp.where(sel, gid, ng)  # dead rows -> overflow bucket
         counts: dict = {}
         cols = [_segment_one(batch, s, gid_live, ng, sel, counts)
                 for s in specs]
         rows = _group_rows(counts, None, gid_live, ng)
-        count_passes = len(counts)
+        count_passes, minmax_passes = len(counts), 0
     out_cols.extend(cols)
     noted = _NOTED.get()
     if noted is not None:
-        noted.append((lowering, count_passes))
+        noted.append((lowering, count_passes, minmax_passes))
     return ColumnBatch(tuple(out_names), out_cols, rows > 0, None)
 
 
@@ -317,11 +317,12 @@ _NOTED: ContextVar = ContextVar("dense_lowerings", default=None)
 @contextmanager
 def noting_lowerings():
     """While a program is traced under this, every ``group_aggregate_dense``
-    appends ``(lowering, count_passes)`` to the list yielded — the lowering
-    it chose and the passes over its input lanes it traced only to count
-    rows: the trace-time facts, kept with the compiled program by the
-    tracer (the executor's ``compile_plan``) and counted once an
-    execution."""
+    appends ``(lowering, count_passes, minmax_passes)`` to the list yielded
+    — the lowering it chose, the passes over its input lanes it traced only
+    to count rows, and the dense (lane, group) passes its Pallas kernels
+    make for a MIN or a MAX: the trace-time facts, kept with the compiled
+    program by the tracer (the executor's ``compile_plan``) and counted once
+    an execution."""
     noted: list = []
     token = _NOTED.set(noted)
     try:
@@ -370,7 +371,8 @@ def dense_lowering(specs: list, ltype_of: Callable, ng: int) -> str:
 def _pallas_dense_cols(batch, specs, gid, ng: int, sel):
     """A dense group-by that :func:`dense_lowering` put on the Pallas MXU
     kernels.  -> (rows per group [ng] as whole-number f64, the aggregate
-    Columns in spec order, the passes made only to count rows: 0 or 1).
+    Columns in spec order, the passes made only to count rows: 0 or 1, the
+    dense passes made for MIN / MAX: one for each asked of a column).
 
     Every value column goes through one fused kernel, whose ``cnt`` counts
     the selected lanes that hold a value.  For a column without a validity
@@ -384,15 +386,20 @@ def _pallas_dense_cols(batch, specs, gid, ng: int, sel):
 
     fused: dict = {}          # input name -> (cnt, sm, mn, mx)
     rows = None
+    minmax_passes = 0
     for s in specs:
         if s.op == "count_star" or s.input in fused:
             continue
         c = batch.column(s.input)
         live = sel if c.validity is None else c.validity & sel
-        # min/max lanes cost extra VPU work per group: only the full
-        # kernel when some spec on this column asks for them
-        if any(x.op in ("min", "max") and x.input == s.input for x in specs):
-            fused[s.input] = fused_group_aggregate(gid, c.data, live, ng)
+        # a MIN or a MAX is a dense compare over every (lane, group) pair:
+        # each is computed only where a spec on this column asks for it
+        ops = {x.op for x in specs if x.input == s.input}
+        want_min, want_max = "min" in ops, "max" in ops
+        if want_min or want_max:
+            fused[s.input] = fused_group_aggregate(
+                gid, c.data, live, ng, want_min=want_min, want_max=want_max)
+            minmax_passes += want_min + want_max
         else:
             fused[s.input] = (*filtered_group_sum(gid, c.data, live, ng),
                               None, None)
@@ -421,7 +428,7 @@ def _pallas_dense_cols(batch, specs, gid, ng: int, sel):
             cols.append(Column(mn.astype(c.data.dtype), nonempty, c.ltype))
         else:
             cols.append(Column(mx.astype(c.data.dtype), nonempty, c.ltype))
-    return rows, cols, count_passes
+    return rows, cols, count_passes, minmax_passes
 
 
 def _group_rows(counts: dict, name: Optional[str], gid, ng: int):

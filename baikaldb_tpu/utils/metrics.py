@@ -719,6 +719,11 @@ agg_scatter_runs = Counter("agg_scatter_runs")
 # (+1 for each nullable column aggregated), 0 on the Pallas arm where a
 # fused kernel's own count serves, else 1
 agg_count_passes = Counter("agg_count_passes")
+# and the dense (lane, group) passes their Pallas kernels make for a MIN or
+# a MAX (ops/pallas_kernels: COUNT and SUM are one MXU contraction, a MIN or
+# a MAX compares every lane with every group): one for each a statement asks
+# of a column, 0 on the segment arms
+agg_minmax_passes = Counter("agg_minmax_passes")
 stream_agg_fallbacks = Counter("stream_agg_fallbacks")
 join_live_rows = Counter("join_live_rows")
 aot_publish_ms = Counter("aot_publish_ms")

@@ -162,24 +162,31 @@ def test_tpu_plan_names_and_traces_its_lowering(north, monkeypatch, key,
     text = jax.jit(raw).trace(captured["batches"]) \
         .lower(lowering_platforms=("tpu",)).as_text()
     assert raw.agg_lowerings == [want] and raw.agg_count_passes == [passes]
+    minmax = int(want == "pallas")          # MIN(v) and no MAX: one pass
+    assert raw.agg_minmax_passes == [minmax]
     assert text.count("tpu_custom_call") == (want == "pallas")
     assert "_hist_kernel" not in text
     assert ("stablehlo.scatter" in text) == (want == "scatter")
     assert executor.traced_extra(raw, False) == {
-        "agg_lowerings": (want,), "agg_count_passes": passes}
+        "agg_lowerings": (want,), "agg_count_passes": passes,
+        "agg_minmax_passes": minmax}
 
 
 def test_an_aot_loaded_program_counts_what_its_artifact_recorded():
     shim = executor.AotRawShim([], {"agg_lowerings": ("select_reduce",
                                                       "pallas", "pallas"),
-                                    "agg_count_passes": 2})
+                                    "agg_count_passes": 2,
+                                    "agg_minmax_passes": 3})
     before, passes = _counts(), metrics.agg_count_passes.value
+    minmax = metrics.agg_minmax_passes.value
     executor.count_lowerings(shim)
     assert _grew(before) == {"select_reduce": 1, "pallas": 2}
     assert metrics.agg_count_passes.value == passes + 2
+    assert metrics.agg_minmax_passes.value == minmax + 3
     old = executor.AotRawShim([{"cap": 8}], None)   # an artifact from before
     assert old.agg_lowerings == [] and old.exchange_bytes == [0]
-    assert old.agg_count_passes == [0]
+    assert old.agg_count_passes == [0] and old.agg_minmax_passes == [0]
     executor.count_lowerings(old)
     assert _grew(before) == {"select_reduce": 1, "pallas": 2}
     assert metrics.agg_count_passes.value == passes + 2
+    assert metrics.agg_minmax_passes.value == minmax + 3

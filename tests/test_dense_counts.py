@@ -1,7 +1,9 @@
 """A dense GROUP BY counts its rows once (PR 36): ``present``, ``COUNT(*)``
 and the count of every column without a validity array are read off one
 per-group vector, in whichever arm runs, and ``agg_count_passes`` says how
-many passes over the input lanes were traced only to count rows.
+many passes over the input lanes were traced only to count rows.  Since PR
+38 the Pallas arm computes a MIN or a MAX only where a spec asks for it, and
+``agg_minmax_passes`` says how many such dense passes were traced.
 
 The Pallas arm is driven on the CPU: ``segments._onehot_backend`` says yes
 and the three kernels run with ``interpret=True``; the scatter arm of the
@@ -81,7 +83,9 @@ def _specs(count_star: bool, ops=("sum", "avg", "min", "count")):
 @pytest.mark.parametrize("count_star", [True, False], ids=["star", "nostar"])
 @pytest.mark.parametrize("nullable", [False, True], ids=["dense_v", "null_v"])
 @pytest.mark.parametrize("ops", [("sum", "avg", "min", "count"),
-                                 ("sum", "count")], ids=["agg", "sum"])
+                                 ("sum", "min", "max", "count"),
+                                 ("sum", "count")],
+                         ids=["agg", "minmax", "sum"])
 def test_pallas_arm_counts_as_the_scatter_arm(pallas_arm, monkeypatch,
                                               nullable, count_star, ops):
     batch, at = _batch(nullable)
@@ -90,7 +94,7 @@ def test_pallas_arm_counts_as_the_scatter_arm(pallas_arm, monkeypatch,
         m.setattr(segments, "_onehot_backend", lambda: False)
         with noting_lowerings() as ref_noted:
             ref = group_aggregate_dense(batch, ["g"], [NG], specs)
-    assert ref_noted == [("scatter", 1 + nullable)]
+    assert ref_noted == [("scatter", 1 + nullable, 0)]
     assert not any(pallas_arm.values())
 
     def traced(b):
@@ -98,9 +102,11 @@ def test_pallas_arm_counts_as_the_scatter_arm(pallas_arm, monkeypatch,
 
     with noting_lowerings() as noted:
         jaxpr = str(jax.make_jaxpr(traced)(batch))
-    # one fused kernel; a histogram only where v's own count cannot serve
-    assert noted == [("pallas", int(nullable))]
-    fused = "fused_group_aggregate" if "min" in ops else "filtered_group_sum"
+    # one fused kernel; a histogram only where v's own count cannot serve;
+    # a dense pass for each of MIN and MAX asked
+    minmax = ("min" in ops) + ("max" in ops)
+    assert noted == [("pallas", int(nullable), minmax)]
+    fused = "fused_group_aggregate" if minmax else "filtered_group_sum"
     assert pallas_arm == {**dict.fromkeys(KERNELS, 0), fused: 1,
                           "partition_histogram": int(nullable)}
     assert "scatter" not in jaxpr and "segment_sum" not in jaxpr
@@ -135,7 +141,7 @@ def test_pallas_arm_counts_as_the_scatter_arm(pallas_arm, monkeypatch,
         np.testing.assert_array_equal(np.asarray(a.validity),
                                       np.asarray(b.validity))
         ok = vals > 0
-        if op == "min":
+        if op in ("min", "max"):
             np.testing.assert_array_equal(np.asarray(a.data)[ok],
                                           np.asarray(b.data)[ok])
         else:
@@ -148,7 +154,7 @@ def test_pallas_arm_with_no_value_column_is_one_histogram(pallas_arm):
     batch, at = _batch(False)
     with noting_lowerings() as noted:
         got = group_aggregate_dense(batch, ["g"], [NG], _specs(True, ()))
-    assert noted == [("pallas", 1)]
+    assert noted == [("pallas", 1, 0)]
     assert pallas_arm == {**dict.fromkeys(KERNELS, 0),
                           "partition_histogram": 1}
     rows = np.bincount(at["slot"][at["sel"]], minlength=NG + 1)
@@ -167,7 +173,7 @@ def test_a_second_nullable_column_is_counted_by_itself(pallas_arm):
              AggSpec("count_star", None, "n"), AggSpec("sum", "w", "sw")]
     with noting_lowerings() as noted:
         got = group_aggregate_dense(batch, ["g"], [NG], specs)
-    assert noted == [("pallas", 0)]
+    assert noted == [("pallas", 0, 0)]
     assert pallas_arm["filtered_group_sum"] == 2
     assert pallas_arm["partition_histogram"] == 0
     rows = np.bincount(at["slot"][at["sel"]], minlength=NG + 1)
@@ -216,7 +222,7 @@ def test_q1_shaped_answers_and_one_count_a_nullable_column(monkeypatch, arm):
         jaxpr = str(jax.make_jaxpr(lambda b: group_aggregate_dense(
             b, ["rf", "ls"], [3, 2], Q1_SPECS))(batch))
     # rows once, d's own count once: 2 passes where nine specs made ten
-    assert noted == [(arm, 2)]
+    assert noted == [(arm, 2, 0)]
     assert ("scatter" in jaxpr) == (arm == "scatter")
     got = group_aggregate_dense(batch, ["rf", "ls"], [3, 2], Q1_SPECS)
     slot = cols["rf"] * 3 + cols["ls"]
@@ -319,3 +325,31 @@ def test_show_status_has_the_counter(served):
     served.query("SELECT COUNT(*) c, SUM(v) s FROM t WHERE v > 0")   # scalar
     served.query("SELECT v, COUNT(*) c FROM t GROUP BY v")           # sorted
     assert metrics.agg_count_passes.value == before
+
+
+@pytest.mark.parametrize("aggs, minmax", [
+    ("COUNT(*) pn, SUM(v) ps, AVG(v) pa, MIN(v) pm", 1),    # the north star's
+    ("COUNT(*) pn, MIN(v) pm, MAX(v) px", 2),
+    ("COUNT(*) pn, SUM(v) ps", 0)])
+def test_an_execution_adds_its_minmax_passes(served, pallas_arm, aggs,
+                                             minmax):
+    """The Pallas arm (the chip's choice at 1,000 groups): the dense MIN /
+    MAX passes are noted when the program is traced (the fused kernel is
+    called then, and only where one is asked) and counted once an
+    execution, the same program for a new literal."""
+    sql = (f"SELECT g1000, {aggs} FROM t WHERE v*3+1 > {{x}} "
+           "GROUP BY g1000")
+    before = metrics.agg_minmax_passes.value
+    pallas = metrics.agg_pallas_runs.value
+    assert served.query(sql.format(x="0.25"))
+    assert metrics.agg_pallas_runs.value == pallas + 1
+    assert metrics.agg_minmax_passes.value == before + minmax
+    traced = dict(pallas_arm)
+    assert traced["fused_group_aggregate"] == int(minmax > 0)
+    assert traced["filtered_group_sum"] == int(minmax == 0)
+    assert served.query(sql.format(x="0.50"))
+    assert pallas_arm == traced                 # not traced again
+    assert metrics.agg_minmax_passes.value == before + 2 * minmax
+    status = {str(r[0]).partition(".")[0]: r[1]
+              for r in served.execute("SHOW STATUS LIKE 'agg_%'").rows}
+    assert int(status["agg_minmax_passes"]) == metrics.agg_minmax_passes.value

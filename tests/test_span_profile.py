@@ -162,6 +162,9 @@ def test_rehearsal_prints_every_span_metric(cell, tracing, tmp_path):
         # v holds no NULL, so one pass a statement where five were
         assert got["count_passes.northstar"]["value"] == 1.0
         assert line["counters"]["agg_count_passes"] == line["attempted"]
+        # and no Pallas kernel, so no dense MIN / MAX pass
+        assert got["minmax_passes.northstar"]["value"] == 0.0
+        assert "agg_minmax_passes" not in line["counters"]
     if cell.startswith("sysbench"):
         assert line["counters"]["txn_commits"] == line["attempted"]
         assert got["point_lookup_ms.oltp"]["value"] > 0
