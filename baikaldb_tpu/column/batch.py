@@ -203,13 +203,17 @@ class ColumnBatch:
         src/exec/packet_node.cpp from Arrow tables on the vectorized path)."""
         import pyarrow as pa
 
-        sel = None if self.sel is None else np.asarray(self.sel)
-        n = None
-        if self.num_rows is not None:
-            n = int(self.num_rows)
+        # every leaf in one transfer: the copies are started together and
+        # waited for once, not one device round trip a leaf
+        sel, n, leaves = jax.device_get(
+            (self.sel, self.num_rows,
+             [(c.data, c.validity) for c in self.columns]))
+        sel = None if sel is None else np.asarray(sel)
+        n = None if n is None else int(n)
         arrays, fields = [], []
-        for name, c in zip(self.names, self.columns):
-            data, valid = c.to_numpy()
+        for name, c, (data, valid) in zip(self.names, self.columns, leaves):
+            data = np.asarray(data)
+            valid = None if valid is None else np.asarray(valid)
             if n is not None:
                 data = data[:n]
                 valid = None if valid is None else valid[:n]

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from ..column.batch import Column, ColumnBatch
 from ..expr.ast import ColRef, Lit
 from ..expr.compile import eval_expr, eval_output, eval_predicate, infer_type
+from ..column.dictionary import KEYS_KEY, bind_keys, recording_bakes
 from ..expr.params import PARAMS_KEY, bind_params
 from ..ops import join as join_ops
 from ..ops.compact import compact, head
@@ -212,6 +213,9 @@ def compile_plan(plan: PlanNode, trace: bool = False, mesh=None) -> Callable:
     agg_lowerings: list = []
     agg_count_passes = [0]
     agg_minmax_passes = [0]
+    # lineage id -> the n of the growing dictionary whose values the last
+    # trace read (column/dictionary.BAKED)
+    baked: dict = {}
 
     def run_local(batches: dict):
         if not getattr(ACCOUNTING_TRACE, "active", False):
@@ -224,10 +228,17 @@ def compile_plan(plan: PlanNode, trace: bool = False, mesh=None) -> Callable:
         ctx = (overflows, counts if trace else None, trace_order, n_shards,
                moved)
         # hoisted-literal params (plan/paramize.py) ride the batches pytree;
-        # Param expr nodes read their slots from this trace-scoped binding
+        # Param expr nodes read their slots from this trace-scoped binding,
+        # as the order keys of growing string dictionaries from theirs
+        # (column/dictionary.py); the values of those the trace reads are
+        # noted, and the program is good up to them
         with bind_params(batches.get(PARAMS_KEY, ())), \
+                bind_keys(batches.get(KEYS_KEY, {})), \
+                recording_bakes() as read, \
                 noting_lowerings() as lowered:
             out = _sub(plan, batches, overflows, ctx)
+        baked.clear()
+        baked.update(read)
         agg_lowerings[:] = [lowering for lowering, _, _ in lowered]
         agg_count_passes[0] = sum(passes for _, passes, _ in lowered)
         agg_minmax_passes[0] = sum(passes for _, _, passes in lowered)
@@ -262,7 +273,7 @@ def compile_plan(plan: PlanNode, trace: bool = False, mesh=None) -> Callable:
             # its own shard_map program.  Built per call from the batch
             # keys; jit caches on the pytree structure, so steady state
             # never reconstructs a trace.
-            specs = {k: (P() if k == PARAMS_KEY else P(AXIS))
+            specs = {k: (P() if k in (PARAMS_KEY, KEYS_KEY) else P(AXIS))
                      for k in batches}
             smapped = jax.shard_map(run_local, mesh=mesh, in_specs=(specs,),
                                     out_specs=P(), check_vma=False)
@@ -275,6 +286,7 @@ def compile_plan(plan: PlanNode, trace: bool = False, mesh=None) -> Callable:
     run.agg_lowerings = agg_lowerings
     run.agg_count_passes = agg_count_passes
     run.agg_minmax_passes = agg_minmax_passes
+    run.baked = baked
     return run
 
 

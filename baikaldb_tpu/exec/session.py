@@ -48,6 +48,8 @@ from ..plan import paramize
 from ..storage.column_store import ROWID as ROWID_COL
 from ..storage.column_store import (TableStore, check_cold_readable,
                                     schema_to_arrow)
+from ..storage.rowstore import ConflictError
+from ..column.dictionary import KEYS_KEY
 from ..types import Field, LType, Schema
 from ..analysis.runtime import guard_stats, hot_path_guard
 from ..obs import progress, trace
@@ -56,7 +58,7 @@ from ..obs.flightrec import (FlightRecorder, device_stats, metric_delta,
 from ..obs.progress import PROGRESS, QueryKilled
 from ..obs.trace import TRACER
 from ..obs.watchdog import QueryWatchdog
-from ..utils import compilecache, metrics
+from ..utils import compilecache, gcpause, metrics
 from ..utils.flags import FLAGS, define
 
 define("cold_fs_dir", "",
@@ -86,6 +88,10 @@ from .executor import (_CapBox, compile_plan, count_shuffle_rounds,
 # INSERT..SELECT at or below this lands in the hot (WAL-durable) row tier;
 # above it, the bulk cold path (durable at the next checkpoint)
 HOT_INSERT_ROWS = 100_000
+# a result of at most this many lanes leaves the chip whole, mask and all,
+# in one transfer (32 KiB an 8-byte column), and the host keeps its live
+# rows; a larger one is counted and gathered on the device first
+EGRESS_HOST_LANES = 1 << 13
 
 
 # server-level system variable defaults (reference: the session_variables
@@ -161,6 +167,41 @@ def _show_like_rx(pat: str):
 
 def _empty_info(name: str):
     return schema_to_arrow(Catalog.INFORMATION_SCHEMA[name]).empty_table()
+
+
+def _string_dictionaries(batches: dict):
+    """The dictionary of every string column of the batches."""
+    for b in batches.values():
+        for c in getattr(b, "columns", ()):
+            if c.dictionary is not None:
+                yield c.dictionary
+
+
+def _dictionary_keys(batches: dict) -> dict:
+    """lineage token -> order keys on the device, for every growing
+    dictionary with a tail among the batches' string columns."""
+    out = {}
+    for d in _string_dictionaries(batches):
+        if d.tailed and d.lineage.token not in out:
+            out[d.lineage.token] = d.lineage.keys_device()
+    return out
+
+
+def _bakes_outgrown(raw, batches: dict) -> bool:
+    """Whether a program's trace read a growing dictionary's values (a
+    string function's table, a join's merge) up to an ``n`` some input
+    dictionary of that lineage now passes."""
+    baked = getattr(raw, "baked", None)
+    if not baked:
+        return False
+    return any(d.lineage is not None and d.lineage.id in baked
+               and len(d) > baked[d.lineage.id]
+               for d in _string_dictionaries(batches))
+
+
+def _coerce_row(row: dict, schema: pa.Schema) -> dict:
+    """A row dict with every value as the table's Arrow type stores it."""
+    return pa.Table.from_pylist([row], schema=schema).to_pylist()[0]
 
 
 def _stmt_image(kind: str, s) -> str:
@@ -773,6 +814,8 @@ class Session:
         # locks + buffered WAL writes + zero-copy region pre-images; the
         # reference's Transaction, src/engine/transaction.cpp:98-396)
         self._sql_txn: Optional[dict] = None
+        # (pin id, ts) of the open transaction's snapshot (_txn_snapshot)
+        self._txn_pin: Optional[tuple] = None
         # session variables (@vars + per-session system vars via SET)
         self.session_vars: dict = {}
         # binlog events buffered until COMMIT (discarded on ROLLBACK) so CDC
@@ -2333,6 +2376,7 @@ class Session:
         TransactionNode fan-out).  Each touched table gets a storage
         TxnContext: pessimistic row locks + row-tier write buffer + zero-copy
         region pre-images; COMMIT is one atomic WAL batch per table."""
+        self._release_txn_snapshot()
         if s.kind == "begin":
             # a new BEGIN implicitly commits any previous txn (MySQL behavior)
             open_txn = self._sql_txn is not None
@@ -2447,7 +2491,18 @@ class Session:
             return None
         key = f"{store.info.database}.{store.info.name}"
         if key not in self._sql_txn:
-            self._sql_txn[key] = store.begin_txn()
+            tctx = store.begin_txn()
+            if self._txn_pin is not None and \
+                    store.mvcc_needs_versioned(self._txn_pin[1]):
+                # the transaction read this table at its snapshot and
+                # someone committed to it since: its reads of the table
+                # from here on (its own rows showing) could not be that
+                # snapshot.  First committer wins, as snapshot isolation
+                tctx.rollback()
+                raise ConflictError(
+                    f"table {store.info.name} changed since this "
+                    "transaction's snapshot")
+            self._sql_txn[key] = tctx
         return self._sql_txn[key]
 
     def load_arrow(self, table_name: str, table: pa.Table,
@@ -2462,6 +2517,7 @@ class Session:
         if vcols:
             table = _expand_vector_arrow(table, vcols)
         self._ingest_arrow(store, table)
+        gcpause.settle("load")
         return table.num_rows
 
     # -- DDL --------------------------------------------------------------
@@ -3648,6 +3704,17 @@ class Session:
     def _point_write_mask(self, store: TableStore, where):
         """The cheap PK mask when WHERE fixes the whole primary key by
         equality; None otherwise (fall back to the compiled predicate)."""
+        key = self._point_write_key(store, where)
+        if key is None:
+            return None
+        try:
+            return self._pk_mask_fn(store, key)
+        except Exception:
+            return None              # odd literal/type: compiled path
+
+    @staticmethod
+    def _point_write_key(store: TableStore, where) -> Optional[dict]:
+        """The primary key WHERE fixes by equality, or None."""
         from ..index.selector import point_key
 
         if store._pk_cols is None or where is None:
@@ -3659,12 +3726,89 @@ class Session:
         w = _W()
         w.where = where
         try:
-            key = point_key(w, store._pk_cols)
-            if key is None:
-                return None
-            return self._pk_mask_fn(store, key)
+            return point_key(w, store._pk_cols)
         except Exception:
-            return None              # odd literal/type: compiled path
+            return None
+
+    def _patch_key(self, store: TableStore, where, db_name: str,
+                   table: str) -> Optional[dict]:
+        """The key of a write the store can take as a patch
+        (``TableStore.pk_update`` / ``pk_delete``): WHERE fixes an integer
+        primary key to a whole number, no global index rides the table, and
+        no CDC consumer wants its rows; else None."""
+        if not store.pk_patchable():
+            return None
+        key = self._point_write_key(store, where)
+        if key is None:
+            return None
+        v = next(iter(key.values()))
+        if isinstance(v, bool) or not isinstance(v, int) \
+                or self._coupled_global(store) \
+                or self.db.cdc.wants_rows(f"{db_name}.{table}"):
+            return None
+        return key
+
+    @staticmethod
+    def _host_assign(assigns, schema):
+        """``row -> new row`` for assignments of literals, columns and
+        ``+`` / ``-`` / ``*`` over them evaluated in Python; None when some
+        assignment is anything else (the compiled path evaluates it)."""
+        from ..expr.ast import Call, Lit
+        from ..expr.ast import ColRef as _CRef
+
+        ops = {"add": lambda a, b: a + b, "sub": lambda a, b: a - b,
+               "mul": lambda a, b: a * b}
+
+        def compile_(e):
+            if isinstance(e, Lit):
+                v = e.value
+                return lambda row: v
+            if isinstance(e, _CRef):
+                name = e.name.split(".")[-1]
+                if name not in schema:
+                    return None
+                return lambda row: row[name]
+            if isinstance(e, Call) and e.op in ops and len(e.args) == 2:
+                a, b = (compile_(x) for x in e.args)
+                if a is None or b is None:
+                    return None
+                f = ops[e.op]
+
+                def ev(row):
+                    x, y = a(row), b(row)
+                    if x is None or y is None:
+                        return None
+                    if not all(isinstance(t, int) and not isinstance(t, bool)
+                               for t in (x, y)):
+                        raise TypeError("not integer arithmetic")
+                    return f(x, y)
+                return ev
+            return None
+
+        fns = []
+        for name, e in assigns:
+            f = schema.field(name)
+            fn = compile_(_qualify_free(e))
+            if fn is None:
+                return None
+            if isinstance(e, Lit):
+                v = e.value
+                ok = (v is None or (f.ltype is LType.STRING
+                                    and isinstance(v, str))
+                      or (f.ltype.is_integer and isinstance(v, int)
+                          and not isinstance(v, bool)))
+                if not ok:
+                    return None
+            elif not f.ltype.is_integer:
+                return None
+            fns.append((name, fn))
+
+        def assign(row):
+            new = dict(row)
+            for name, fn in fns:
+                new[name] = fn(row)
+            return new
+        return assign
 
     def _update(self, s: UpdateStmt) -> Result:
         store = self._store(s.table)
@@ -3706,6 +3850,29 @@ class Session:
                 out = out.set_column(idx, f, merged)
             return out
 
+        db_name = s.table.database or self.current_db
+        changed = [name for name, _ in assigns]
+        pkey = self._patch_key(store, s.where, db_name, s.table.name)
+        if pkey is not None and not set(changed) & set(store._pk_cols):
+            # the row by the pk index, the new version a patch of the
+            # resident image (TableStore.pk_update)
+            fast = self._host_assign(assigns, schema)
+
+            def assign_row(row, _fast=fast):
+                if _fast is not None:
+                    try:
+                        return _coerce_row(_fast(row), arrow_schema)
+                    except (TypeError, OverflowError, ValueError):
+                        pass
+                one = pa.Table.from_pylist([row], schema=arrow_schema)
+                return assign_fn(one, np.ones(1, bool)).to_pylist()[0]
+            got = store.pk_update(pkey, assign_row, self._tctx(store))
+            n = 0 if got is None else 1
+            if n:
+                self._log_binlog("update", db_name, s.table.name, rows=None,
+                                 statement=_stmt_image("update", s),
+                                 affected=n)
+            return Result(affected_rows=n)
         mask_fn = self._point_write_mask(store, s.where)
         if mask_fn is not None:
             # point update: evaluate assignments on the ONE matched row,
@@ -3748,8 +3915,6 @@ class Session:
                 return out
         else:
             mask_fn = self._host_mask(store, s.where)
-        changed = [name for name, _ in assigns]
-        db_name = s.table.database or self.current_db
         # row-image capture for CDC/matviews: old/new pairs let consumers
         # fold the delta instead of rescanning; only on the non-coupled
         # path (the global-index path dry-runs assign_fn, which would
@@ -3784,9 +3949,19 @@ class Session:
 
     def _delete(self, s: DeleteStmt) -> Result:
         store = self._store(s.table)
+        db_name = s.table.database or self.current_db
+        pkey = self._patch_key(store, s.where, db_name, s.table.name)
+        if pkey is not None:
+            # the row by the pk index, its lane a patch of the resident
+            # image (TableStore.pk_delete)
+            old = store.pk_delete(pkey, self._tctx(store))
+            if old is not None:
+                self._log_binlog("delete", db_name, s.table.name, rows=None,
+                                 statement=_stmt_image("delete", s),
+                                 affected=1)
+            return Result(affected_rows=int(old is not None))
         mask_fn = self._point_write_mask(store, s.where) or \
             self._host_mask(store, s.where)
-        db_name = s.table.database or self.current_db
         # row-image capture (see _update): outgoing rows let CDC consumers
         # retract exactly; count-verified, statement-image fallback
         captured: list = []
@@ -4039,6 +4214,26 @@ class Session:
             return None     # nested SELECT (subquery): inherit the scope
         if self._snapshot is not None:
             return (None, self._snapshot[1])
+        if self._sql_txn is not None and self.mesh is None:
+            # a transaction reads one snapshot, taken by its first SELECT
+            # and held to its end.  A table it has written reads its latest
+            # state instead, so its own PENDING rows show: its writer lease
+            # keeps every other writer off that table, and _tctx refused the
+            # lease if the table had moved past the snapshot, so that state
+            # is the snapshot's plus the transaction's own writes
+            tables = self._qos_tables([stmt])
+            if not any(t in self._sql_txn for t in tables):
+                return self._txn_snapshot()
+            if self._txn_pin is not None:
+                for t in tables:
+                    store = self.db.stores.get(t)
+                    if t not in self._sql_txn and store is not None and \
+                            store.mvcc_needs_versioned(self._txn_pin[1]):
+                        raise ConflictError(
+                            f"table {t} changed since this transaction's "
+                            "snapshot, and the statement also reads a "
+                            "table the transaction wrote")
+            return None
         if self._sql_txn is not None or self.mesh is not None:
             return None
         from ..expr.ast import AggCall
@@ -4070,6 +4265,26 @@ class Session:
             metrics.count_swallowed("snapshot.autopin")
             return None
         return (pid, ts)
+
+    def _txn_snapshot(self):
+        """(None, ts) of the open transaction's snapshot, pinned on the
+        first call (released by ``_release_txn_snapshot``)."""
+        from ..storage.mvcc import SnapshotRefused
+        if self._txn_pin is None:
+            ts = self.db.mvcc.now_ts()
+            try:
+                pid = self.db.mvcc.snapshots.pin(ts, query="txn",
+                                                 holder=self.user)
+            except SnapshotRefused:
+                metrics.count_swallowed("snapshot.txnpin")
+                return None
+            self._txn_pin = (pid, ts)
+        return (None, self._txn_pin[1])
+
+    def _release_txn_snapshot(self) -> None:
+        if self._txn_pin is not None:
+            self.db.mvcc.snapshots.unpin(self._txn_pin[0])
+            self._txn_pin = None
 
     def _snap_dirty(self, stmt) -> bool:
         """Does the pinned snapshot actually diverge from the live image
@@ -4258,7 +4473,7 @@ class Session:
             # stats-derived plan choices (dense group-by domains, key shifts)
             # go stale when data changes: replan on any version bump
             stale = any(self.db.stores.get(tk) is None or
-                        self.db.stores[tk].version != v
+                        self.db.stores[tk].plan_version != v
                         for tk, v in entry["versions"].items())
             # view redefinitions (possibly by ANOTHER session) change plans
             # without touching any table store version
@@ -4347,7 +4562,12 @@ class Session:
                 batches, shape_key, full_scan = self._collect_batches(plan)
         finally:
             self._param_subst = None
-        entry["versions"] = {p[0]: p[1] for p in shape_key}
+        # what the plan was built on: a pk-addressed write (a patch of the
+        # image) leaves it standing unless it widened a statistic
+        entry["versions"] = {
+            p[0]: (self.db.stores[p[0]].plan_version
+                   if p[1] >= 0 and p[0] in self.db.stores else p[1])
+            for p in shape_key}
         if norm is not None:
             from ..expr.params import PARAMS_KEY
             with trace.span("plan.bind"):
@@ -5602,7 +5822,8 @@ class Session:
         for tk, v, *_rest in shape_key:
             if v < 0 or tk not in full_scan:
                 return inline()
-        if any(k.startswith("__presort__") for k in batches):
+        if any(k.startswith("__presort__") for k in batches) \
+                or _dictionary_keys(batches):
             return inline()
         # members coalesce on (statement structure + pinned values, scan
         # shapes at exact versions, plan signature): they differ only in
@@ -5634,6 +5855,14 @@ class Session:
                                          progress.current())
             with trace.span("egress.compact"):
                 return self._egress_compact(out)
+        # the order keys of growing string dictionaries ride the batches
+        # as data (column/dictionary.py); their capacities join the shape
+        keys = _dictionary_keys(batches)
+        if keys:
+            batches = dict(batches)
+            batches[KEYS_KEY] = keys
+            shape_key = tuple(shape_key) + tuple(
+                (token, -2, int(a.shape[0])) for token, a in keys.items())
         # a plan with no scans has no sharded state (distribute leaves it
         # fully replicated) — run it as a plain single-device program
         mesh = self.mesh if batches else None
@@ -5673,7 +5902,9 @@ class Session:
 
         def get_aot_key():
             nonlocal aot_key
-            if aot_key is None and compilecache.AOT.enabled():
+            # a growing dictionary's key is this process's own lineage:
+            # no artifact holds it
+            if aot_key is None and compilecache.AOT.enabled() and not keys:
                 sig = entry.get("plan_sig")
                 if sig is None:
                     sig = entry["plan_sig"] = plan_signature(plan)
@@ -5689,6 +5920,11 @@ class Session:
             # trace+compile+run of the whole plan
             qp.checkpoint()
             pair = entry["compiled"].get(shape_key)
+            if pair is not None and _bakes_outgrown(pair[1], batches):
+                # the trace read a growing dictionary's values up to an n
+                # this input has passed: trace it again
+                entry["compiled"].pop(shape_key, None)
+                pair = None
             if pair is not None and len(pair) == 3 \
                     and pair[2] != versions_key:
                 # an AOT pair is pinned to the EXACT store versions it
@@ -5758,6 +5994,9 @@ class Session:
                         cms = (time.perf_counter() - t0) * 1e3
                         metrics.compile_ms.observe(cms)
                         sp.set(compiled=True)
+                        # a new executable is long-lived state: out of
+                        # the collections of serving with what it made
+                        gcpause.settle("compile")
                         compiled_here = True
                         # device-resource accounting (compile seam): the cost/
                         # memory analysis itself is LAZY — only the identity,
@@ -5917,6 +6156,11 @@ class Session:
 
         if batch.sel is None or batch.live_prefix or len(batch) == 0:
             return compact(batch)
+        if len(batch) <= EGRESS_HOST_LANES:
+            # a result this small crosses whole, with its mask, in the one
+            # transfer of ``to_arrow``, which keeps the live rows on the
+            # host: no count to wait for, no device gather
+            return batch
         sel = batch.sel_mask()
         cs = jnp.cumsum(sel.astype(jnp.int32))
         with trace.span("egress.count"):

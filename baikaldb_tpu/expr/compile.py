@@ -30,7 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..column.batch import Column, ColumnBatch
-from ..column.dictionary import NULL_CODE, Dictionary, merge as dict_merge
+from ..column.dictionary import NULL_CODE, Dictionary, merge as dict_merge, \
+    order_data
 from ..types import LType, promote
 from ..utils import datetime_kernels as dtk
 from .ast import AggCall, Call, ColRef, Expr, Lit, Param
@@ -578,7 +579,7 @@ def _compare(op, a, b, batch) -> Column:
     if a.ltype is LType.STRING or b.ltype is LType.STRING:
         if a.ltype is LType.STRING and b.ltype is LType.STRING:
             a, b = _align_string_columns(a, b)
-            x, y = a.data, b.data
+            x, y = order_data(a), order_data(b)
         else:
             sc = a if a.ltype is LType.STRING else b
             oc = b if a.ltype is LType.STRING else a
@@ -598,9 +599,10 @@ def _cmp_code_literal(op, c: Column, s: str) -> Column:
 
 
 def _cmp_code_bounds(op, c: Column, lo, hi) -> Column:
-    """Range test over dictionary codes; lo/hi may be trace-time host ints
+    """Range test over dictionary codes in order space (their order keys
+    once the dictionary has a tail); lo/hi may be trace-time host ints
     (baked literal) or traced scalars (strcmp param)."""
-    codes = c.data
+    codes = order_data(c)
     if op == "eq":
         data = (codes >= lo) & (codes < hi)
     elif op == "ne":
@@ -861,7 +863,8 @@ def _like_impl(e, batch, negate):
     plain = p.replace("\\%", "").replace("\\_", "")
     if "%" not in plain.rstrip("%") and "_" not in plain and p.endswith("%") and not p.endswith("\\%"):
         lo, hi = a.dictionary.prefix_range(p[:-1].replace("\\%", "%").replace("\\_", "_"))
-        hit = (a.data >= lo) & (a.data < hi)
+        x = order_data(a)
+        hit = (x >= lo) & (x < hi)
     else:
         rx = re.compile(_like_to_regex(p), re.S)
         mask = a.dictionary.match_mask(lambda v: rx.match(v) is not None)

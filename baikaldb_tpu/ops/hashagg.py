@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from ..column.batch import Column, ColumnBatch
+from ..column.dictionary import order_data
 from . import segments
 from .segments import (scan_identity, seg_max, seg_min, seg_scan, seg_sum,
                        shift_lanes)
@@ -81,6 +82,25 @@ def _sum_dtype(c: Column):
     return jnp.int64 if c.ltype.is_integer else jnp.float64
 
 
+def _by_order(c: Column) -> Column:
+    """What MIN / MAX compare: the column, or — a string column whose
+    dictionary has a tail, so its codes are not in value order — each
+    row's order key above its code in one int64 (``_from_order`` takes the
+    code back out)."""
+    if not getattr(c.dictionary, "tailed", False):
+        return c
+    key = order_data(c).astype(jnp.int64)
+    code = jnp.maximum(c.data, 0).astype(jnp.int64)
+    return Column((key << 32) | code, c.validity, LType.INT64)
+
+
+def _from_order(r: Column) -> Column:
+    if not getattr(r.dictionary, "tailed", False):
+        return r
+    return Column((r.data & 0xFFFFFFFF).astype(jnp.int32), r.validity,
+                  r.ltype, r.dictionary)
+
+
 def _minmax_identity(c: Column, is_min: bool):
     info = (jnp.iinfo if c.data.dtype.kind in "iu" else jnp.finfo)(c.data.dtype)
     return info.max if is_min else info.min
@@ -120,10 +140,12 @@ def _scalar_one(batch: ColumnBatch, s: AggSpec, sel) -> Column:
         any_ = ct > 0
         return Column((sm / jnp.maximum(ct, 1))[None], any_[None], LType.FLOAT64)
     if s.op in ("min", "max"):
-        ident = _minmax_identity(c, s.op == "min")
-        v = jnp.where(live, c.data, ident)
+        src = _by_order(c)
+        ident = _minmax_identity(src, s.op == "min")
+        v = jnp.where(live, src.data, ident)
         r = (jnp.min(v) if s.op == "min" else jnp.max(v))[None]
-        return Column(r, jnp.any(live)[None], c.ltype, c.dictionary)
+        return _from_order(Column(r, jnp.any(live)[None], c.ltype,
+                                  c.dictionary))
     if s.op == "sumsq":
         x = c.data.astype(jnp.float64)
         v = jnp.sum(jnp.where(live, x * x, 0.0))[None]
@@ -473,10 +495,11 @@ def _segment_one(batch: ColumnBatch, s: AggSpec, gid, ng: int, sel,
         return Column(sm / jnp.maximum(ct, 1), ct > 0, LType.FLOAT64)
     if s.op in ("min", "max"):
         is_min = s.op == "min"
+        src = _by_order(c)
         v = (seg_min if is_min else seg_max)(
-            jnp.where(live, c.data, _minmax_identity(c, is_min)), gid_v,
+            jnp.where(live, src.data, _minmax_identity(src, is_min)), gid_v,
             num_segments=ng + 1)[:ng]
-        return Column(v, ct > 0, c.ltype, c.dictionary)
+        return _from_order(Column(v, ct > 0, c.ltype, c.dictionary))
     if s.op == "sumsq":
         x = c.data.astype(jnp.float64)
         v = seg_sum(jnp.where(live, x * x, 0.0), gid_v, num_segments=ng + 1)[:ng]

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from ..column.batch import Column, ColumnBatch
+from ..column.dictionary import order_data
 from .compact import stable_partition
 
 _I32_MAX = 0x7FFFFFFF
@@ -129,7 +130,7 @@ class SortKey:
 
 
 def _orderable(c: Column):
-    d = c.data
+    d = order_data(c)
     if d.dtype == jnp.bool_:
         d = d.astype(jnp.int32)
     return d

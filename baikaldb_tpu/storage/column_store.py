@@ -28,9 +28,9 @@ from __future__ import annotations
 
 import os
 import threading
-from dataclasses import dataclass, field
 from typing import Optional
 
+import jax
 import numpy as np
 import pyarrow as pa
 import pyarrow.parquet as pq
@@ -43,6 +43,9 @@ from ..utils import metrics
 from .rowstore import ConflictError, KeyCodec, RowTable, Txn
 
 DEFAULT_REGION_ROWS = 1 << 20  # split threshold on the row axis
+# an INSERT of at most this many rows into a pk_patchable table is patched
+# into the resident image; a larger one appends to the region and rebuilds
+PATCH_INSERT_ROWS = 64
 ROWID = "__rowid"              # hidden parquet column carrying row identity
 
 
@@ -104,28 +107,176 @@ def schema_to_arrow(schema: Schema) -> pa.Schema:
                       for f in schema.fields])
 
 
-@dataclass
+class _Overlay:
+    """Rows a region's pk-addressed writes changed since its Arrow table
+    was last rebuilt: ``rows`` the live new versions (updated or
+    inserted) by rowid, ``dead`` the rowids of the Arrow table's rows that
+    are no longer live as stored (deleted or updated)."""
+
+    __slots__ = ("rows", "dead")
+
+    def __init__(self):
+        self.rows: dict = {}
+        self.dead: set = set()
+
+
 class Region:
     """One row-range shard of a table (reference Region minus Raft, which
-    arrives with the distributed store tier)."""
-    region_id: int
-    data: pa.Table
-    rowids: Optional[np.ndarray] = None      # int64 [num_rows]
-    version: int = 1
-    # table-partition id this region belongs to (reference: partitioned
-    # tables place each partition's data in its own regions,
-    # schema_factory.h:427-533); -1 = unpartitioned/unknown
-    part: int = -1
-    _device: Optional[ColumnBatch] = None
-    _device_version: int = -1
+    arrives with the distributed store tier).
 
-    def __post_init__(self):
-        if self.rowids is None:
-            self.rowids = np.zeros(self.data.num_rows, np.int64)
+    A pk-addressed write (``TableStore.pk_update`` / ``pk_delete`` /
+    ``insert_rows``) changes a region through its overlay and leaves the
+    Arrow table as it is; reading ``data`` or ``rowids`` folds the overlay
+    into a new table first, so every other reader sees one plain table."""
+
+    def __init__(self, region_id: int, data: pa.Table,
+                 rowids: Optional[np.ndarray] = None, version: int = 1,
+                 part: int = -1):
+        self.region_id = region_id
+        self._data = data
+        self._rowids = np.zeros(data.num_rows, np.int64) \
+            if rowids is None else rowids
+        self.version = version
+        # table-partition id this region belongs to (reference: partitioned
+        # tables place each partition's data in its own regions,
+        # schema_factory.h:427-533); -1 = unpartitioned/unknown
+        self.part = part
+        self._ov: Optional[_Overlay] = None
+        # set while a transaction's pk writes are open on the region: a read
+        # of ``data`` merges without folding, so a rollback finds the Arrow
+        # table it started from
+        self.hold = False
+        self._merge_cache = (None, None)
+        self._rising = (None, False)    # (rowids array, does it rise?)
+        self._device: Optional[ColumnBatch] = None
+        self._device_version = -1
+
+    @property
+    def data(self) -> pa.Table:
+        if self._ov is not None:
+            if self.hold:
+                return self._merged()[0]
+            self._fold()
+        return self._data
+
+    @data.setter
+    def data(self, t: pa.Table) -> None:
+        if self._ov is not None:
+            self._fold()
+        self._data = t
+
+    @property
+    def rowids(self) -> np.ndarray:
+        if self._ov is not None:
+            if self.hold:
+                return self._merged()[1]
+            self._fold()
+        return self._rowids
+
+    @rowids.setter
+    def rowids(self, r: np.ndarray) -> None:
+        if self._ov is not None:
+            self._fold()
+        self._rowids = r
 
     @property
     def num_rows(self) -> int:
-        return self.data.num_rows
+        ov = self._ov
+        n = self._data.num_rows
+        return n if ov is None else n - len(ov.dead) + len(ov.rows)
+
+    # -- the overlay ------------------------------------------------------
+    def _overlay(self) -> _Overlay:
+        if self._ov is None:
+            self._ov = _Overlay()
+        return self._ov
+
+    def _base_pos(self, rid: int) -> int:
+        """Position of ``rid`` in the Arrow table, or -1: a binary search
+        when its rowids rise (a bulk load, appends), else a scan."""
+        base = self._rowids
+        if self._rising[0] is not base:
+            self._rising = (base, bool(len(base) < 2
+                                       or (base[1:] > base[:-1]).all()))
+        if self._rising[1]:
+            i = int(np.searchsorted(base, rid))
+            return i if i < len(base) and int(base[i]) == rid else -1
+        found = np.nonzero(base == rid)[0]
+        return int(found[0]) if found.size else -1
+
+    def row(self, rid: int) -> Optional[dict]:
+        """The live row ``rid`` as a dict, without a fold (None: absent)."""
+        ov = self._ov
+        if ov is not None:
+            hit = ov.rows.get(rid)
+            if hit is not None:
+                return dict(hit)
+            if rid in ov.dead:
+                return None
+        return self._base_row(rid)
+
+    def put_row(self, rid: int, row: Optional[dict]) -> None:
+        """Row ``rid`` becomes ``row`` (None: deleted) in the overlay; a
+        row put back as the Arrow table stores it leaves the overlay."""
+        ov = self._overlay()
+        in_base = self._base_pos(rid) >= 0
+        if row is None:
+            ov.rows.pop(rid, None)
+        elif in_base and row == self._base_row(rid):
+            ov.rows.pop(rid, None)
+            ov.dead.discard(rid)
+            in_base = False
+        else:
+            ov.rows[rid] = row
+        if in_base:
+            ov.dead.add(rid)
+        if not ov.rows and not ov.dead:
+            self._ov = None
+
+    def _base_row(self, rid: int) -> Optional[dict]:
+        at = self._base_pos(rid)
+        return None if at < 0 else self._data.slice(at, 1).to_pylist()[0]
+
+    def _fold(self) -> None:
+        """The Arrow table rebuilt with the overlay folded in."""
+        self._data, self._rowids = self._merged()
+        self._ov = None
+
+    def _merged(self) -> tuple:
+        """(a new Arrow table from the old one and the overlay: updated
+        rows in place, deleted rows gone, inserted rows at the end in
+        rowid order, its rowids)."""
+        ov = self._ov
+        if not ov.rows and not ov.dead:
+            return self._data, self._rowids
+        key = (self.version, id(self._data), id(ov))
+        if self._merge_cache[0] == key:
+            return self._merge_cache[1]
+        metrics.overlay_folds.add(1)
+        base, rids = self._data, self._rowids
+        n = base.num_rows
+        extra = list(ov.rows)
+        at = {rid: n + j for j, rid in enumerate(extra)}
+        keep = np.ones(n, bool)
+        order = np.arange(n, dtype=np.int64)
+        if ov.dead:
+            dead = np.fromiter(ov.dead, np.int64, count=len(ov.dead))
+            for p in np.nonzero(np.isin(rids, dead))[0]:
+                j = at.pop(int(rids[p]), None)
+                if j is None:
+                    keep[p] = False
+                else:
+                    order[p] = j
+        tail = sorted(at)       # inserted rows in rowid (= arrival) order
+        order = np.concatenate([order[keep],
+                                np.asarray([at[r] for r in tail], np.int64)])
+        new_ids = np.concatenate([rids, np.asarray(extra, np.int64)])[order]
+        ext = pa.Table.from_pylist([ov.rows[r] for r in extra],
+                                   schema=base.schema)
+        out = (pa.concat_tables([base, ext]).take(order).combine_chunks(),
+               new_ids)
+        self._merge_cache = (key, out)
+        return out
 
     def device_batch(self) -> ColumnBatch:
         """Device-resident batch, rebuilt only when the region mutates."""
@@ -145,16 +296,29 @@ class TxnContext:
         self.row_txn: Txn = store.row_table.begin()
         self._snap = None
         self._mvcc_pre = None
+        # pk-addressed writes (TableStore.pk_update / pk_delete / the
+        # patched insert): (rowid, row before) in order, undone in reverse;
+        # ``_snap_at`` is how many there were when a bulk write took the
+        # region capture below
+        self.undo: list = []
+        self._snap_at = 0
 
     def _capture(self):
-        """Called by the store (under its lock) before the first mutation."""
+        """Called by the store (under its lock) before the first mutation
+        that rebuilds a region (references only: nothing a row)."""
         if self._snap is None:
             st = self.store
             self._snap = (list(st.regions),
-                          [(r, r.data, r.rowids, r.version) for r in st.regions])
-            # MVCC preimage rides the same capture: rollback must also
-            # unwind PENDING stamps and this txn's history entries
-            self._mvcc_pre = st._mvcc.capture()
+                          [(r, r._data, r._rowids, r._ov, r.version)
+                           for r in st.regions])
+            self._snap_at = len(self.undo)
+            self._capture_mvcc()
+
+    def _capture_mvcc(self):
+        # MVCC preimage rides the first capture: rollback must also unwind
+        # PENDING stamps and this txn's history entries
+        if self._mvcc_pre is None:
+            self._mvcc_pre = self.store._mvcc.capture()
 
     def commit(self, commit_ts: int | None = None):
         """``commit_ts``: the decide-time MVCC stamp — multi-table commits
@@ -205,17 +369,22 @@ class TxnContext:
     def _restore_preimage(self):
         st = self.store
         with st._lock:
+            undo = self.undo
             if self._snap is not None:
+                # the region capture supersedes every pk write after it
+                undo = undo[:self._snap_at]
                 regions, states = self._snap
                 st.regions = list(regions)
-                for r, data, rowids, version in states:
-                    r.data = data
-                    r.rowids = rowids
+                for r, data, rowids, ov, version in states:
+                    r._data, r._rowids, r._ov = data, rowids, ov
                     # versions stay monotonic so stale device/stats caches
                     # can never alias a rolled-back state
                     r.version = max(r.version, version) + 1
                 st._mutations += 1
                 st._pk_stale = True
+            for rid, row in reversed(undo):
+                st._pk_restore(rid, row)
+            self.undo = []
             if self._mvcc_pre is not None:
                 st._mvcc.restore(self._mvcc_pre)
 
@@ -293,6 +462,41 @@ def commit_group(tctxs: list["TxnContext"]) -> None:
         t.commit(commit_ts=commit_ts)
 
 
+class _LaneImage:
+    """The resident device image of a table and what maps it to rows.
+
+    ``lane_rowid`` is the rowid each lane holds (-1: dead or free),
+    ``free`` the first lane of the never-used tail, ``holes`` the dead
+    lanes below it, which an insert takes before the tail, ``pending`` the lanes
+    pk-addressed writes changed since the last patch (lane -> the row, or
+    None: dead), and ``pk_vals`` / ``pk_lanes`` the primary key's values in
+    key order with the lane of each (-1: the key's row is deleted)."""
+
+    __slots__ = ("key", "batch", "lane_rowid", "free", "holes", "pending",
+                 "pk_vals", "pk_lanes")
+
+    def __init__(self, key, batch, lane_rowid, free):
+        self.key, self.batch = key, batch
+        self.lane_rowid, self.free = lane_rowid, free
+        self.holes: list = []
+        self.pending: dict = {}
+        self.pk_vals = None
+        self.pk_lanes = None
+
+
+@jax.jit
+def _image_patch(sel, idx, live, leaves):
+    """Changed lanes scattered into every column of the image and its
+    ``sel``; padded lanes point past the end and drop."""
+    out = []
+    for data, d, validity, v in leaves:
+        nd = data.at[idx].set(d.astype(data.dtype), mode="drop")
+        nv = None if validity is None else \
+            validity.at[idx].set(v, mode="drop")
+        out.append((nd, nv))
+    return sel.at[idx].set(live, mode="drop"), out
+
+
 class TableStore:
     """All regions of one table + DML on the host tier.
 
@@ -317,6 +521,13 @@ class TableStore:
         self._lock = GuardedLock("store.table_lock", rank=self.RANK,
                                  reentrant=True)
         self._mutations = 0
+        # pk-addressed writes (pk_update / pk_delete / the patched insert)
+        # bump _patch_seq, not _mutations: the resident image and the pk key
+        # order take them as patches; _plan_gen moves when one of them
+        # widens a statistic a plan was built on
+        self._patch_seq = 0
+        self._plan_gen = 0
+        self._img: Optional[_LaneImage] = None
         self._next_region = 1
         self._next_rowid = 1
         self._rowid_pool = 0          # meta-allocated range (replicated)
@@ -524,6 +735,8 @@ class TableStore:
         with self._lock:
             if self._writer is tctx:
                 self._writer = None
+            for r in self._regions:
+                r.hold = False
 
     def _writer_check(self, tctx: Optional[TxnContext]):
         """Statement-level write admission: an open transaction holds the
@@ -580,42 +793,145 @@ class TableStore:
 
     @property
     def version(self) -> int:
-        """Monotonic mutation counter.  NOT derived from region versions:
-        transaction rollback rebuilds regions, and a derived version could
-        revisit an old value and alias stale device/stats caches."""
+        """Monotonic mutation counter: every write, patches included.  NOT
+        derived from region versions: transaction rollback rebuilds
+        regions, and a derived version could revisit an old value and
+        alias stale device/stats caches."""
         with self._lock:
-            return self._mutations
+            return self._mutations + self._patch_seq
+
+    @property
+    def plan_version(self) -> int:
+        """What a cached plan and the statistics it read are valid for: a
+        pk-addressed write moves it only where it widened a statistic."""
+        with self._lock:
+            return self._mutations + self._plan_gen
 
     def device_table_batch(self) -> ColumnBatch:
         """Whole-table device batch with table-wide string dictionaries.
 
         Built from the concatenated snapshot so every string column has ONE
         dictionary (regions sharing dictionaries is what lets per-region
-        partial aggregates merge by code).  Cached until any region mutates.
+        partial aggregates merge by code), each the first snapshot of a
+        growing one (column/dictionary.Lineage).  Kept until a write that
+        is not pk-addressed; a pk-addressed write reaches it as a patch
+        (``_flush_patches``), so lanes and row positions part ways: a
+        deleted row's lane turns dead, an inserted row takes the next lane
+        of the dead tail.
 
         With ``FLAGS.batch_bucketing`` the batch pads to a power-of-two
         capacity bucket (column/batch.bucket_capacity) with a dead-row tail
         (``sel=False``), so DML that moves the row count inside one bucket
         keeps the device shape — compiled executables scanning this table
         stay valid and only a bucket crossing retraces."""
-        from ..column.batch import bucket_capacity, pad_batch
+        with self._lock:
+            img = self._image()
+            if img.pending:
+                self._flush_patches(img)
+                img = self._image()     # built again if the patch could not
+            return img.batch
+
+    def _image_key(self) -> tuple:
         from ..utils.flags import FLAGS
 
-        with self._lock:
-            v = self.version
-            bucketing = bool(FLAGS.batch_bucketing)
-            key = (v, bucketing,
-                   int(FLAGS.batch_bucket_min) if bucketing else 0)
-            if getattr(self, "_table_device", None) is not None and \
-                    getattr(self, "_table_device_key", None) == key:
-                return self._table_device
-            b = ColumnBatch.from_arrow(self.snapshot())
-            if bucketing:
-                b = pad_batch(b, bucket_capacity(
-                    len(b), int(FLAGS.batch_bucket_min)))
-            self._table_device = b
-            self._table_device_key = key
-            return self._table_device
+        bucketing = bool(FLAGS.batch_bucketing)
+        return (self._mutations, bucketing,
+                int(FLAGS.batch_bucket_min) if bucketing else 0)
+
+    def _image(self) -> "_LaneImage":
+        """The lane image of the current structural version, built when
+        there is none (caller holds the lock)."""
+        from ..column.batch import bucket_capacity, pad_batch
+        from ..column.dictionary import Dictionary
+        from dataclasses import replace
+
+        key = self._image_key()
+        img = self._img
+        if img is not None and img.key == key:
+            return img
+        if img is not None:
+            metrics.image_rebuilds.add(1)
+        snap = self.snapshot()
+        b = ColumnBatch.from_arrow(snap)
+        b = ColumnBatch(b.names, [
+            replace(c, dictionary=Dictionary.growable(c.dictionary))
+            if c.dictionary is not None else c for c in b.columns])
+        n = len(b)
+        if key[1]:
+            b = pad_batch(b, bucket_capacity(n, key[2]))
+        lanes = np.full(len(b), -1, np.int64)
+        if n:
+            lanes[:n] = np.concatenate([r.rowids for r in self.regions])
+        img = _LaneImage(key, b, lanes, n)
+        pk = self.pk_range_column()
+        if pk is not None:
+            # the primary key in key order, by lane: sorted once an image,
+            # then kept by the pk-addressed writes
+            if getattr(self, "_pk_order_built", False):
+                metrics.pk_order_rebuilds.add(1)
+            self._pk_order_built = True
+            svals, spos = self._secondary_order(pk)
+            img.pk_vals, img.pk_lanes = svals, spos.astype(np.int64)
+        self._img = img
+        return img
+
+    def _flush_patches(self, img: "_LaneImage") -> None:
+        """The rows pk-addressed writes changed since the last flush, laid
+        into the resident image by one program: every column of each
+        changed lane, and its ``sel`` bit (caller holds the lock)."""
+        from ..column.batch import _arrow_to_numpy
+        from ..column.dictionary import NULL_CODE
+        from dataclasses import replace
+
+        pending, img.pending = img.pending, {}
+        lanes = sorted(pending)
+        live = [pending[ln] is not None for ln in lanes]
+        rows = [pending[ln] for ln in lanes if pending[ln] is not None]
+        with trace.timed("image.patch", lanes=len(lanes)) as sp:
+            b = img.batch
+            m = len(lanes)
+            width = max(8, 1 << (m - 1).bit_length())
+            idx = np.full(width, len(b), np.int32)
+            idx[:m] = lanes
+            sel = np.zeros(width, bool)
+            sel[:m] = live
+            upd = pa.Table.from_pylist(rows, schema=self.arrow_schema)
+            at = np.cumsum(live) - 1
+            leaves, cols = [], []
+            for name, c in zip(b.names, b.columns):
+                arr = upd.column(name)
+                if c.ltype is LType.STRING:
+                    lin = c.dictionary.lineage
+                    vals = arr.to_pylist()
+                    data = np.asarray(
+                        [NULL_CODE if v is None else lin.append(v)
+                         for v in vals], np.int32)
+                    valid = None if not arr.null_count else \
+                        np.asarray([v is not None for v in vals])
+                    c = replace(c, dictionary=c.dictionary.current())
+                else:
+                    data, valid, _, _ = _arrow_to_numpy(arr, arr.type)
+                if valid is not None and c.validity is None:
+                    # a NULL where the image keeps no validity: the
+                    # image's structure changes, so it is built again
+                    self._drop_image()
+                    return
+                d = np.zeros(width, c.data.dtype)
+                v = np.zeros(width, bool)
+                if rows:
+                    d[:m] = np.where(sel[:m], data[np.maximum(at, 0)], 0)
+                    v[:m] = sel[:m] & (True if valid is None
+                                      else valid[np.maximum(at, 0)])
+                leaves.append((c.data, d, c.validity,
+                               v if c.validity is not None else None))
+                cols.append(c)
+            out_sel, out = _image_patch(b.sel, idx, sel, leaves)
+            img.batch = ColumnBatch(
+                b.names, [replace(c, data=d, validity=vd)
+                          for c, (d, vd) in zip(cols, out)],
+                out_sel, None, live_prefix=b.live_prefix and not img.holes)
+        metrics.image_patches.add(1)
+        metrics.image_patch_ms.add(sp.ms)
 
     # -- MVCC (storage/mvcc.py) ------------------------------------------
     def attach_mvcc(self, runtime) -> None:
@@ -671,7 +987,9 @@ class TableStore:
             return
         wm = self._snap_reg.watermark(now_ts) if self._snap_reg is not None \
             else now_ts
-        self._mvcc.gc(wm)
+        with trace.span("mvcc.gc", versions=len(self._mvcc.history)):
+            self._mvcc.gc(wm)
+        metrics.mvcc_gc_sweeps.add(1)
 
     def mvcc_gc(self, watermark: int) -> int:
         """One watermark-driven sweep (MvccRuntime.gc / the GC thread)."""
@@ -745,7 +1063,7 @@ class TableStore:
         """Host-side column statistics for planner decisions (the analog of
         the reference's statistics.proto CM-sketch/histogram feed)."""
         with self._lock:
-            v = self.version
+            v = self.plan_version
             cache = getattr(self, "_stats_cache", None)
             if cache is None or cache[0] != v:
                 cache = (v, {})
@@ -769,8 +1087,7 @@ class TableStore:
         f = self.info.schema.field(column)
         if f.ltype is LType.STRING:
             batch = self.device_table_batch()
-            d = batch.column(column).dictionary
-            st["dict_size"] = 0 if d is None else len(d)
+            st["dict_size"] = _dict_domain(batch.column(column).dictionary)
         elif snap.num_rows:
             try:
                 mm = pc.min_max(col).as_py()
@@ -863,7 +1180,7 @@ class TableStore:
                 or f.ltype is LType.DATE or f.ltype.is_temporal):
             return None
         with self._lock:
-            v = self.version
+            v = self.plan_version
             cache = getattr(self, "_zone_cache", None)
             if cache is None or cache[0] != v:
                 cache = (v, {})
@@ -1081,13 +1398,15 @@ class TableStore:
         return None
 
     def _pk_range_slice(self, lo, hi):
-        """(positions in key order, i, j): the rows with pk in the closed
-        range [lo, hi] are positions[i:j].  Either bound may be None; a
-        literal the key's type cannot be compared with leaves its side
-        open (callers only need a superset)."""
+        """(image lanes in key order, i, j): the rows with pk in the closed
+        range [lo, hi] are lanes[i:j], less the -1 of deleted keys.
+        Either bound may be None; a literal the key's type cannot be
+        compared with leaves its side open (callers only need a
+        superset)."""
         col = self.pk_range_column()
         lt = self.info.schema.field(col).ltype
-        svals, spos = self._secondary_order(col)
+        img = self._image()
+        svals, spos = img.pk_vals, img.pk_lanes
         lo, hi = _zone_scalar(lo, lt), _zone_scalar(hi, lt)
         if svals.dtype.kind == "M":
             # DATE -> epoch days, DATETIME/TIMESTAMP -> epoch seconds: the
@@ -1105,18 +1424,20 @@ class TableStore:
         """How many rows hold pk in [lo, hi], for a table that has a
         pk_range_column; None when a bound does not compare with the key."""
         try:
-            _, i, j = self._pk_range_slice(lo, hi)
+            with self._lock:
+                lanes, i, j = self._pk_range_slice(lo, hi)
+                return int(np.count_nonzero(lanes[i:j] >= 0))
         except (TypeError, ValueError, OverflowError):
             return None
-        return j - i
 
     def pk_range_scan(self, lo, hi):
         """(ascending snapshot positions of the rows with pk in [lo, hi],
         the resident device image they index), both of ONE version: taken
         under one lock acquisition, as secondary_scan takes its pair."""
         with self._lock:
-            spos, i, j = self._pk_range_slice(lo, hi)
-            return np.sort(spos[i:j]), self.device_table_batch()
+            lanes, i, j = self._pk_range_slice(lo, hi)
+            pos = lanes[i:j]
+            return np.sort(pos[pos >= 0]), self.device_table_batch()
 
     def point_lookup(self, values: dict):
         """Primary-key point read from the host tier (no device program).
@@ -1134,9 +1455,9 @@ class TableStore:
             return None
         with self._lock:
             for r in self.regions:
-                hit = np.nonzero(r.rowids == rid)[0]
-                if hit.size:
-                    return r.data.slice(int(hit[0]), 1).to_pylist()[0]
+                row = r.row(rid)
+                if row is not None:
+                    return row
         return None
 
     # -- table partitioning (reference: range/hash partitions in
@@ -1483,6 +1804,16 @@ class TableStore:
         cols = {f.name: [r.get(f.name) for r in rows] for f in self.arrow_schema}
         table = pa.table(cols, schema=self.arrow_schema)
         with self._lock:
+            if len(rows) <= PATCH_INSERT_ROWS and self.pk_patchable() \
+                    and table.column(self._pk_cols[0]).null_count == 0:
+                self._patch_writer(tctx)
+                new_keys = self._check_duplicates(table)
+                rowids = self._pk_insert(table, tctx)
+                if new_keys and self._pk_index is not None \
+                        and not self._pk_stale:
+                    for k, rid in zip(new_keys, rowids):
+                        self._pk_index[k] = int(rid)
+                return
             self._writer_check(tctx)
             new_keys = self._check_duplicates(table)
             if self.partition_spec() is not None:
@@ -1664,6 +1995,245 @@ class TableStore:
                     pa.concat_tables(old_rows).combine_chunks(),
                     pa.concat_tables(new_rows_t).combine_chunks())
         return updated
+
+    # -- pk-addressed writes: patches, not rebuilds -----------------------
+    def pk_patchable(self) -> bool:
+        """Whether a write addressed by this table's primary key can take
+        the patch path: one region, one integer key column, local
+        (no raft tier, no deferred attach), unpartitioned, and a resident
+        image with a dead tail to insert into (``batch_bucketing``)."""
+        from ..utils.flags import FLAGS
+
+        col = self.pk_range_column()
+        with self._lock:
+            return (col is not None and bool(FLAGS.batch_bucketing)
+                    and self.info.schema.field(col).ltype.is_integer
+                    and self.replicated is None
+                    and self._attach_pending is None
+                    and self.partition_spec() is None
+                    and len(self.regions) == 1)
+
+    def _pk_slot(self, img: "_LaneImage", v: int) -> int:
+        """Index of key ``v`` in the image's key order, or -1."""
+        i = int(np.searchsorted(img.pk_vals, v))
+        return i if i < len(img.pk_vals) and img.pk_vals[i] == v else -1
+
+    def _patch_writer(self, tctx: Optional[TxnContext]) -> None:
+        """Write admission for a pk-addressed write: the writer lease, and
+        the MVCC preimage (no region capture: the undo log restores)."""
+        if self._writer is not None and self._writer is not tctx:
+            raise ConflictError(
+                f"table {self.info.name} locked by an open transaction")
+        if tctx is not None:
+            tctx._capture_mvcc()
+            self.regions[0].hold = True
+
+    def _pk_key_bytes(self, key: dict) -> bytes:
+        one = {c: pa.array([key[c]]).cast(self.arrow_schema.field(c).type)
+               for c in self._pk_cols}
+        return self._encode_pk_table(pa.table(one))[0]
+
+    def _pk_put(self, rid: int, key: int, row: Optional[dict],
+                insert: bool = False) -> None:
+        """Row ``rid`` (primary key ``key``) becomes ``row`` (None:
+        deleted; ``insert``: a new rowid) in the region's overlay, the pk
+        key order and the image's pending lanes (caller holds the lock)."""
+        reg = self.regions[0]
+        reg.put_row(rid, row)
+        reg.version += 1
+        self._patch_seq += 1
+        img = self._img
+        if img is None or img.key != self._image_key():
+            if row is not None:
+                self._stats_patch(row, insert)
+            return
+        i = self._pk_slot(img, key)
+        lane = int(img.pk_lanes[i]) if i >= 0 else -1
+        if row is None:
+            if lane >= 0:
+                img.pk_lanes[i] = -1
+                img.lane_rowid[lane] = -1
+                img.pending[lane] = None
+                img.holes.append(lane)
+            return
+        if lane < 0:
+            if img.holes:
+                # a deleted row's lane first: a table that deletes as much
+                # as it inserts never reaches the end of its bucket
+                lane = img.holes.pop()
+                insert = False          # not past the last lane
+            elif img.free >= len(img.lane_rowid):
+                self._drop_image()      # no dead lane left: a bucket crossing
+                self._stats_patch(row, insert)
+                return
+            else:
+                lane, img.free = img.free, img.free + 1
+            if i >= 0:
+                img.pk_lanes[i] = lane
+            else:
+                j = int(np.searchsorted(img.pk_vals, key))
+                img.pk_vals = np.insert(img.pk_vals, j, key)
+                img.pk_lanes = np.insert(img.pk_lanes, j, lane)
+            img.lane_rowid[lane] = rid
+        # a new string gets its code now, so the statistics (and the
+        # plan that reads them) see the dictionary the patch will carry
+        for c, name in zip(img.batch.columns, img.batch.names):
+            if c.dictionary is not None and row.get(name) is not None:
+                lin = c.dictionary.lineage
+                n = lin.n
+                lin.append(row[name])
+                if lin.n > n:
+                    metrics.dict_appends.add(1)
+        img.pending[lane] = row
+        self._stats_patch(row, insert)
+
+    def _drop_image(self) -> None:
+        """The resident image goes: the next read builds it, with new
+        dictionaries, so no statistic a plan read of the old one stands."""
+        self._img = None
+        self._plan_gen += 1
+        self._stats_cache = None
+        self._zone_cache = None
+
+    def _stats_patch(self, row: dict, insert: bool = False) -> None:
+        """Keep the cached statistics true for a written row: a zone map
+        or a min / max widens, an ``ordered`` column stops being so, a
+        string's domain grows with its dictionary; each such change of a
+        column statistic moves plan_version."""
+        zones = getattr(self, "_zone_cache", None)
+        if zones is not None and zones[0] == self.plan_version:
+            for col, zs in zones[1].items():
+                if zs is None:
+                    continue
+                v = _zone_scalar(row.get(col),
+                                 self.info.schema.field(col).ltype)
+                z = zs[0]
+                if row.get(col) is None:
+                    zs[0] = (None, None, True) if z is None else \
+                        (z[0], z[1], True)
+                elif v is None:
+                    zones[1][col] = None    # incomparable: prune nothing
+                elif z is None or z[0] is None:
+                    zs[0] = (v, v, bool(z and z[2]))
+                else:
+                    zs[0] = (min(z[0], v), max(z[1], v), z[2])
+        cache = getattr(self, "_stats_cache", None)
+        if cache is None or cache[0] != self.plan_version:
+            return
+        moved = False
+        for col, st in cache[1].items():
+            v = row.get(col)
+            if "dict_size" in st:
+                if self._img is None:
+                    continue
+                size = _dict_domain(
+                    self._img.batch.column(col).dictionary.current())
+                if size != st["dict_size"]:
+                    st["dict_size"], moved = size, True
+                continue
+            if v is None:
+                if st.get("ordered"):
+                    st["ordered"], moved = False, True
+                continue
+            v = _zone_scalar(v, self.info.schema.field(col).ltype)
+            # a row past the last lane in value order keeps the column
+            # ordered; any other write may not
+            tail = insert and v is not None and st.get("max") is not None \
+                and v >= st["max"]
+            if st.get("ordered") and not tail:
+                st["ordered"], moved = False, True
+            if st.get("min") is not None and v is not None:
+                if v < st["min"]:
+                    st["min"], moved = v, True
+                if v > st["max"]:
+                    st["max"], moved = v, True
+        if moved:
+            self._plan_gen += 1
+            self._stats_cache = (self.plan_version, cache[1])
+            if zones is not None and zones[0] == self.plan_version - 1:
+                self._zone_cache = (self.plan_version, zones[1])
+
+    def _pk_restore(self, rid: int, row: Optional[dict]) -> None:
+        """Undo of one pk-addressed write (a rolled-back transaction): row
+        ``rid`` back to ``row`` (None: it did not exist)."""
+        pkc = self._pk_cols[0]
+        cur = self.regions[0].row(rid)
+        key = (row or cur or {}).get(pkc)
+        if key is None:
+            return
+        self._pk_put(rid, key, row)
+        if self._pk_index is not None and not self._pk_stale:
+            kb = self._pk_key_bytes({pkc: key})
+            if row is None:
+                self._pk_index.pop(kb, None)
+            else:
+                self._pk_index[kb] = rid
+
+    def pk_update(self, key: dict, assign,
+                  tctx: Optional[TxnContext] = None):
+        """``UPDATE ... WHERE <pk> = v`` on a pk_patchable table: the row
+        found by the pk index, ``assign(old_row) -> new_row`` (the key
+        columns unchanged), the new version written as a patch.  -> (old
+        row, new row), or None when no row holds the key."""
+        with self._lock:
+            self._patch_writer(tctx)
+            rid = self._ensure_pk_index().get(self._pk_key_bytes(key))
+            if rid is None:
+                return None
+            with trace.span("dml.pk_write", op="update"):
+                old = self.regions[0].row(rid)
+                new = assign(dict(old))
+                self._write_hot([dict(new, **{ROWID: rid})], tctx)
+                from ..utils.flags import FLAGS
+                if FLAGS.mvcc:
+                    # the dying version closes where the new one opens
+                    dts = self._mvcc_record_dead([old], [rid], tctx)
+                    self._mvcc.stamp([rid], dts)
+                self._pk_put(rid, old[self._pk_cols[0]], new)
+                if tctx is not None:
+                    tctx.undo.append((rid, old))
+            return old, new
+
+    def pk_delete(self, key: dict, tctx: Optional[TxnContext] = None):
+        """``DELETE ... WHERE <pk> = v`` on a pk_patchable table, as a
+        patch.  -> the deleted row, or None when no row holds the key."""
+        with self._lock:
+            self._patch_writer(tctx)
+            kb = self._pk_key_bytes(key)
+            idx = self._ensure_pk_index()
+            rid = idx.get(kb)
+            if rid is None:
+                return None
+            with trace.span("dml.pk_write", op="delete"):
+                old = self.regions[0].row(rid)
+                self._write_hot([{ROWID: rid, "__del": True}], tctx)
+                self._mvcc_record_dead([old], [rid], tctx)
+                self._pk_put(rid, old[self._pk_cols[0]], None)
+                idx.pop(kb, None)
+                if tctx is not None:
+                    tctx.undo.append((rid, old))
+            return old
+
+    def _pk_insert(self, table: pa.Table, tctx: Optional[TxnContext]):
+        """``insert_rows`` as patches: each row takes a fresh rowid, the
+        overlay, the key order and the next lane of the image's dead tail
+        (caller holds the lock and has checked the keys are new)."""
+        with trace.span("dml.pk_write", op="insert", rows=table.num_rows):
+            rows = table.to_pylist()
+            rowids = self._alloc_rowids(len(rows))
+            self._write_hot([dict(r, **{ROWID: int(rid)})
+                             for r, rid in zip(rows, rowids)], tctx)
+            auto_col = (self.info.options or {}).get("auto_increment")
+            pkc = self._pk_cols[0]
+            for row, rid in zip(rows, rowids):
+                if auto_col and row.get(auto_col) is not None:
+                    self._auto_incr = max(self._auto_incr or 0,
+                                          int(row[auto_col]))
+                self._pk_put(int(rid), row[pkc], row, insert=True)
+                if tctx is not None:
+                    tctx.undo.append((int(rid), None))
+            self._mvcc_stamp_new(rowids, tctx)
+            return rowids
 
     def _write_hot(self, recs: list[dict], tctx: Optional[TxnContext]):
         if not recs:
@@ -1879,6 +2449,19 @@ class TableStore:
             if not self.regions:
                 self.regions = [Region(self._alloc_region_id(),
                                        self.arrow_schema.empty_table())]
+
+
+def _dict_domain(d) -> int:
+    """The ``dict_size`` statistic: the codes a string column can hold — its
+    dictionary's size, or, once it grows, the capacity of its order keys,
+    so a dense GROUP BY domain outlives the appends inside it."""
+    from ..column.dictionary import _pow2
+
+    if d is None:
+        return 0
+    if not d.tailed:
+        return len(d)
+    return _pow2(len(d) + 1)
 
 
 # rows compared at a time by ``_non_decreasing``: a column out of order

@@ -219,7 +219,7 @@ class MvccState:
     """
 
     __slots__ = ("live_cts", "runs", "history", "high_water", "pending",
-                 "pending_runs", "reclaimed", "__weakref__")
+                 "pending_runs", "reclaimed", "dead_high", "__weakref__")
 
     def __init__(self):
         self.live_cts: dict[int, int] = {}
@@ -229,6 +229,9 @@ class MvccState:
         self.pending: set[int] = set()
         self.pending_runs = 0
         self.reclaimed = 0      # history versions GC has dropped, ever
+        # the largest delete_ts in history (PENDING while an open
+        # transaction's is there): no version is alive past it
+        self.dead_high = 0
         _STATES.add(self)
 
     # -- write-path hooks (caller holds the table lock) -----------------
@@ -313,6 +316,8 @@ class MvccState:
             elif cts == PENDING:
                 pend.discard(rid)
             hist.append((row, cts, dts))
+        if dts > self.dead_high:
+            self.dead_high = dts
 
     def restamp_pending(self, commit_ts: int) -> int:
         """Replace every PENDING stamp with the decide-time commit_ts —
@@ -333,11 +338,16 @@ class MvccState:
             self.pending_runs = 0
         if n and commit_ts > self.high_water:
             self.high_water = commit_ts
-        for i, (row, c, d) in enumerate(self.history):
-            if d == PENDING:
-                self.history[i] = (row, c, commit_ts)
-                n += 1
+        if self.dead_high == PENDING:
+            for i, (row, c, d) in enumerate(self.history):
+                if d == PENDING:
+                    self.history[i] = (row, c, commit_ts)
+                    n += 1
+            self._settle_dead_high()
         return n
+
+    def _settle_dead_high(self) -> None:
+        self.dead_high = max((h[2] for h in self.history), default=0)
 
     # -- preimage (transaction rollback) --------------------------------
     def capture(self) -> tuple:
@@ -357,6 +367,7 @@ class MvccState:
         self.pending = set(pending)
         self.runs = list(runs)
         self.pending_runs = pending_runs
+        self._settle_dead_high()
 
     def reset(self) -> None:
         """Table image replaced wholesale (truncate / load / DDL rebuild):
@@ -368,10 +379,13 @@ class MvccState:
         self.high_water = 0
         self.pending.clear()
         self.pending_runs = 0
+        self.dead_high = 0
 
     # -- read-path helpers ----------------------------------------------
     def versions_at(self, snap_ts: int) -> list[tuple[dict, int, int]]:
         """History versions alive at ``snap_ts`` (cts <= snap < dts)."""
+        if self.dead_high <= snap_ts:
+            return []
         return [h for h in self.history if h[1] <= snap_ts < h[2]]
 
     def live_newer_than(self, snap_ts: int) -> bool:
@@ -408,6 +422,7 @@ class MvccState:
         if self.high_water <= watermark:
             self.high_water = 0     # every committed stamp just settled
         reclaimed = before - len(self.history)
+        self._settle_dead_high()
         self.reclaimed += reclaimed
         if reclaimed:
             mvcc_gc_reclaimed.add(reclaimed)

@@ -581,6 +581,21 @@ index_scans = Counter("index_scans")
 # their key ranges matched
 pk_range_scans = Counter("pk_range_scans")
 pk_range_rows = Counter("pk_range_rows")
+# the write path of a pk-addressed statement (storage/column_store): the
+# patch programs that carried committed rows into the resident image, the
+# ms they took (host seam: the dispatch, not the chip), the builds of the
+# image that replaced one (a bucket crossing, a bulk write, DDL, a
+# rollback of a bulk write), the values appended to a growing string
+# dictionary (column/dictionary.Lineage), the pk key orders built again by
+# a sort, the Arrow tables rebuilt from a region's overlay, and the MVCC
+# history sweeps
+image_patches = Counter("image_patches")
+image_patch_ms = Counter("image_patch_ms")
+image_rebuilds = Counter("image_rebuilds")
+dict_appends = Counter("dict_appends")
+pk_order_rebuilds = Counter("pk_order_rebuilds")
+overlay_folds = Counter("overlay_folds")
+mvcc_gc_sweeps = Counter("mvcc_gc_sweeps")
 regions_pruned = Counter("regions_pruned")
 # XLA (re)traces of query programs: each count is one compile.  With capacity
 # bucketing on, an identical SELECT repeated across DML that stays inside one

@@ -644,8 +644,9 @@ def test_served_path_check_counters():
     assert (quiet, versioned) == (0, 2)
     assert pinned == base
     s.execute("SET SNAPSHOT = 0")
-    # inside BEGIN..COMMIT no pin is taken and neither site is reached
+    # inside BEGIN..COMMIT the transaction's own snapshot is pinned by its
+    # first SELECT: both sites answer "quiet" on an unwritten table
     s.execute("BEGIN")
     _, quiet, versioned = grew(lambda: s.query(q))
     s.execute("COMMIT")
-    assert (quiet, versioned) == (0, 0)
+    assert (quiet, versioned) == (2, 0)

@@ -418,8 +418,7 @@ STREAMED_SPANS = {
 RESIDENT_SPANS = {
     "parse.sql", "select.route", "plan.paramize", "plan.cache",
     "exec.batches", "access.path", "stream.source", "stage.resident",
-    "plan.bind", "exec.run", "exec.flags", "egress.compact", "egress.count",
-    "egress.arrow"}
+    "plan.bind", "exec.run", "exec.flags", "egress.compact", "egress.arrow"}
 STREAMED_Q = "SELECT g, SUM(v) FROM st WHERE v > {} GROUP BY g"
 RESIDENT_Q = "SELECT v FROM st WHERE id BETWEEN {} AND 60 ORDER BY v"
 
