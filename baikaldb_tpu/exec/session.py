@@ -5231,7 +5231,7 @@ class Session:
             # planned for resident rows in key order folds as the scatter
             # or the sort it would have been
             hit[1].unstream()
-        return ChunkSource(cs, keep)
+        return ChunkSource(cs, keep, streaming.staged_columns(n, cs.names))
 
     def _evict_access(self, table_key: str, version: int):
         """Drop access-path batches of older versions of this table, and

@@ -20,7 +20,9 @@ chunked segments (storage/streamchunks.py):
   device residency is two chunks (the one folding + the one prefetched);
 - a daemon thread stages chunk i+1 (coldfs read -> decode -> device put)
   through a Queue(maxsize=1) while chunk i folds, so host I/O overlaps
-  device compute.  The ``stream.prefetch`` span (the fold loop blocked on
+  device compute.  It stages only the scan's own columns
+  (``staged_columns``): what the plan never reads is never decoded or
+  put.  The ``stream.prefetch`` span (the fold loop blocked on
   the stager) vs the stager's ``stream.stage.*`` seams is the overlap
   measurement;
 - sorted-strategy accumulators carry an overflow bit folded into the
@@ -44,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from ..column.batch import ColumnBatch, bucket_capacity, concat_batches
+from ..expr.ast import referenced_columns
 from ..expr.params import PARAMS_KEY, bind_params
 from ..obs import trace
 from ..ops.hashagg import (ROW_AGGS, group_aggregate_dense,
@@ -120,6 +123,25 @@ def eligible(plan: PlanNode, scan_node=None):
     if scan_node is not None and scan is not scan_node:
         return None
     return above, agg, below, scan
+
+
+def staged_columns(scan: ScanNode, names: tuple) -> tuple:
+    """The columns a streamed scan stages: the plan's own pruned
+    ``scan.columns``, in the table's column order ``names``.  The scan's
+    pushed filter runs over the staged chunk, so every column it reads
+    must be among them — checked, since a missing one would fail inside
+    the fold's trace."""
+    wanted = set(scan.columns)
+    cols = tuple(n for n in names if n in wanted)
+    need = {f"{scan.label}.{c}" for c in wanted}
+    if scan.pushed_filter is not None:
+        need |= {c.name for c in referenced_columns(scan.pushed_filter)}
+    missing = need - {f"{scan.label}.{c}" for c in cols}
+    if missing:
+        raise executor.ExecError(
+            f"streamed scan of {scan.table_key} reads {sorted(missing)}"
+            " outside its staged columns")
+    return cols
 
 
 def stream_source(batches: dict):
@@ -255,13 +277,14 @@ class StreamRunner:
             if self.agg.strategy == "sorted" and self.keys:
                 want = self.agg.max_groups or 1024
                 self.acc_cap = min(bucket_capacity(want), self.cap_limit)
-        skey = (cs.capacity, cs.names,
-                tuple(str(cs._dtypes[n]) for n in cs.names),
-                tuple(bool(cs._has_validity[n]) for n in cs.names),
+        cols = source.columns
+        skey = (cs.capacity, cols,
+                tuple(str(cs._dtypes[n]) for n in cols),
+                tuple(bool(cs._has_validity[n]) for n in cols),
                 self.acc_cap)
         if self._jit_step is not None and self._skey == skey:
             return
-        chunk_struct = cs.device_struct()
+        chunk_struct = cs.device_struct(cols)
         # the accumulator's pytree is the FIXPOINT of the step: partial
         # columns can gain validity after one merge (count: None -> ct>0)
         # — iterate abstractly (eval_shape; nothing runs on device) until
@@ -327,7 +350,7 @@ class StreamRunner:
         return out
 
     def _fold(self, source: ChunkSource, params, qp, stats):
-        cs = source.chunks
+        cs, cols = source.chunks, source.columns
         # zero chunks survived pruning: fold chunk 0 with an all-False sel
         # so the aggregate still sees its (empty) input shape — COUNT
         # renders 0, not a missing row
@@ -342,9 +365,9 @@ class StreamRunner:
 
         def stage(i):
             if not split:
-                return cs.load_chunk(i, dead=dead)
+                return cs.load_chunk(i, dead, cols)
             mark = trace.clocks()
-            out = cs.load_chunk(i, dead=dead)
+            out = cs.load_chunk(i, dead, cols)
             waits.append(trace.wait_ms_since(mark))
             return out
 
@@ -363,6 +386,7 @@ class StreamRunner:
                 with trace.timed("stream.prefetch", chunk=i) as sp:
                     _i, (dev, nbytes, seams) = next(it)
                 metrics.stream_bytes_h2d.add(nbytes)
+                metrics.stream_columns_staged.add(len(cols))
                 stats["prefetch_wait_ms"] += sp.ms
                 stats["bytes_h2d"] += nbytes
                 for seam, ms in seams.items():

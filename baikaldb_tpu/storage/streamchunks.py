@@ -16,10 +16,11 @@ so table size is bounded by device memory.  This module breaks that bound:
 - per-chunk zone maps (min/max/has_null, canonicalized exactly like
   ``column_store._zone_scalar``) let selective predicates skip whole
   chunks before any host->device transfer;
-- ``load_chunk`` decodes one segment into a device ColumnBatch whose
-  pytree structure is IDENTICAL for every chunk of the set (validity
-  presence decided over the whole table, fixed capacity, explicit sel),
-  so the streaming fold's jitted step compiles once.
+- ``load_chunk`` decodes the columns one scan reads out of one segment
+  into a device ColumnBatch whose pytree structure is IDENTICAL for every
+  chunk of the set (validity presence decided over the whole table, fixed
+  capacity, explicit sel), so the streaming fold's jitted step compiles
+  once; columns the plan never reads are neither decoded nor put.
 
 The chunk set caches on the TableStore keyed by (version, chunk_rows),
 mirroring the ``_table_device`` idiom.
@@ -210,31 +211,37 @@ class StreamChunkSet:
                 keep.append(i)
         return keep
 
-    def device_struct(self):
-        """The ShapeDtypeStruct pytree every ``load_chunk`` result matches —
-        what the streaming fold traces against before any chunk loads."""
+    def device_struct(self, columns: tuple):
+        """The ShapeDtypeStruct pytree every ``load_chunk(..., columns)``
+        result matches — what the streaming fold traces against before any
+        chunk loads."""
         import jax
         import jax.numpy as jnp
 
         cap = self.capacity
         cols = []
-        for name in self.names:
+        for name in columns:
             data = jax.ShapeDtypeStruct((cap,), self._dtypes[name])
             validity = jax.ShapeDtypeStruct((cap,), jnp.bool_) \
                 if self._has_validity[name] else None
             cols.append(Column(data, validity, self.ltypes[name],
                                self._dicts[name]))
-        return ColumnBatch(self.names, cols,
+        return ColumnBatch(columns, cols,
                            jax.ShapeDtypeStruct((cap,), jnp.bool_),
                            None, live_prefix=True)
 
-    def load_chunk(self, i: int, dead: bool = False):
+    def load_chunk(self, i: int, dead: bool, columns: tuple):
         """-> (device ColumnBatch, bytes moved host->device, {seam: ms}).
 
-        Every chunk of the set has the same structure: fixed capacity,
-        explicit ``sel = arange < live`` (all-False when ``dead`` — the
-        empty-input stand-in when pruning removed every chunk), validity
-        arrays exactly on the columns the whole table has them.
+        Only ``columns`` (the scan's projection, in the table's column
+        order) are decoded out of the segment, padded and put, each with
+        its validity where the table has one; the set holds no per-query
+        state, so scans with different projections share it.  Every chunk
+        loaded with the same ``columns`` has the same structure: fixed
+        capacity, explicit ``sel = arange < live`` (all-False when
+        ``dead`` — the empty-input stand-in when pruning removed every
+        chunk), validity arrays exactly on the columns the whole table has
+        them.
 
         The three seams (segment read; parquet decode + numpy padding;
         host->device put, which returns once the transfer is enqueued) are
@@ -245,15 +252,20 @@ class StreamChunkSet:
         import jax.numpy as jnp
         import pyarrow.parquet as pq
 
+        read = []
+        for name in columns:
+            read.append(name)
+            if self._has_validity[name]:
+                read.append(f"__v_{name}")
         with trace.timed("stream.stage.read") as t_read:
             payload = self._read_segment(i)
         with trace.timed("stream.stage.decode") as sp:
-            t = pq.read_table(io.BytesIO(payload))
+            t = pq.read_table(io.BytesIO(payload), columns=read)
         decode_ms, h2d_ms = sp.ms, 0.0
         live = 0 if dead else self.live[i]
         cap = self.capacity
         cols, nbytes = [], 0
-        for name in self.names:
+        for name in columns:
             with trace.timed("stream.stage.decode") as sp:
                 data = t.column(name).to_numpy(zero_copy_only=False)
                 data = np.ascontiguousarray(data.astype(self._dtypes[name],
@@ -263,11 +275,8 @@ class StreamChunkSet:
                     data = np.concatenate([data, pad])
                 validity = None
                 if self._has_validity[name]:
-                    if f"__v_{name}" in t.column_names:
-                        validity = t.column(f"__v_{name}").to_numpy(
-                            zero_copy_only=False).astype(bool)
-                    else:
-                        validity = np.ones(self.live[i], dtype=bool)
+                    validity = t.column(f"__v_{name}").to_numpy(
+                        zero_copy_only=False).astype(bool)
                     if len(validity) < cap:
                         validity = np.concatenate(
                             [validity, np.zeros(cap - len(validity), bool)])
@@ -281,7 +290,7 @@ class StreamChunkSet:
         sel = np.arange(cap) < live
         nbytes += sel.nbytes
         with trace.timed("stream.stage.h2d") as sp:
-            batch = ColumnBatch(self.names, cols, jnp.asarray(sel), None,
+            batch = ColumnBatch(columns, cols, jnp.asarray(sel), None,
                                 live_prefix=True)
         return batch, nbytes, {"stream.stage.read": t_read.ms,
                                "stream.stage.decode": decode_ms,
@@ -290,13 +299,16 @@ class StreamChunkSet:
 
 class ChunkSource:
     """One execution's view of a chunk set: the chunk ids this query's
-    predicate zone maps kept.  This is what rides the batches dict in a
-    ScanNode's slot — exec/streaming.py recognizes it and takes the
-    chunk-folded path instead of feeding it to a jitted program."""
+    predicate zone maps kept, and the columns its scan reads (the
+    projection ``load_chunk`` stages).  This is what rides the batches
+    dict in a ScanNode's slot — exec/streaming.py recognizes it and takes
+    the chunk-folded path instead of feeding it to a jitted program."""
 
-    def __init__(self, chunks: StreamChunkSet, keep: list[int]):
+    def __init__(self, chunks: StreamChunkSet, keep: list[int],
+                 columns: tuple):
         self.chunks = chunks
         self.keep = keep
+        self.columns = columns
 
     def __len__(self) -> int:
         return self.chunks.capacity

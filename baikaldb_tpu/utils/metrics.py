@@ -781,14 +781,17 @@ flightrec_records = Counter("flightrec_records")
 flightrec_bundles = Counter("flightrec_bundles")
 # out-of-core streaming scans (exec/streaming.py): chunks folded, chunks
 # zone-map-skipped before any transfer, coldfs segment-read retries, fold
-# restarts after a group-capacity overflow, and bytes moved host->device
-# (how long the fold loop waited on the prefetcher is the stream.prefetch
-# span's, in the statement's query_log row)
+# restarts after a group-capacity overflow, bytes moved host->device, and
+# the columns put with them (the scan's projected count a chunk; sel and
+# validity ride uncounted).  How long the fold loop waited on the
+# prefetcher is the stream.prefetch span's, in the statement's query_log
+# row
 stream_chunks = Counter("stream_chunks")
 stream_chunks_skipped = Counter("stream_chunks_skipped")
 stream_retries = Counter("stream_retries")
 stream_restarts = Counter("stream_restarts")
 stream_bytes_h2d = Counter("stream_bytes_h2d")
+stream_columns_staged = Counter("stream_columns_staged")
 # pushed-down fragment execution (exec/fragments.py): per-region fragment
 # dispatches to store daemons, re-dispatches after a mid-flight split/
 # migration re-target (StaleRoutingError -> refresh -> re-slice), whole
