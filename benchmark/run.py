@@ -104,7 +104,28 @@ class Window:
         return self.t_close - self.t_open
 
 
-def run_txn(conn, txn, client: int) -> TxnRecord:
+class Wire:
+    """One client's connection and the statements it has prepared on it
+    (SQL with ``?`` -> statement id)."""
+
+    def __init__(self, conn):
+        self.conn, self.ids = conn, {}
+
+    def send(self, sql: str, prepare: str | None = None, args: tuple = ()):
+        """``sql`` as COM_QUERY, or, given ``prepare``, COM_STMT_EXECUTE of
+        it with ``args`` bound, prepared on its first use here (in the
+        warm-up, as sysbench prepares in ``thread_init``)."""
+        if prepare is None:
+            return self.conn.query(sql)
+        if prepare not in self.ids:
+            self.ids[prepare] = self.conn.prepare(prepare)
+        return self.conn.execute(self.ids[prepare], args)
+
+    def close(self) -> None:
+        self.conn.close()
+
+
+def run_txn(wire: Wire, txn, client: int) -> TxnRecord:
     """One transaction over the wire, every statement's answer kept; the
     call is a span of its own in a profiler trace."""
     from jax.profiler import TraceAnnotation
@@ -115,16 +136,16 @@ def run_txn(conn, txn, client: int) -> TxnRecord:
     with TraceAnnotation(txn.annotation):
         try:
             if txn.begin:
-                conn.query(txn.begin)
+                wire.send(txn.begin, txn.begin if txn.prepared else None)
             for s in txn.statements:
                 sr = StatementRecord(s.name, s.params, s.sql,
                                      time.perf_counter())
-                res = conn.query(s.sql)
+                res = wire.send(s.sql, s.prepare, s.args)
                 sr.t1 = time.perf_counter()
                 sr.columns, sr.rows = res.columns, res.rows
                 rec.statements.append(sr)
             if txn.commit:
-                conn.query(txn.commit)
+                wire.send(txn.commit, txn.commit if txn.prepared else None)
         except MySQLError as e:
             rec.error = f"{type(e).__name__}: {e}"
         rec.t1 = time.perf_counter()
@@ -135,9 +156,9 @@ class ClientThread(threading.Thread):
     """A closed-loop client: the next transaction goes out when the last
     one's COMMIT is acknowledged, until the deadline."""
 
-    def __init__(self, conn, client):
+    def __init__(self, wire, client):
         super().__init__(name=f"client-{client.index}")
-        self.conn, self.client = conn, client
+        self.wire, self.client = wire, client
         self.deadline = 0.0
         self.go = threading.Event()
         self.txns: list = []
@@ -147,18 +168,18 @@ class ClientThread(threading.Thread):
         self.go.wait()
         try:
             while time.perf_counter() < self.deadline:
-                self.txns.append(run_txn(self.conn, self.client.next(),
+                self.txns.append(run_txn(self.wire, self.client.next(),
                                          self.client.index))
         except BaseException as e:          # handed to main(), which raises
             self.crash = e
 
 
-def drive(conns: list, clients: list, seconds: float,
+def drive(wires: list, clients: list, seconds: float,
           meanwhile=lambda t_open: None) -> tuple:
     """Every client at once, each on its own connection, for ``seconds``;
     ``meanwhile(t_open)`` runs on this thread while they work.  Waits for
     every answer.  -> (t_open, the transactions, what ``meanwhile`` gave)."""
-    threads = [ClientThread(c, cl) for c, cl in zip(conns, clients)]
+    threads = [ClientThread(w, cl) for w, cl in zip(wires, clients)]
     for t in threads:
         t.start()
     t_open = time.perf_counter()
@@ -291,7 +312,7 @@ def main(argv: list | None = None) -> int:
 
     db = Database()
     srv = MySQLServer(db, port=0).start()
-    conns: list = []
+    wires: list = []
     try:
         t0 = time.perf_counter()
         loaded = resolve(config["loader"])(
@@ -311,16 +332,16 @@ def main(argv: list | None = None) -> int:
         warm = [trafficgen.Client(traffic, variables, args.seed, i,
                                   trafficgen.WARMUP_STREAM) for i in range(n)]
         for i in range(n):
-            conns.append(Connection(port=srv.port))
+            wires.append(Wire(Connection(port=srv.port)))
             for r in range(traffic.get("warmup_rounds", 2) * kinds):
-                rec = run_txn(conns[i], warm[i].next(), i)
+                rec = run_txn(wires[i], warm[i].next(), i)
                 if rec.error:
                     raise RuntimeError(f"warm-up failed: {rec.error}")
                 say(f"run: warm-up client {i} {rec.name} #{r // kinds}: "
                     f"{(rec.t1 - rec.t0) * 1e3:.1f} ms")
         together = traffic.get("warmup_together_s", 0)
         if together:
-            _, recs, _ = drive(conns, warm, together)
+            _, recs, _ = drive(wires, warm, together)
             bad = [x.error for x in recs if x.error]
             if bad:
                 raise RuntimeError(f"warm-up failed: {bad[0]}")
@@ -355,7 +376,7 @@ def main(argv: list | None = None) -> int:
         db.query_log.clear()
         before = read_counters(metrics)
         t_open, txns, (trace_dir, trace_span) = drive(
-            conns, clients, args.seconds, trace_some)
+            wires, clients, args.seconds, trace_some)
         t_close = max([x.t1 for x in txns] + [t_open + args.seconds])
         after = read_counters(metrics)
         grew = {k: v - before.get(k, 0) for k, v in after.items()}
@@ -368,8 +389,8 @@ def main(argv: list | None = None) -> int:
             device_kind=devices[0].device_kind, trace_span=trace_span)
         device = device_block(devices, cell["chips"])
     finally:
-        for c in conns:
-            c.close()
+        for w in wires:
+            w.close()
         srv.stop()
 
     if trace_dir is not None:

@@ -176,21 +176,38 @@ def test_cap_fill_reads_nothing_where_there_is_nothing():
         NS(counters={"join_cap_slots": 4096, "join_live_rows": 1024})) == 25.0
 
 
+# what BENCHMARK.json held before this cell (PR 28): every new name has to
+# sort after every one of these, whatever later cells add
+ACCEPTED = {
+    "configs": ["sysbench_1m", "tpch_sf1", "tpch_sf1_mesh4"],
+    "workloads": ["sysbench_1m.read_only", "tpch_sf1.q1q6",
+                  "tpch_sf1_mesh4.q1q3"],
+    "analytic_qps": ["tpch_sf1.q1q6", "tpch_sf1_mesh4.q1q3"],
+    "per_layer_last": "tpch_mesh4.scan_roofline",
+}
+
+
 def test_the_cells_files_sort_after_every_accepted_one():
     """``manifest.py`` lists files by name and the driver reads an entry in
     the middle of a list as an edit: this cell's configuration, cell and
-    metrics are the last of their lists."""
+    metrics come right after every entry the benchmark held before it."""
     m = json.loads((ROOT / "BENCHMARK.json").read_text())
-    assert m["configs"][-1]["name"] == CELL_FILE["config"]
-    assert m["workloads"][-1]["name"] == CELL
-    assert m["workloads"][-1]["chips"] == 1
+    n = len(ACCEPTED["configs"])
+    assert [c["name"] for c in m["configs"]][:n + 1] == \
+        ACCEPTED["configs"] + [CELL_FILE["config"]]
+    n = len(ACCEPTED["workloads"])
+    assert [w["name"] for w in m["workloads"]][:n + 1] == \
+        ACCEPTED["workloads"] + [CELL]
+    assert m["workloads"][n]["chips"] == 1
     e2e = {x["name"]: x for x in m["end_to_end"]}
-    assert e2e["analytic_qps"]["workloads"][-1] == CELL
+    n = len(ACCEPTED["analytic_qps"])
+    assert e2e["analytic_qps"]["workloads"][:n + 1] == \
+        ACCEPTED["analytic_qps"] + [CELL]
     names = [x["name"] for x in m["per_layer"]]
     mine = sorted(CELL_FILE["per_layer"])
-    assert names[-len(mine):] == mine
-    assert names[-len(mine) - 1] == "tpch_mesh4.scan_roofline"
-    for x in m["per_layer"][-len(mine):]:
+    at = names.index(ACCEPTED["per_layer_last"])
+    assert names[at + 1:at + 1 + len(mine)] == mine
+    for x in m["per_layer"][at + 1:at + 1 + len(mine)]:
         assert x["workloads"] == [CELL] and x["moves"] == "analytic_qps"
 
 

@@ -22,7 +22,14 @@ A traffic file (``benchmark/traffic/<name>.json``) holds
   ``begin``/``commit`` may be null (autocommit);
 - ``warmup_rounds``: how often each kind of transaction runs on each
   connection, one connection after another, before the window; and
-  ``warmup_together_s``: for how long all clients then run at once.
+  ``warmup_together_s``: for how long all clients then run at once;
+- ``prepared`` (default false): every statement, ``begin`` and ``commit``
+  included, goes out as sysbench's ``db-ps-mode=auto`` sends it: prepared
+  once a connection (COM_STMT_PREPARE of its template with a ``?`` for each
+  parameter, ``prepared_form``), then COM_STMT_EXECUTE with the parameters
+  bound in template order.  ``sql`` stays the text form, for the record and
+  the reference, and the draws do not change.  Otherwise a statement goes
+  out as COM_QUERY of its text.
 
 Every client draws from ``numpy.random.default_rng([seed, stream, client])``:
 the statements of a run are a pure function of the seed, and the window's
@@ -31,8 +38,10 @@ stream is apart from the warm-up's.
 
 import ast
 import datetime
+import itertools
 import json
 import operator
+import string
 from dataclasses import dataclass, field
 from pathlib import Path
 
@@ -95,11 +104,37 @@ def draw_params(spec: dict, rng, variables: dict) -> dict:
     return out
 
 
+def prepared_form(template: str) -> tuple:
+    """A ``str.format`` template as a statement to prepare -> (its SQL with
+    a ``?`` for each field, the fields' names in that order).  A field that
+    is a whole quoted literal (``'{c}'``) loses its quotes: a bound string
+    needs none.  A field inside a longer literal, or one with a format
+    spec or a conversion, cannot be bound and is refused."""
+    sql, names = "", []
+    for text, name, spec, conv in string.Formatter().parse(template):
+        sql += text
+        if name is None:
+            continue
+        if spec or conv:
+            raise ValueError(f"traffic: {{{name}}} in {template!r}: a "
+                             "prepared parameter takes no format")
+        names.append(name)
+        sql += "\0"
+    sql = sql.replace("'\0'", "?")
+    prefixes = itertools.accumulate(sql.split("\0")[:-1])
+    if any(p.count("'") % 2 for p in prefixes):
+        raise ValueError(f"traffic: {template!r}: a prepared parameter "
+                         "inside a quoted literal")
+    return sql.replace("\0", "?"), tuple(names)
+
+
 @dataclass
 class Statement:
     name: str
     sql: str
     params: dict
+    prepare: str | None = None      # sent prepared: the SQL with ``?``
+    args: tuple = ()                # its parameters, in ``?`` order
 
 
 @dataclass
@@ -109,6 +144,7 @@ class Transaction:
     begin: str | None
     commit: str | None
     statements: list = field(default_factory=list)
+    prepared: bool = False          # begin and commit too
 
 
 def load_traffic(name: str) -> dict:
@@ -124,17 +160,24 @@ class Client:
         self.traffic, self.variables, self.index = traffic, variables, index
         self.rng = np.random.default_rng([seed, stream, index])
         self.turn = index       # clients start the cycle at different places
+        self.prepared = {name: prepared_form(s["sql"])
+                         for name, s in traffic["statements"].items()} \
+            if traffic.get("prepared") else {}
 
     def next(self) -> Transaction:
         kinds = self.traffic["transactions"]
         t = kinds[self.turn % len(kinds)]
         self.turn += 1
         txn = Transaction(t["name"], t.get("annotation", "client.txn"),
-                          t.get("begin"), t.get("commit"))
+                          t.get("begin"), t.get("commit"),
+                          prepared=bool(self.prepared))
         for step in t["steps"]:
             s = self.traffic["statements"][step["statement"]]
             for _ in range(step.get("repeat", 1)):
                 p = draw_params(s.get("params", {}), self.rng, self.variables)
-                txn.statements.append(
-                    Statement(step["statement"], s["sql"].format(**p), p))
+                st = Statement(step["statement"], s["sql"].format(**p), p)
+                if self.prepared:
+                    st.prepare, names = self.prepared[step["statement"]]
+                    st.args = tuple(p[a] for a in names)
+                txn.statements.append(st)
         return txn
